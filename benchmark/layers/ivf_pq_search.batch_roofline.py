@@ -1,0 +1,4 @@
+"""Refined IVF-PQ search calls' least time over the device's busy time
+in the window, in % (closed-loop cells: every call is a full batch)."""
+
+from benchmark.roofline import share as read  # noqa: F401
