@@ -5,8 +5,6 @@ served and deadline-expired traffic) with the full ISSUE-18 stack armed
 — request-scope tracing, the flight recorder, and an attached SLO
 watchtower — then proves the exporter contracts in-process:
 
-  * `obs.to_chrome_trace()` rendered twice must be byte-identical and
-    must parse as valid Chrome trace-event JSON;
   * the flight dump must land as one readable atomic JSON file (no
     `*.tmp.*` droppings) whose ring carries the run's events;
   * the obs snapshot saved to `--out` must carry trace records, all
@@ -92,19 +90,7 @@ def main():
                     raise
         i += len(futs)
 
-    # -- contract 1: chrome export is valid and byte-stable ------------
-    one = obs.to_chrome_trace()
-    two = obs.to_chrome_trace()
-    if one != two:
-        raise SystemExit("chrome trace render is not byte-stable")
-    payload = json.loads(one)
-    if not payload["traceEvents"]:
-        raise SystemExit("chrome trace rendered no events")
-    chrome_path = os.path.join(out, "chrome_trace.json")
-    with open(chrome_path, "w") as f:
-        f.write(one)
-
-    # -- contract 2: the flight dump is one readable atomic file -------
+    # -- contract 1: the flight dump is one readable atomic file -------
     dump_path = flight.maybe_dump("bench_trace_smoke",
                                   served=served, expired=expired)
     if dump_path is None or not os.path.exists(dump_path):
@@ -117,7 +103,7 @@ def main():
     if not dump["events"]:
         raise SystemExit("flight ring dumped empty")
 
-    # -- contract 3: the snapshot carries the full ISSUE-18 surface ----
+    # -- contract 2: the snapshot carries the full tracing surface ----
     snap_path = os.path.join(out, "obs_snapshot.json")
     snap = export.save_snapshot(snap_path)
     counters = snap["metrics"]["counters"]
@@ -144,10 +130,8 @@ def main():
     print(json.dumps({
         "suite": "trace_smoke", "served": served, "expired": expired,
         "trace_events_on_bus": len(traces),
-        "chrome_events": len(payload["traceEvents"]),
         "flight_ring_events": len(dump["events"]),
         "snapshot": snap_path, "flight_dump": dump_path,
-        "chrome_trace": chrome_path,
     }, sort_keys=True))
 
 

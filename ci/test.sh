@@ -162,8 +162,8 @@ case "$tier" in
     tmp="$(mktemp -d)"
     # hermetic tracing smoke: ~1k traced requests through a step-mode
     # server with the flight recorder + SLO watchtower armed; the
-    # script itself enforces chrome-export byte-stability and the
-    # atomic-dump contract, then the run report over its snapshot is
+    # script itself enforces the atomic-dump contract, then the run
+    # report over its snapshot is
     # rendered twice + cmp'd (byte-determinism is the contract) and
     # must carry the tracing + SLO sections
     env RAFT_TPU_OBS=1 JAX_PLATFORMS=cpu \

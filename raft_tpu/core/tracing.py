@@ -50,6 +50,23 @@ def trace_range(name: str, **kwargs):
             yield
 
 
+def scoped(name: str):
+    """Decorator: trace `f` inside `jax.named_scope(name)`. Under
+    `jax.jit` every op of the program carries `name` in its metadata
+    (the profiler's `tf_op`), and nothing else about the program
+    changes. A fresh scope per call: `jax.named_scope(...)` used as a
+    decorator keeps one saved name stack for every thread that traces."""
+    def deco(f):
+        @functools.wraps(f)
+        def wrapper(*args, **kwargs):
+            with jax.named_scope(name):
+                return f(*args, **kwargs)
+
+        return wrapper
+
+    return deco
+
+
 def annotate(name: str, **kwargs):
     """Decorator form of trace_range; `**kwargs` forward to it."""
     def deco(f):

@@ -30,6 +30,8 @@ from raft_tpu.core.config import auto_convert_output
 
 # database rows per tile in the scanned path
 _TILE = 1 << 15
+#: stage scopes of `_bf_knn_impl`, in its ops' metadata
+DISTANCE, SELECT = "brute_force.distance", "brute_force.select"
 
 
 @functools.partial(
@@ -51,53 +53,66 @@ def _bf_knn_impl(
     rows displace true neighbors out of the selection entirely (zero pads
     sit closer to many queries than real far-away rows). `prefilter`
     (core.bitset.Bitset over dataset row ids, a pytree arg) masks
-    filtered-out rows the same way, also before selection."""
+    filtered-out rows the same way, also before selection.
+
+    Stage scopes in the program's op metadata: `brute_force.distance`
+    (tiling, distances, masks) and `brute_force.select` (the per-tile
+    select-k and the running merge)."""
     n = dataset.shape[0]
     select_min = metric not in SIMILARITY_METRICS
     worst = jnp.inf if select_min else -jnp.inf
 
     if n <= max(2 * tile, 4 * k):
-        d = _pairwise_impl(queries, dataset, metric, metric_arg=metric_arg)
-        if n_valid is not None:
-            d = jnp.where(jnp.arange(n)[None, :] < n_valid, d, worst)
-        if prefilter is not None:
-            d = jnp.where(prefilter.test(jnp.arange(n))[None, :], d, worst)
-        vals, idx = _select_k_impl(d, k, select_min)
-        return vals, idx.astype(jnp.int32)
+        with jax.named_scope(DISTANCE):
+            d = _pairwise_impl(queries, dataset, metric, metric_arg=metric_arg)
+            if n_valid is not None:
+                d = jnp.where(jnp.arange(n)[None, :] < n_valid, d, worst)
+            if prefilter is not None:
+                d = jnp.where(prefilter.test(jnp.arange(n))[None, :], d, worst)
+        with jax.named_scope(SELECT):
+            vals, idx = _select_k_impl(d, k, select_min)
+            return vals, idx.astype(jnp.int32)
 
     ntiles = -(-n // tile)
     pad = ntiles * tile - n
-    if pad:
-        padval = jnp.full((pad, dataset.shape[1]), 0, dataset.dtype)
-        dataset = jnp.concatenate([dataset, padval], axis=0)
-    tiles = dataset.reshape(ntiles, tile, dataset.shape[1])
+    with jax.named_scope(DISTANCE):
+        if pad:
+            padval = jnp.full((pad, dataset.shape[1]), 0, dataset.dtype)
+            dataset = jnp.concatenate([dataset, padval], axis=0)
+        tiles = dataset.reshape(ntiles, tile, dataset.shape[1])
     q = queries.shape[0]
     limit = n if n_valid is None else jnp.minimum(n_valid, n)
 
     def step(carry, inp):
         best_v, best_i = carry
         t, dtile = inp
-        d = _pairwise_impl(queries, dtile, metric, metric_arg=metric_arg)
-        base = t * tile
-        if pad or n_valid is not None or prefilter is not None:
-            col = jnp.arange(tile) + base
-            keep = col[None, :] < limit
-            if prefilter is not None:
-                keep = keep & prefilter.test(col)[None, :]
-            d = jnp.where(keep, d, worst)
-        v, i = _select_k_impl(d, min(k, tile), select_min)
-        i = i.astype(jnp.int32) + base
-        # merge running queue with tile candidates (knn_merge_parts)
-        cat_v = jnp.concatenate([best_v, v], axis=1)
-        cat_i = jnp.concatenate([best_i, i], axis=1)
-        mv, mi = _select_k_impl(cat_v, k, select_min)
-        return (mv, jnp.take_along_axis(cat_i, mi, axis=1)), None
+        with jax.named_scope(DISTANCE):
+            d = _pairwise_impl(queries, dtile, metric, metric_arg=metric_arg)
+            base = t * tile
+            if pad or n_valid is not None or prefilter is not None:
+                col = jnp.arange(tile) + base
+                keep = col[None, :] < limit
+                if prefilter is not None:
+                    keep = keep & prefilter.test(col)[None, :]
+                d = jnp.where(keep, d, worst)
+        with jax.named_scope(SELECT):
+            v, i = _select_k_impl(d, min(k, tile), select_min)
+            i = i.astype(jnp.int32) + base
+            # merge running queue with tile candidates (knn_merge_parts)
+            cat_v = jnp.concatenate([best_v, v], axis=1)
+            cat_i = jnp.concatenate([best_i, i], axis=1)
+            mv, mi = _select_k_impl(cat_v, k, select_min)
+            return (mv, jnp.take_along_axis(cat_i, mi, axis=1)), None
 
-    init = (
-        jnp.full((q, k), worst, jnp.float32),
-        jnp.full((q, k), -1, jnp.int32),
-    )
-    (vals, idx), _ = lax.scan(step, init, (jnp.arange(ntiles), tiles))
+    with jax.named_scope(SELECT):
+        init = (
+            jnp.full((q, k), worst, jnp.float32),
+            jnp.full((q, k), -1, jnp.int32),
+        )
+    # the loop's own work (slicing each tile out of the corpus) is the
+    # distance stage's; an op takes the innermost scope on its path
+    with jax.named_scope(DISTANCE):
+        (vals, idx), _ = lax.scan(step, init, (jnp.arange(ntiles), tiles))
     return vals, idx
 
 @obs.spanned("neighbors.brute_force.knn")

@@ -662,7 +662,7 @@ def _search_impl_listmajor(
 
     v, ids = score_and_select(
         tables, block, slot_rows, _select_k_impl, nq, n_probes, k, select_min,
-        chunk, chunk_block, max_list,
+        chunk, chunk_block, max_list, stage="ivf_flat",
     )
     if metric == DistanceType.L2SqrtExpanded:
         v = jnp.sqrt(v)

@@ -358,76 +358,90 @@ def _coarse_fit(params, x, rotation, key, seed: int):
     return centers, x_train_rot, key
 
 
-@obs.spanned("neighbors.ivf_pq.build")
 def build(params: IndexParams, dataset, resources=None, seed: int = 0) -> Index:
     """Train rotation, coarse centers, codebooks; encode + pack lists
-    (detail/ivf_pq_build.cuh:1074)."""
-    from raft_tpu.core.validation import check_matrix
+    (detail/ivf_pq_build.cuh:1074).
 
-    x = check_matrix(dataset, name="dataset").astype(jnp.float32)
-    n, dim = x.shape
-    if params.n_lists > n:
-        raise ValueError(f"n_lists={params.n_lists} > dataset rows {n}")
-    pq_dim = params.pq_dim or _auto_pq_dim(dim)
-    pq_len = -(-dim // pq_dim)
-    rot_dim = pq_dim * pq_len
-    key = jax.random.PRNGKey(seed)
-    key, rk = jax.random.split(key)
-    rotation = _make_rotation(rk, rot_dim, dim, params.force_random_rotation or rot_dim != dim)
+    Spans, with obs enabled: `neighbors.ivf_pq.build` around it all,
+    `ivf_pq.build.coarse` (trainset, balanced k-means) and
+    `ivf_pq.build.codebook` (labels, residuals, codebook training);
+    `neighbors.ivf_pq.extend` holds the encode. Each of the three blocks
+    on its outputs as it closes, so its time is device time; disabled,
+    the build adds no sync."""
+    with obs.span("neighbors.ivf_pq.build") as sp:
+        from raft_tpu.core.validation import check_matrix
 
-    centers, x_train_rot, key = _coarse_fit(params, x, rotation, key, seed)
-    n_train = int(x_train_rot.shape[0])
-    metric_name = "inner_product" if params.metric == DistanceType.InnerProduct else "sqeuclidean"
+        x = check_matrix(dataset, name="dataset").astype(jnp.float32)
+        n, dim = x.shape
+        if params.n_lists > n:
+            raise ValueError(f"n_lists={params.n_lists} > dataset rows {n}")
+        pq_dim = params.pq_dim or _auto_pq_dim(dim)
+        pq_len = -(-dim // pq_dim)
+        rot_dim = pq_dim * pq_len
+        key = jax.random.PRNGKey(seed)
+        key, rk = jax.random.split(key)
+        rotation = _make_rotation(rk, rot_dim, dim, params.force_random_rotation or rot_dim != dim)
 
-    # codebooks from trainset residuals. Codebook EM only needs enough
-    # samples to fit 2^pq_bits centroids per subspace (the reference trains
-    # codebooks on the same subsampled trainset, ivf_pq_build.cuh:393);
-    # capping the residual set keeps the vmapped-EM stage O(1) in dataset
-    # size without measurable recall cost. PER_CLUSTER partitions the
-    # sampled rows across n_lists before training, so its cap must scale
-    # with n_lists to keep every cluster's sample set populated.
-    nb = 1 << params.pq_bits
-    max_cb_rows = max(65536, 64 * nb)
-    if params.codebook_kind == PER_CLUSTER:
-        max_cb_rows = max(max_cb_rows, 256 * params.n_lists)
-    if n_train > max_cb_rows:
-        key, rk2 = jax.random.split(key)
-        cb_sel = jax.random.choice(rk2, n_train, (max_cb_rows,), replace=False)
-        x_cb = x_train_rot[cb_sel]
-    else:
-        x_cb = x_train_rot
-    train_labels = kmeans_balanced.predict(x_cb, centers, metric=metric_name)
-    residuals = x_cb - centers[train_labels]
-    key, ck = jax.random.split(key)
-    # codebook training through the shared quantizer layer (the jitted
-    # trainers are the pre-refactor functions — bit-identical)
-    quant = PqQuantizer(
-        codebook_kind=params.codebook_kind, pq_bits=params.pq_bits,
-        pq_dim=pq_dim, pq_len=pq_len, n_lists=params.n_lists,
-    )
-    pq_centers = quant.train(ck, residuals, train_labels).pq_centers
+        with obs.span("ivf_pq.build.coarse") as st:
+            centers, x_train_rot, key = st.settle(
+                _coarse_fit(params, x, rotation, key, seed))
+        n_train = int(x_train_rot.shape[0])
+        metric_name = "inner_product" if params.metric == DistanceType.InnerProduct else "sqeuclidean"
 
-    index = Index(
-        params,
-        rotation,
-        centers,
-        pq_centers,
-        jnp.zeros((params.n_lists, 1, pq_dim), jnp.uint8),
-        jnp.full((params.n_lists, 1), -1, jnp.int32),
-        jnp.zeros((params.n_lists,), jnp.int32),
-        jnp.zeros((0,), jnp.int32),
-    )
-    # empty index: zero radii — extend max-folds each batch's exact
-    # (rotated-space) residual norms in, one pass over assignments
-    index.list_radii = jnp.zeros((params.n_lists,), jnp.float32)
-    if params.add_data_on_build:
-        index = extend(index, x, jnp.arange(n, dtype=jnp.int32))
-    # build-time integrity sidecar (kept fresh incrementally after)
-    from raft_tpu.integrity.digest import attach as _attach_digests
+        # codebooks from trainset residuals. Codebook EM only needs enough
+        # samples to fit 2^pq_bits centroids per subspace (the reference trains
+        # codebooks on the same subsampled trainset, ivf_pq_build.cuh:393);
+        # capping the residual set keeps the vmapped-EM stage O(1) in dataset
+        # size without measurable recall cost. PER_CLUSTER partitions the
+        # sampled rows across n_lists before training, so its cap must scale
+        # with n_lists to keep every cluster's sample set populated.
+        nb = 1 << params.pq_bits
+        max_cb_rows = max(65536, 64 * nb)
+        if params.codebook_kind == PER_CLUSTER:
+            max_cb_rows = max(max_cb_rows, 256 * params.n_lists)
+        if n_train > max_cb_rows:
+            key, rk2 = jax.random.split(key)
+            cb_sel = jax.random.choice(rk2, n_train, (max_cb_rows,), replace=False)
+            x_cb = x_train_rot[cb_sel]
+        else:
+            x_cb = x_train_rot
+        with obs.span("ivf_pq.build.codebook") as st:
+            train_labels = kmeans_balanced.predict(x_cb, centers,
+                                                   metric=metric_name)
+            residuals = x_cb - centers[train_labels]
+            key, ck = jax.random.split(key)
+            # codebook training through the shared quantizer layer (the jitted
+            # trainers are the pre-refactor functions — bit-identical)
+            quant = PqQuantizer(
+                codebook_kind=params.codebook_kind, pq_bits=params.pq_bits,
+                pq_dim=pq_dim, pq_len=pq_len, n_lists=params.n_lists,
+            )
+            pq_centers = st.settle(
+                quant.train(ck, residuals, train_labels).pq_centers)
 
-    _attach_digests(index, "ivf_pq")
-    if resources is not None:
-        resources.track(index.codes)
+        index = Index(
+            params,
+            rotation,
+            centers,
+            pq_centers,
+            jnp.zeros((params.n_lists, 1, pq_dim), jnp.uint8),
+            jnp.full((params.n_lists, 1), -1, jnp.int32),
+            jnp.zeros((params.n_lists,), jnp.int32),
+            jnp.zeros((0,), jnp.int32),
+        )
+        # empty index: zero radii — extend max-folds each batch's exact
+        # (rotated-space) residual norms in, one pass over assignments
+        index.list_radii = jnp.zeros((params.n_lists,), jnp.float32)
+        if params.add_data_on_build:
+            index = extend(index, x, jnp.arange(n, dtype=jnp.int32))
+        # build-time integrity sidecar (kept fresh incrementally after)
+        from raft_tpu.integrity.digest import attach as _attach_digests
+
+        _attach_digests(index, "ivf_pq")
+        if resources is not None:
+            resources.track(index.codes)
+        sp.settle([v for v in vars(index).values()
+                   if isinstance(v, jax.Array)])
     return index
 
 
@@ -736,7 +750,9 @@ def _search_impl(
     select_min = metric != DistanceType.InnerProduct
     worst = jnp.inf if select_min else -jnp.inf
 
-    q_rot, probes = _coarse_select(queries, rotation, centers, n_probes, metric)
+    with jax.named_scope("ivf_pq.coarse"):
+        q_rot, probes = _coarse_select(queries, rotation, centers, n_probes,
+                                       metric)
 
     qb = _query_block_size(n_probes, max_list, pq_dim)
     nblocks = -(-nq // qb)
@@ -809,17 +825,22 @@ def _search_impl(
         rows = rows.reshape(qb, -1)  # (qb, np*max_list)
         scores = scores.reshape(qb, -1)
         scores = jnp.where(rows >= 0, scores, worst)
-        v, pos = _select_k_impl(scores, k, select_min)
-        return v, jnp.take_along_axis(rows, pos, axis=1)
+        with jax.named_scope("ivf_pq.merge"):
+            v, pos = _select_k_impl(scores, k, select_min)
+            return v, jnp.take_along_axis(rows, pos, axis=1)
 
-    vals, rows = lax.map(
-        block,
-        (qblocks, pblocks, pvblocks) if pvalid is not None
-        else (qblocks, pblocks))
-    vals = vals.reshape(-1, k)[:nq]
-    rows = rows.reshape(-1, k)[:nq]
-    if metric == DistanceType.L2SqrtExpanded:
-        vals = jnp.sqrt(jnp.maximum(vals, 0.0))
+    # stage scopes: an op takes the innermost one on its path, so the
+    # select inside `block` is merge and the rest of the loop is scan
+    with jax.named_scope("ivf_pq.scan"):
+        vals, rows = lax.map(
+            block,
+            (qblocks, pblocks, pvblocks) if pvalid is not None
+            else (qblocks, pblocks))
+    with jax.named_scope("ivf_pq.merge"):
+        vals = vals.reshape(-1, k)[:nq]
+        rows = rows.reshape(-1, k)[:nq]
+        if metric == DistanceType.L2SqrtExpanded:
+            vals = jnp.sqrt(jnp.maximum(vals, 0.0))
     return vals, rows
 
 
@@ -849,7 +870,9 @@ def _search_impl_recon8(
     select_min = metric != DistanceType.InnerProduct
     worst = jnp.inf if select_min else -jnp.inf
 
-    q_rot, probes = _coarse_select(queries, rotation, centers, n_probes, metric)
+    with jax.named_scope("ivf_pq.coarse"):
+        q_rot, probes = _coarse_select(queries, rotation, centers, n_probes,
+                                       metric)
 
     qb = _query_block_size(n_probes, max_list, rot_dim)
     nblocks = -(-nq // qb)
@@ -893,17 +916,22 @@ def _search_impl_recon8(
         rows = rows.reshape(qb, -1)
         scores = scores.reshape(qb, -1)
         scores = jnp.where(rows >= 0, scores, worst)
-        v, pos = _select_k_impl(scores, k, select_min)
-        return v, jnp.take_along_axis(rows, pos, axis=1)
+        with jax.named_scope("ivf_pq.merge"):
+            v, pos = _select_k_impl(scores, k, select_min)
+            return v, jnp.take_along_axis(rows, pos, axis=1)
 
-    vals, rows = lax.map(
-        block,
-        (qblocks, pblocks, pvblocks) if pvalid is not None
-        else (qblocks, pblocks))
-    vals = vals.reshape(-1, k)[:nq]
-    rows = rows.reshape(-1, k)[:nq]
-    if metric == DistanceType.L2SqrtExpanded:
-        vals = jnp.sqrt(jnp.maximum(vals, 0.0))
+    # stage scopes: an op takes the innermost one on its path, so the
+    # select inside `block` is merge and the rest of the loop is scan
+    with jax.named_scope("ivf_pq.scan"):
+        vals, rows = lax.map(
+            block,
+            (qblocks, pblocks, pvblocks) if pvalid is not None
+            else (qblocks, pblocks))
+    with jax.named_scope("ivf_pq.merge"):
+        vals = vals.reshape(-1, k)[:nq]
+        rows = rows.reshape(-1, k)[:nq]
+        if metric == DistanceType.L2SqrtExpanded:
+            vals = jnp.sqrt(jnp.maximum(vals, 0.0))
     return vals, rows
 
 
@@ -968,15 +996,19 @@ def _search_impl_recon8_listmajor(
     n_lists, max_list, rot_dim = recon8.shape
     select_min = metric != DistanceType.InnerProduct
     worst = jnp.inf if select_min else -jnp.inf
-    q_rot, probes = _coarse_select(queries, rotation, centers, n_probes, metric)
+    with jax.named_scope("ivf_pq.coarse"):
+        q_rot, probes = _coarse_select(queries, rotation, centers, n_probes,
+                                       metric)
     # impls resolved by the caller OUTSIDE this jit (static args), so a
     # tuned flip retraces instead of serving the stale program
     invert_impl, qs_impl = setup_impls
     invert = invert_probes_count if invert_impl == "count" else invert_probes_sort
-    tables = invert(probes, n_lists, chunk, pvalid)
+    with jax.named_scope("ivf_pq.invert"):
+        tables = invert(probes, n_lists, chunk, pvalid)
 
-    q_pad = jnp.concatenate([q_rot, jnp.zeros((1, rot_dim), q_rot.dtype)])
-    scale_bf = recon_scale.astype(jnp.bfloat16)
+    with jax.named_scope("ivf_pq.scan"):
+        q_pad = jnp.concatenate([q_rot, jnp.zeros((1, rot_dim), q_rot.dtype)])
+        scale_bf = recon_scale.astype(jnp.bfloat16)
 
     def block(inp):
         lofb, qids = inp  # (CB,), (CB, chunk)
@@ -1027,11 +1059,12 @@ def _search_impl_recon8_listmajor(
 
     v, rows_out = score_and_select(
         tables, block, slot_rows, _select_k_impl, nq, n_probes, k, select_min,
-        chunk, chunk_block, max_list, exact_trim=exact_trim,
+        chunk, chunk_block, max_list, exact_trim=exact_trim, stage="ivf_pq",
     )
-    v = v.astype(jnp.float32)
-    if metric == DistanceType.L2SqrtExpanded:
-        v = jnp.sqrt(jnp.maximum(v, 0.0))
+    with jax.named_scope("ivf_pq.merge"):
+        v = v.astype(jnp.float32)
+        if metric == DistanceType.L2SqrtExpanded:
+            v = jnp.sqrt(jnp.maximum(v, 0.0))
     return v, rows_out
 
 
@@ -1080,74 +1113,80 @@ def _search_impl_recon8_listmajor_pallas(
     select_min = metric != DistanceType.InnerProduct
     ip = metric == DistanceType.InnerProduct
 
-    q_rot, probes = _coarse_select(queries, rotation, centers, n_probes, metric)
+    with jax.named_scope("ivf_pq.coarse"):
+        q_rot, probes = _coarse_select(queries, rotation, centers, n_probes,
+                                       metric)
     invert_impl, qs_impl = setup_impls
     invert = invert_probes_count if invert_impl == "count" else invert_probes_sort
-    tables = invert(probes, n_lists, chunk, pvalid)
+    with jax.named_scope("ivf_pq.invert"):
+        tables = invert(probes, n_lists, chunk, pvalid)
     lof, qid_tbl = tables.lof, tables.qid_tbl
     ncb = lof.shape[0]
 
-    # per-chunk query residuals with the int8 store's scale folded in
-    # (the kernel then consumes raw int8 codes with no dequant multiply)
-    q_pad = jnp.concatenate([q_rot, jnp.zeros((1, rot_dim), q_rot.dtype)])
-    qs = gather_query_rows(q_pad, qid_tbl, qs_impl)  # (ncb, chunk, rot)
-    cent = centers[lof]  # (ncb, rot)
-    qres = qs if ip else qs - cent[:, None, :]
-    qres_s = qres * recon_scale[None, None, :]
+    with jax.named_scope("ivf_pq.scan"):
+        # per-chunk query residuals with the int8 store's scale folded in
+        # (the kernel then consumes raw int8 codes with no dequant multiply)
+        q_pad = jnp.concatenate([q_rot, jnp.zeros((1, rot_dim), q_rot.dtype)])
+        qs = gather_query_rows(q_pad, qid_tbl, qs_impl)  # (ncb, chunk, rot)
+        cent = centers[lof]  # (ncb, rot)
+        qres = qs if ip else qs - cent[:, None, :]
+        qres_s = qres * recon_scale[None, None, :]
 
-    # additive per-slot base: L2 -> recon norm; IP -> 0; invalid -> +inf
-    valid = slot_rows_pad >= 0
-    if ip:
-        # kernel minimizes base - dots = -dots on valid slots
-        base = jnp.where(valid, 0.0, jnp.inf)[:, None, :]
-    else:
-        base = jnp.where(valid, recon_norm, jnp.inf)[:, None, :]
+        # additive per-slot base: L2 -> recon norm; IP -> 0; invalid -> +inf
+        valid = slot_rows_pad >= 0
+        if ip:
+            # kernel minimizes base - dots = -dots on valid slots
+            base = jnp.where(valid, 0.0, jnp.inf)[:, None, :]
+        else:
+            base = jnp.where(valid, recon_norm, jnp.inf)[:, None, :]
 
-    if int8_queries:
-        # symmetric int8 scoring in-kernel (the XLA engine's int8 path,
-        # moved inside the fused scan): quantize each scale-folded query
-        # residual row to int8 and let the kernel dequant by the per-row
-        # scale after its int8 x int8 -> int32 matmul
-        q8, row_scale = _quantize_query_rows(qres_s)
-        vals, slot_idx = pq_list_scan(
-            lof, q8, recon8, base, inner_product=ip, interpret=interpret,
-            q_scale=row_scale, fold=fold,
+        if int8_queries:
+            # symmetric int8 scoring in-kernel (the XLA engine's int8 path,
+            # moved inside the fused scan): quantize each scale-folded query
+            # residual row to int8 and let the kernel dequant by the per-row
+            # scale after its int8 x int8 -> int32 matmul
+            q8, row_scale = _quantize_query_rows(qres_s)
+            vals, slot_idx = pq_list_scan(
+                lof, q8, recon8, base, inner_product=ip, interpret=interpret,
+                q_scale=row_scale, fold=fold,
+            )
+        else:
+            vals, slot_idx = pq_list_scan(
+                lof, qres_s, recon8, base, inner_product=ip, interpret=interpret,
+                fold=fold,
+            )  # (ncb, chunk, 512) minimizing
+
+        invalid = ~jnp.isfinite(vals)
+        rows = jnp.take_along_axis(slot_rows_pad[lof][:, None, :], slot_idx, axis=2)
+        rows = jnp.where(invalid, -1, rows)
+
+        # undo the kernel's minimization frame and add per-query constants
+        if ip:
+            # IP score = dots + q.center; kernel returned -dots on valid slots
+            qdotc = jnp.einsum("cqd,cd->cq", qs, cent)
+            vals = jnp.where(invalid, -jnp.inf, -vals + qdotc[:, :, None])
+        else:
+            qcn = jnp.sum(qres**2, axis=2)  # (ncb, chunk)
+            vals = vals + qcn[:, :, None]
+
+    with jax.named_scope("ivf_pq.trim"):
+        # trim the bin candidates to the merge width kk (tiny exact top-k)
+        cands = vals.shape[-1]
+        kk = min(k, _BINS)
+        tv, tpos = _select_k_impl(
+            vals.reshape(ncb * vals.shape[1], cands), kk, select_min
         )
-    else:
-        vals, slot_idx = pq_list_scan(
-            lof, qres_s, recon8, base, inner_product=ip, interpret=interpret,
-            fold=fold,
-        )  # (ncb, chunk, 512) minimizing
+        tr = jnp.take_along_axis(rows.reshape(ncb * rows.shape[1], cands), tpos, axis=1)
+        tv = tv.reshape(ncb, -1, kk)
+        tr = tr.reshape(ncb, -1, kk)
 
-    invalid = ~jnp.isfinite(vals)
-    rows = jnp.take_along_axis(slot_rows_pad[lof][:, None, :], slot_idx, axis=2)
-    rows = jnp.where(invalid, -1, rows)
-
-    # undo the kernel's minimization frame and add per-query constants
-    if ip:
-        # IP score = dots + q.center; kernel returned -dots on valid slots
-        qdotc = jnp.einsum("cqd,cd->cq", qs, cent)
-        vals = jnp.where(invalid, -jnp.inf, -vals + qdotc[:, :, None])
-    else:
-        qcn = jnp.sum(qres**2, axis=2)  # (ncb, chunk)
-        vals = vals + qcn[:, :, None]
-
-    # trim the bin candidates to the merge width kk (tiny exact top-k)
-    cands = vals.shape[-1]
-    kk = min(k, _BINS)
-    tv, tpos = _select_k_impl(
-        vals.reshape(ncb * vals.shape[1], cands), kk, select_min
-    )
-    tr = jnp.take_along_axis(rows.reshape(ncb * rows.shape[1], cands), tpos, axis=1)
-    tv = tv.reshape(ncb, -1, kk)
-    tr = tr.reshape(ncb, -1, kk)
-
-    v, rows_out = regroup_merge(
-        tables, tv, tr, _select_k_impl, nq, n_probes, int(k), select_min
-    )
-    v = v.astype(jnp.float32)
-    if metric == DistanceType.L2SqrtExpanded:
-        v = jnp.sqrt(jnp.maximum(v, 0.0))
+    with jax.named_scope("ivf_pq.merge"):
+        v, rows_out = regroup_merge(
+            tables, tv, tr, _select_k_impl, nq, n_probes, int(k), select_min
+        )
+        v = v.astype(jnp.float32)
+        if metric == DistanceType.L2SqrtExpanded:
+            v = jnp.sqrt(jnp.maximum(v, 0.0))
     return v, rows_out
 
 
@@ -1206,62 +1245,67 @@ def _search_impl_recon8_listmajor_fused(
     select_min = metric != DistanceType.InnerProduct
     ip = metric == DistanceType.InnerProduct
 
-    q_rot, probes = _coarse_select(queries, rotation, centers, n_probes, metric)
+    with jax.named_scope("ivf_pq.coarse"):
+        q_rot, probes = _coarse_select(queries, rotation, centers, n_probes,
+                                       metric)
     invert_impl, qs_impl = setup_impls
     invert = invert_probes_count if invert_impl == "count" else invert_probes_sort
-    tables = invert(probes, n_lists, chunk, pvalid)
+    with jax.named_scope("ivf_pq.invert"):
+        tables = invert(probes, n_lists, chunk, pvalid)
     lof, qid_tbl = tables.lof, tables.qid_tbl
-    cvalid = chunk_validity(qid_tbl, nq)  # empty chunks skip in-kernel
+    with jax.named_scope("ivf_pq.scan"):
+        cvalid = chunk_validity(qid_tbl, nq)  # empty chunks skip in-kernel
 
-    q_pad = jnp.concatenate([q_rot, jnp.zeros((1, rot_dim), q_rot.dtype)])
-    qs = gather_query_rows(q_pad, qid_tbl, qs_impl)  # (ncb, chunk, rot)
-    cent = centers[lof]
-    qres = qs if ip else qs - cent[:, None, :]
-    qres_s = qres * recon_scale[None, None, :]
+        q_pad = jnp.concatenate([q_rot, jnp.zeros((1, rot_dim), q_rot.dtype)])
+        qs = gather_query_rows(q_pad, qid_tbl, qs_impl)  # (ncb, chunk, rot)
+        cent = centers[lof]
+        qres = qs if ip else qs - cent[:, None, :]
+        qres_s = qres * recon_scale[None, None, :]
 
-    valid = slot_rows_pad >= 0
-    if ip:
-        base = jnp.where(valid, 0.0, jnp.inf)[:, None, :]
-    else:
-        base = jnp.where(valid, recon_norm, jnp.inf)[:, None, :]
+        valid = slot_rows_pad >= 0
+        if ip:
+            base = jnp.where(valid, 0.0, jnp.inf)[:, None, :]
+        else:
+            base = jnp.where(valid, recon_norm, jnp.inf)[:, None, :]
 
-    if int8_queries:
-        # symmetric int8 scoring fused end to end: quantize the
-        # scale-folded residual rows exactly like the pallas trim and
-        # hand the int8 operands to the dispatch layer's int8 kernel
-        q8, row_scale = _quantize_query_rows(qres_s)
-        vals, slot_idx = list_scan_select_k(
-            lof, q8, recon8, base, k, strategy="fused_int8",
-            q_scale=row_scale, kbuf=kb, inner_product=ip,
-            interpret=interpret, fault_key=fault_key, chunk_valid=cvalid,
+        if int8_queries:
+            # symmetric int8 scoring fused end to end: quantize the
+            # scale-folded residual rows exactly like the pallas trim and
+            # hand the int8 operands to the dispatch layer's int8 kernel
+            q8, row_scale = _quantize_query_rows(qres_s)
+            vals, slot_idx = list_scan_select_k(
+                lof, q8, recon8, base, k, strategy="fused_int8",
+                q_scale=row_scale, kbuf=kb, inner_product=ip,
+                interpret=interpret, fault_key=fault_key, chunk_valid=cvalid,
+            )
+        else:
+            vals, slot_idx = list_scan_select_k(
+                lof, qres_s, recon8, base, k, strategy="fused", kbuf=kb,
+                inner_product=ip, interpret=interpret, fault_key=fault_key,
+                chunk_valid=cvalid,
+            )  # (ncb, chunk, kbuf) exact best-first, minimizing
+        vals = vals[:, :, :k]
+        slot_idx = slot_idx[:, :, :k]
+
+        invalid = ~jnp.isfinite(vals)
+        slot_idx = jnp.where(invalid, 0, slot_idx)  # sentinel -> safe gather
+        rows = jnp.take_along_axis(slot_rows_pad[lof][:, None, :], slot_idx, axis=2)
+        rows = jnp.where(invalid, -1, rows)
+
+        if ip:
+            qdotc = jnp.einsum("cqd,cd->cq", qs, cent)
+            vals = jnp.where(invalid, -jnp.inf, -vals + qdotc[:, :, None])
+        else:
+            qcn = jnp.sum(qres**2, axis=2)  # (ncb, chunk)
+            vals = vals + qcn[:, :, None]
+
+    with jax.named_scope("ivf_pq.merge"):
+        v, rows_out = regroup_merge(
+            tables, vals, rows, _select_k_impl, nq, n_probes, int(k), select_min
         )
-    else:
-        vals, slot_idx = list_scan_select_k(
-            lof, qres_s, recon8, base, k, strategy="fused", kbuf=kb,
-            inner_product=ip, interpret=interpret, fault_key=fault_key,
-            chunk_valid=cvalid,
-        )  # (ncb, chunk, kbuf) exact best-first, minimizing
-    vals = vals[:, :, :k]
-    slot_idx = slot_idx[:, :, :k]
-
-    invalid = ~jnp.isfinite(vals)
-    slot_idx = jnp.where(invalid, 0, slot_idx)  # sentinel -> safe gather
-    rows = jnp.take_along_axis(slot_rows_pad[lof][:, None, :], slot_idx, axis=2)
-    rows = jnp.where(invalid, -1, rows)
-
-    if ip:
-        qdotc = jnp.einsum("cqd,cd->cq", qs, cent)
-        vals = jnp.where(invalid, -jnp.inf, -vals + qdotc[:, :, None])
-    else:
-        qcn = jnp.sum(qres**2, axis=2)  # (ncb, chunk)
-        vals = vals + qcn[:, :, None]
-
-    v, rows_out = regroup_merge(
-        tables, vals, rows, _select_k_impl, nq, n_probes, int(k), select_min
-    )
-    v = v.astype(jnp.float32)
-    if metric == DistanceType.L2SqrtExpanded:
-        v = jnp.sqrt(jnp.maximum(v, 0.0))
+        v = v.astype(jnp.float32)
+        if metric == DistanceType.L2SqrtExpanded:
+            v = jnp.sqrt(jnp.maximum(v, 0.0))
     return v, rows_out
 
 

@@ -28,11 +28,13 @@ from raft_tpu import obs
 from raft_tpu.distance.distance_types import DistanceType, resolve_metric
 from raft_tpu.matrix.select_k import _select_k_impl
 from raft_tpu.core.config import auto_convert_output
+from raft_tpu.core.tracing import scoped
 
 _LANES = 128
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
+@scoped("refine")
 def _refine_impl(dataset, queries, candidates, k: int, metric: DistanceType):
     nq, nc = candidates.shape
     select_min = metric != DistanceType.InnerProduct
@@ -75,6 +77,7 @@ def _refine_impl(dataset, queries, candidates, k: int, metric: DistanceType):
 @functools.partial(
     jax.jit, static_argnames=("k", "metric", "interpret", "fault_key")
 )
+@scoped("refine")
 def _refine_fused_impl(dataset, queries, candidates, k: int,
                        metric: DistanceType, interpret: bool = False,
                        fault_key=None):
@@ -288,6 +291,7 @@ def refine_host(
 @functools.partial(
     jax.jit, static_argnames=("k", "metric", "interpret", "fault_key")
 )
+@scoped("refine")
 def _refine_fused_gathered_impl(cdata, queries, candidates, k: int,
                                 metric: DistanceType,
                                 interpret: bool = False, fault_key=None):
@@ -299,6 +303,7 @@ def _refine_fused_gathered_impl(cdata, queries, candidates, k: int,
 
 
 @functools.partial(jax.jit, static_argnames=("k", "metric"))
+@scoped("refine")
 def _refine_gathered_impl(cdata, queries, candidates, k: int, metric: DistanceType):
     """Exact re-rank when candidate rows are already gathered:
     cdata (nq, nc, dim) aligned with candidates (nq, nc)."""

@@ -6,9 +6,10 @@ histograms), structured nested spans, and an ordered event bus that the
 comms collectives, MNMG drivers, neighbors entry points, the serving
 engine, `core.faults` chaos injections, and `core.logger` all feed.
 Exporters render the joined state as a JSON snapshot, Prometheus
-exposition text, or a `jax.profiler` trace session;
-`python -m raft_tpu.obs.report` turns a snapshot into a human-readable
-run report.
+exposition text, or a `jax.profiler` trace session, in which every span
+and every garbage collection (`raft_tpu.gc`) shows beside the device
+ops; `python -m raft_tpu.obs.report` turns a snapshot into a
+human-readable run report.
 
 Gating: everything is OFF by default. Enable with `RAFT_TPU_OBS=1` in
 the environment or `obs.enable()` at runtime. Disabled, every
@@ -16,7 +17,12 @@ instrumentation hook is one module-attribute read and a branch —
 measured within noise of the pre-instrumentation library (see
 docs/observability.md) — and traced programs are byte-identical either
 way (instrumentation is host-side only; nothing is ever inserted into
-a jaxpr).
+a jaxpr). The stage names of the search programs (`jax.named_scope`,
+e.g. `ivf_pq.scan`) are not gated: they are op metadata, always there.
+
+Enabled, a `gc.callbacks` hook writes every garbage collection into the
+profiler trace as a `raft_tpu.gc` span carrying its generation;
+`obs.disable()` removes it.
 
 Counting semantics under jit: collective instruments count at TRACE
 time (XLA owns execution; a cached executable re-runs without
@@ -42,7 +48,10 @@ Public surface:
 
 from __future__ import annotations
 
+import gc
 import os
+
+import jax
 
 # submodule-path imports keep this package safe to import from inside
 # raft_tpu.core's own init (core.faults -> obs -> core.tracing)
@@ -67,7 +76,7 @@ from raft_tpu.obs.spans import (  # noqa: F401
     open_spans,
     span_impl,
 )
-from raft_tpu.obs.trace import TraceCtx, to_chrome_trace  # noqa: F401
+from raft_tpu.obs.trace import TraceCtx  # noqa: F401
 
 ENV_FLAG = "RAFT_TPU_OBS"
 
@@ -86,6 +95,7 @@ def enable(flag: bool = True) -> None:
     global _ENABLED
     _ENABLED = bool(flag)
     _bridge_logger(_ENABLED)
+    _gc_hook(_ENABLED)
     if _ENABLED:
         # RAFT_TPU_FLIGHT_DIR auto-arms the crash flight recorder
         flight.maybe_env_install()
@@ -124,6 +134,32 @@ def _bridge_logger(install: bool) -> None:
             _logger_mod.logger.addHandler(_LOG_HANDLER)
     elif _LOG_HANDLER is not None:
         _logger_mod.logger.removeHandler(_LOG_HANDLER)
+
+
+_GC_OPEN = None  # profiler annotation of the collection in progress
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """`gc.callbacks` hook: each collection is one `raft_tpu.gc`
+    profiler span. Collections never overlap (the collector holds the
+    GIL throughout), so one open slot suffices; the hook takes no lock,
+    since a collection can start inside any locked section."""
+    global _GC_OPEN
+    if phase == "start":
+        _GC_OPEN = jax.profiler.TraceAnnotation(
+            "raft_tpu.gc", generation=int(info.get("generation", -1)))
+        _GC_OPEN.__enter__()
+    elif _GC_OPEN is not None:
+        ann, _GC_OPEN = _GC_OPEN, None
+        ann.__exit__(None, None, None)
+
+
+def _gc_hook(install: bool) -> None:
+    if install:
+        if _on_gc not in gc.callbacks:
+            gc.callbacks.append(_on_gc)
+    elif _on_gc in gc.callbacks:
+        gc.callbacks.remove(_on_gc)
 
 
 def registry() -> Registry:
@@ -316,7 +352,6 @@ __all__ = [
     "span",
     "span_cost",
     "spanned",
-    "to_chrome_trace",
     "trace",
     "trace_range",
     "trace_session",

@@ -142,8 +142,11 @@ def render_registry_prometheus(registry: Optional[_reg_mod.Registry] = None,
 def trace_session(logdir: str, create_perfetto_link: bool = False):
     """Profiler trace session: everything inside the block lands in a
     `jax.profiler` trace under `logdir` (viewable with TensorBoard /
-    Perfetto). Composes with spans — `trace_range` names show up inside
-    the captured timeline.
+    Perfetto). With obs enabled every span shows there as a
+    `raft_tpu.<name>` host event, and every garbage collection as
+    `raft_tpu.gc`, on the device ops' clock; the search programs' stage
+    scopes (`ivf_pq.scan`, `brute_force.select`, ...) name the device
+    ops themselves.
 
         with obs.trace_session("/tmp/tb"):
             ivf_flat.search(p, index, q, k=10)

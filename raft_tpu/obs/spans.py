@@ -1,12 +1,14 @@
 """Structured spans: nested, timed scopes over the real hot paths.
 
 The reference annotates every major entry point with NVTX RAII ranges
-(`core/nvtx.hpp`); `core/tracing.trace_range` is the TPU analogue for
-the *profiler* timeline. Spans are the *accounting* analogue: each one
-times a named scope with the monotonic clock, knows its parent (a
-per-thread stack), lands one "span" event on the bus at close, and
-aggregates its duration into the `span.<name>` histogram — so a run
-report can say where wall-clock went without a profiler session.
+(`core/nvtx.hpp`). A span is both: each one times a named scope with the
+monotonic clock, knows its parent (a per-thread stack), lands one "span"
+event on the bus at close, aggregates its duration into the
+`span.<name>` histogram — so a run report can say where wall-clock went
+without a profiler session — and opens a `jax.profiler.TraceAnnotation`
+named `raft_tpu.<name>` on its own thread, so a profiler trace shows
+every span on the same clock as the device ops (an idle gap on the
+device is labelled by the spans open across it).
 
 Timing semantics (important on an async backend): a span measures HOST
 wall time of the scope. jax dispatch returns before the device
@@ -17,10 +19,13 @@ charge device time to the span, fence the result inside the scope:
         vals, ids = ivf_flat.search(p, index, q, k)
         sp.fence((vals, ids))      # block_until_ready inside the timer
 
-`fence` returns its argument, so it composes inline. With observability
-disabled `span()` yields an inert singleton and touches no clock, no
-stack, no lock — the disabled overhead is one module-attribute read and
-one branch.
+`fence` returns its argument, so it composes inline. `settle` is the
+variant for code that must keep its asynchronous dispatch when nobody
+is timing it: it blocks only inside a recorded span, and the inert span
+returns its argument untouched. With observability disabled `span()`
+yields an inert singleton and touches no clock, no stack, no lock, no
+profiler — the disabled overhead is one module-attribute read and one
+branch.
 """
 
 from __future__ import annotations
@@ -28,6 +33,8 @@ from __future__ import annotations
 import contextlib
 import threading
 import time
+
+import jax
 
 from raft_tpu.obs import bus as _bus_mod
 from raft_tpu.obs import registry as _reg_mod
@@ -45,7 +52,8 @@ _ALL_STACKS: dict = {}
 class Span:
     """One open scope. `set(**attrs)` attaches fields to the close
     event; `cost()` charges analytic flops/bytes (obs.perf formulas);
-    `fence(x)` blocks on device results inside the timer."""
+    `fence(x)` blocks on device results inside the timer; `settle(x)`
+    does the same, and is the one of the two the inert span skips."""
 
     __slots__ = ("name", "depth", "parent", "attrs", "t0")
 
@@ -99,8 +107,11 @@ class Span:
     def fence(self, value):
         """`jax.block_until_ready(value)` so the span's duration covers
         device execution, not just dispatch. Returns `value`."""
-        import jax
+        return jax.block_until_ready(value)
 
+    def settle(self, value):
+        """Block on `value` so the span's duration is device time; the
+        disabled path (`NULL_SPAN.settle`) adds no sync. Returns `value`."""
         return jax.block_until_ready(value)
 
 
@@ -122,9 +133,10 @@ class _NullSpan:
         return self
 
     def fence(self, value):
-        import jax
-
         return jax.block_until_ready(value)
+
+    def settle(self, value):
+        return value
 
 
 NULL_SPAN = _NullSpan()
@@ -164,9 +176,12 @@ def span_impl(name: str, **attrs):
     sp = Span(str(name), depth=len(st), parent=st[-1].name if st else None,
               attrs=attrs)
     st.append(sp)
+    ann = jax.profiler.TraceAnnotation("raft_tpu." + sp.name)
+    ann.__enter__()
     try:
         yield sp
     finally:
+        ann.__exit__(None, None, None)
         st.pop()
         dur = time.monotonic() - sp.t0
         _reg_mod.GLOBAL.histogram(f"span.{sp.name}").observe(dur)

@@ -31,17 +31,13 @@ injected corruption marks the ctx dead and the request degrades to
 *untraced* — results stay bit-identical, because tracing only ever
 observes the request, never steers it.
 
-`to_chrome_trace()` renders trace + span events as Chrome/Perfetto
-trace-event JSON (load in https://ui.perfetto.dev): one track per serve
-worker thread showing stage segments, one track per bucket ladder entry
-showing whole requests. The render is a pure function of the event list
-with sorted keys and fixed separators, so two renders of the same bus
-are byte-identical.
+For a timeline, record a profiler trace (`obs.trace_session`): the
+serve worker's spans (`raft_tpu.serve.*`) sit there beside the device
+ops, on one clock.
 """
 
 from __future__ import annotations
 
-import json
 import threading
 import time
 from typing import List, Optional
@@ -180,111 +176,3 @@ def complete(ctx: Optional[TraceCtx], outcome: str = "ok", **attrs) -> None:
         worker=threading.current_thread().name,
         **ctx.attrs,
     )
-
-
-# ---------------------------------------------------------------------------
-# Chrome/Perfetto trace-event export
-
-
-def _us(t: float, t0: float) -> float:
-    """Microseconds relative to the window start, rounded so the float
-    repr (hence the JSON bytes) is stable."""
-    return round((t - t0) * 1e6, 3)
-
-
-def to_chrome_trace(events: Optional[List[dict]] = None) -> str:
-    """Render bus "trace" + "span" events as Chrome trace-event JSON.
-
-    Tracks: pid 1 = serve worker threads (one tid per worker; each
-    request's stage segments as complete "X" events), pid 2 = bucket
-    ladder (one tid per bucket; one "X" event spanning the whole
-    request), pid 3 = spans (one tid per thread nesting by depth).
-    Pure function of `events` (defaults to the global bus window) —
-    rendering the same window twice yields byte-identical output.
-    """
-    if events is None:
-        events = _bus_mod.GLOBAL.events()
-    traces = [e for e in events if e.get("kind") == "trace" and e.get("marks")]
-    spans = [e for e in events
-             if e.get("kind") == "span" and "dur_s" in e and "t" in e]
-
-    t0 = None
-    for e in traces:
-        lo = min(e["marks"].values())
-        t0 = lo if t0 is None else min(t0, lo)
-    for e in spans:
-        lo = float(e["t"]) - float(e["dur_s"])
-        t0 = lo if t0 is None else min(t0, lo)
-    if t0 is None:
-        t0 = 0.0
-
-    PID_WORKERS, PID_BUCKETS, PID_SPANS = 1, 2, 3
-    workers = sorted({str(e.get("worker", "?")) for e in traces})
-    worker_tid = {w: i + 1 for i, w in enumerate(workers)}
-    buckets = sorted({int(e.get("bucket", 0)) for e in traces})
-    bucket_tid = {b: i + 1 for i, b in enumerate(buckets)}
-    span_threads = sorted({str(e.get("thread", e.get("worker", "?")))
-                           for e in spans})
-    span_tid = {n: i + 1 for i, n in enumerate(span_threads)}
-
-    out: List[dict] = []
-
-    def meta(pid, tid, what, name):
-        out.append({"ph": "M", "pid": pid, "tid": tid, "name": what,
-                    "args": {"name": name}})
-
-    if traces:
-        meta(PID_WORKERS, 0, "process_name", "serve workers")
-        for w in workers:
-            meta(PID_WORKERS, worker_tid[w], "thread_name", w)
-        meta(PID_BUCKETS, 0, "process_name", "bucket ladder")
-        for b in buckets:
-            meta(PID_BUCKETS, bucket_tid[b], "thread_name", f"bucket={b}")
-    if spans:
-        meta(PID_SPANS, 0, "process_name", "spans")
-        for n in span_threads:
-            meta(PID_SPANS, span_tid[n], "thread_name", n)
-
-    for e in traces:
-        marks = e["marks"]
-        tid = worker_tid[str(e.get("worker", "?"))]
-        base_args = {k: v for k, v in sorted(e.items())
-                     if k not in ("kind", "seq", "t", "marks", "stages",
-                                  "worker")}
-        base_args["trace_id"] = f"{int(e['trace_id']):016x}"
-        present = [s for s in STAGES if s in marks]
-        for a, b in zip(present, present[1:]):
-            hist = STAGE_HISTOGRAMS.get((a, b))
-            name = hist.rsplit(".", 1)[-1][:-2] if hist else f"{a}->{b}"
-            out.append({
-                "ph": "X", "pid": PID_WORKERS, "tid": tid, "name": name,
-                "ts": _us(marks[a], t0),
-                "dur": max(0.0, _us(marks[b], t0) - _us(marks[a], t0)),
-                "cat": "serve.stage", "args": base_args,
-            })
-        if len(present) >= 2:
-            out.append({
-                "ph": "X", "pid": PID_BUCKETS,
-                "tid": bucket_tid[int(e.get("bucket", 0))],
-                "name": f"request {base_args['trace_id']}",
-                "ts": _us(marks[present[0]], t0),
-                "dur": max(0.0, _us(marks[present[-1]], t0)
-                           - _us(marks[present[0]], t0)),
-                "cat": "serve.request", "args": base_args,
-            })
-
-    for e in spans:
-        tid = span_tid[str(e.get("thread", e.get("worker", "?")))]
-        args = {k: v for k, v in sorted(e.items())
-                if k not in ("kind", "seq", "t", "dur_s", "name", "thread")}
-        out.append({
-            "ph": "X", "pid": PID_SPANS, "tid": tid,
-            "name": str(e.get("name", "span")),
-            "ts": _us(float(e["t"]) - float(e["dur_s"]), t0),
-            "dur": round(float(e["dur_s"]) * 1e6, 3),
-            "cat": "span", "args": args,
-        })
-
-    payload = {"displayTimeUnit": "ms", "traceEvents": out}
-    return json.dumps(payload, sort_keys=True, separators=(",", ":"),
-                      default=repr)

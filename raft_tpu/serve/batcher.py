@@ -37,6 +37,7 @@ from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import numpy as np
 
+from raft_tpu import obs
 from raft_tpu.core import faults
 from raft_tpu.obs import trace as _trace
 from raft_tpu.serve.admission import (
@@ -324,28 +325,32 @@ class MicroBatcher:
         first request, then linger `max_wait_ms` (from that request's
         arrival) for more to coalesce — returning early once the merged
         rows reach the largest bucket. None when idle past the timeout
-        or closed-and-drained."""
+        or closed-and-drained. The three steps are the spans
+        `serve.wait`, `serve.linger` and `serve.take`."""
         with self._cond:
             deadline = None if timeout_s is None else time.monotonic() + timeout_s
-            while not self._dq:
-                if self._closed:
-                    return None
-                if deadline is None:
-                    self._cond.wait()
-                else:
-                    remaining = deadline - time.monotonic()
-                    if remaining <= 0 or not self._cond.wait(timeout=remaining):
+            with obs.span("serve.wait"):
+                while not self._dq:
+                    if self._closed:
                         return None
+                    if deadline is None:
+                        self._cond.wait()
+                    else:
+                        remaining = deadline - time.monotonic()
+                        if remaining <= 0 or not self._cond.wait(timeout=remaining):
+                            return None
             # linger window anchored at the oldest pending arrival so a
             # request never waits more than max_wait_ms for company
             linger_until = self._dq[0].submit_t + self.max_wait_s
-            while (self._pending_rows < self.max_batch
-                   and not self._closed):
-                remaining = linger_until - time.monotonic()
-                if remaining <= 0:
-                    break
-                self._cond.wait(timeout=remaining)
-            taken = self._take_locked(time.monotonic())
+            with obs.span("serve.linger"):
+                while (self._pending_rows < self.max_batch
+                       and not self._closed):
+                    remaining = linger_until - time.monotonic()
+                    if remaining <= 0:
+                        break
+                    self._cond.wait(timeout=remaining)
+            with obs.span("serve.take"):
+                taken = self._take_locked(time.monotonic())
         if not taken:
             return None
         return Batch(requests=taken, k=taken[0].k,

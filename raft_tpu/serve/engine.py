@@ -40,7 +40,6 @@ import jax
 
 from raft_tpu import obs
 from raft_tpu.core import faults
-from raft_tpu.core.tracing import trace_range
 from raft_tpu.obs import flight as _flight
 from raft_tpu.obs import trace as _trace
 from raft_tpu.serve.admission import (
@@ -662,7 +661,7 @@ class SearchServer:
         import time as _time
 
         compiled = 0
-        with trace_range("raft_tpu.serve.warmup"), obs.span("serve.warmup"):
+        with obs.span("serve.warmup"):
             for kk in sorted({int(k), *(int(x) for x in ks)}):
                 for bucket in self.batcher.buckets:
                     q = np.zeros((bucket, self.searcher.dim), np.float32)
@@ -712,12 +711,14 @@ class SearchServer:
         upsert/delete never touches the request path. Heal runs first:
         mutations defer while the mesh is degraded. The integrity
         watchdog ticks last (`Searcher.maybe_scrub`) so its slice hashes
-        the index the NEXT batch will actually serve."""
-        mh = getattr(self.searcher, "maybe_heal", None)
-        if mh is not None:
-            mh()
-        self.searcher.maybe_apply_mutations()
-        self.searcher.maybe_scrub()
+        the index the NEXT batch will actually serve. Span
+        `serve.maintain`."""
+        with obs.span("serve.maintain"):
+            mh = getattr(self.searcher, "maybe_heal", None)
+            if mh is not None:
+                mh()
+            self.searcher.maybe_apply_mutations()
+            self.searcher.maybe_scrub()
 
     def step(self, timeout_s: float = 0.0) -> int:
         """Single-thread test mode: collect one batch (no linger beyond
@@ -749,71 +750,77 @@ class SearchServer:
         return total
 
     def _dispatch(self, batch: Batch) -> None:
+        """One batch in spans, none per request: `serve.merge` (expiry,
+        bucket, pad), `serve.batch` with its children `serve.dispatch`
+        (the searcher call) and `serve.fence` (the wait for the device),
+        then `serve.scatter` (results to the host, replies, metrics)."""
         import time as _time
 
         # chaos site: a slow/flaky device dispatch (the serving analogue
         # of a straggling rank); no-op without an installed plan
         faults.fault_point(BATCH_SITE)
-        now = _time.monotonic()
-        live = []
-        for req in batch.requests:
-            # a request can expire between collection and dispatch (e.g.
-            # behind an injected slow batch) — still cheaper to drop now
-            # than to deliver a result its caller already abandoned
-            if self.admission.expired(req.deadline, now):
-                self.batcher._expire(req)
-            else:
-                live.append(req)
-        if not live:
-            return
-        batch = Batch(requests=live, k=batch.k,
-                      recall_target=batch.recall_target)
-        bucket = bucket_for(batch.rows, self.batcher.buckets)
-        padded, valid = merge(batch, self.searcher.dim, bucket)
-        scale = self.admission.probe_scale(self.batcher.pending_rows)
-        key = (bucket, batch.k,
-               self.searcher.probe_key(scale, batch.recall_target))
-        with self._compiled_lock:
-            cached = key in self._compiled
-        if obs.enabled():
-            obs.counter("serve.compile_cache.hit" if cached
-                        else "serve.compile_cache.miss").inc()
-            obs.event("compile", phase="serve", bucket=bucket, k=batch.k,
-                      cached=cached)
-        for req in batch.requests:
-            if req.trace is not None:
-                req.trace.stamp("dispatched", bucket=bucket, k=batch.k,
-                                cached=cached, probe=repr(key[2]))
-        with trace_range("raft_tpu.serve.batch"), \
-                obs.span("serve.batch", bucket=bucket, k=batch.k,
-                         rows=valid, pad_rows=bucket - valid,
-                         cached=cached):
-            vals, ids, coverage = self.searcher.search(
-                padded, batch.k, probe_scale=scale,
-                recall_target=batch.recall_target)
-            vals, ids = jax.block_until_ready((vals, ids))
-        # mark compiled only after the program actually ran: a failed
-        # dispatch must not fake a cache hit for the next batch
-        with self._compiled_lock:
-            self._compiled.add(key)
-        for req in batch.requests:
-            if req.trace is not None:
-                req.trace.stamp("fenced")
-        vals = np.asarray(vals)
-        ids = np.asarray(ids)
-        done_t = _time.monotonic()
-        outcome = "degraded" if float(coverage) < 1.0 else "ok"
-        latencies = []
-        for req, reply in scatter(batch, vals, ids, coverage):
-            if req.trace is not None:
-                req.trace.stamp("scattered", coverage=float(coverage))
-                _trace.complete(req.trace, outcome=outcome)
-            req.reply._set(reply)
-            latencies.append(done_t - req.submit_t)
-        self.metrics.observe_batch(
-            n_requests=len(batch.requests),
-            valid_rows=valid,
-            bucket_rows=bucket,
-            latencies_s=latencies,
-            coverage=coverage,
-        )
+        with obs.span("serve.merge"):
+            now = _time.monotonic()
+            live = []
+            for req in batch.requests:
+                # a request can expire between collection and dispatch (e.g.
+                # behind an injected slow batch) — still cheaper to drop now
+                # than to deliver a result its caller already abandoned
+                if self.admission.expired(req.deadline, now):
+                    self.batcher._expire(req)
+                else:
+                    live.append(req)
+            if not live:
+                return
+            batch = Batch(requests=live, k=batch.k,
+                          recall_target=batch.recall_target)
+            bucket = bucket_for(batch.rows, self.batcher.buckets)
+            padded, valid = merge(batch, self.searcher.dim, bucket)
+            scale = self.admission.probe_scale(self.batcher.pending_rows)
+            key = (bucket, batch.k,
+                   self.searcher.probe_key(scale, batch.recall_target))
+            with self._compiled_lock:
+                cached = key in self._compiled
+            if obs.enabled():
+                obs.counter("serve.compile_cache.hit" if cached
+                            else "serve.compile_cache.miss").inc()
+                obs.event("compile", phase="serve", bucket=bucket, k=batch.k,
+                          cached=cached)
+            for req in batch.requests:
+                if req.trace is not None:
+                    req.trace.stamp("dispatched", bucket=bucket, k=batch.k,
+                                    cached=cached, probe=repr(key[2]))
+        with obs.span("serve.batch", bucket=bucket, k=batch.k,
+                      rows=valid, pad_rows=bucket - valid, cached=cached):
+            with obs.span("serve.dispatch"):
+                vals, ids, coverage = self.searcher.search(
+                    padded, batch.k, probe_scale=scale,
+                    recall_target=batch.recall_target)
+            with obs.span("serve.fence"):
+                vals, ids = jax.block_until_ready((vals, ids))
+        with obs.span("serve.scatter"):
+            # mark compiled only after the program actually ran: a failed
+            # dispatch must not fake a cache hit for the next batch
+            with self._compiled_lock:
+                self._compiled.add(key)
+            for req in batch.requests:
+                if req.trace is not None:
+                    req.trace.stamp("fenced")
+            vals = np.asarray(vals)
+            ids = np.asarray(ids)
+            done_t = _time.monotonic()
+            outcome = "degraded" if float(coverage) < 1.0 else "ok"
+            latencies = []
+            for req, reply in scatter(batch, vals, ids, coverage):
+                if req.trace is not None:
+                    req.trace.stamp("scattered", coverage=float(coverage))
+                    _trace.complete(req.trace, outcome=outcome)
+                req.reply._set(reply)
+                latencies.append(done_t - req.submit_t)
+            self.metrics.observe_batch(
+                n_requests=len(batch.requests),
+                valid_rows=valid,
+                bucket_rows=bucket,
+                latencies_s=latencies,
+                coverage=coverage,
+            )

@@ -478,14 +478,17 @@ def test_chaos_drill_snapshot_exact(obs_on):
         ("serve", 4, 5, False),
     ]
 
-    # -- spans: the serving path nests under serve.batch
+    # -- spans: the serving path nests under serve.batch's dispatch
     span_evs = [e for e in snap["events"] if e["kind"] == "span"]
     serve_batches = [e for e in span_evs if e["name"] == "serve.batch"]
     assert len(serve_batches) == 3
+    dispatches = [e for e in span_evs if e["name"] == "serve.dispatch"]
+    assert [e["parent"] for e in dispatches] == ["serve.batch"] * 3
     knn_spans = [e for e in span_evs
                  if e["name"] == "neighbors.brute_force.knn"]
     assert len(knn_spans) == 5  # 2 warmup + 3 batches
-    assert {e["parent"] for e in knn_spans} == {"serve.warmup", "serve.batch"}
+    assert {e["parent"] for e in knn_spans} == {"serve.warmup",
+                                                "serve.dispatch"}
 
 
 @pytest.mark.parametrize("runs", [2])

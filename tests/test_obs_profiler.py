@@ -1,0 +1,233 @@
+"""The program's instrumentation on the profiler's clock: `obs.span`
+written into a `jax.profiler` trace, the garbage-collector hook, the
+serve worker's per-batch spans, the IVF-PQ build's stage spans, and the
+stage scopes inside the compiled search programs — and, with obs
+disabled, none of it (no profiler annotation, no clock, no sync)."""
+
+import gc
+import glob
+import importlib
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from raft_tpu import obs, serve
+from raft_tpu.distance.distance_types import DistanceType
+from raft_tpu.neighbors import brute_force, ivf_pq
+from raft_tpu.obs import spans
+
+refine_mod = importlib.import_module("raft_tpu.neighbors.refine")
+
+SERVE_STEPS = ("take", "merge", "batch", "dispatch", "fence", "scatter",
+               "maintain")
+
+
+@pytest.fixture
+def obs_on():
+    obs.reset()
+    obs.enable()
+    yield
+    obs.disable()
+    obs.reset()
+
+
+def _host_events(logdir):
+    from jax.profiler import ProfileData
+
+    (path,) = glob.glob(os.path.join(logdir, "**", "*.xplane.pb"),
+                        recursive=True)
+    pd = ProfileData.from_file(path)
+    return [(ev.name, dict(ev.stats)) for plane in pd.planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for ev in line.events]
+
+
+def _traced(tmp_path, fn):
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    return _host_events(str(tmp_path))
+
+
+@pytest.mark.parametrize("enabled", [True, False])
+def test_span_writes_profiler_event_only_when_enabled(tmp_path, enabled):
+    obs.reset()
+    obs.enable(enabled)
+    try:
+        def work():
+            with obs.span("drill.outer"):
+                with obs.span("drill.inner"):
+                    jax.block_until_ready(jnp.ones(4) + 1)
+        names = {n for n, _ in _traced(tmp_path, work)}
+    finally:
+        obs.disable()
+        obs.reset()
+    mine = {n for n in names if n.startswith("raft_tpu.")}
+    if enabled:
+        assert {"raft_tpu.drill.outer", "raft_tpu.drill.inner"} <= mine
+        assert obs.histogram("span.drill.outer").count == 0  # reset after
+    else:
+        assert not mine
+
+
+def test_disabled_sites_touch_no_profiler_clock_or_sync(monkeypatch):
+    """Disabled: `obs.span` (every new serve and build site), `settle`
+    and the GC hook call no TraceAnnotation, read no clock, add no sync."""
+    obs.disable()
+    calls = []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation",
+                        lambda *a, **k: calls.append("annotation"))
+    monkeypatch.setattr(spans.time, "monotonic",
+                        lambda: calls.append("clock") or 0.0)
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda x: calls.append("sync") or x)
+    with obs.span("serve.merge") as sp:
+        assert sp.settle("value") == "value"
+    assert obs._on_gc not in gc.callbacks
+    gc.collect()
+    assert calls == []
+
+
+def test_disabled_build_adds_no_sync(monkeypatch):
+    obs.disable()
+    x = np.random.default_rng(0).standard_normal((600, 8)).astype(np.float32)
+    params = ivf_pq.IndexParams(n_lists=4, pq_dim=4, kmeans_n_iters=2)
+    ivf_pq.build(params, x)  # compiles outside the count
+    syncs = []
+    real = jax.block_until_ready
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda v: syncs.append(1) or real(v))
+    ivf_pq.build(params, x)
+    assert syncs == []
+    obs.enable()
+    try:
+        ivf_pq.build(params, x)
+    finally:
+        obs.disable()
+        obs.reset()
+    assert len(syncs) == 3  # coarse, codebook, the whole build
+
+
+def test_gc_hook_records_collections_until_disabled(tmp_path, obs_on):
+    assert obs._on_gc in gc.callbacks
+    events = _traced(tmp_path / "on", lambda: gc.collect(2))
+    gcs = [stats for name, stats in events if name == "raft_tpu.gc"]
+    assert gcs and any(s.get("generation") == 2 for s in gcs)
+    obs.disable()
+    assert obs._on_gc not in gc.callbacks
+    events = _traced(tmp_path / "off", lambda: gc.collect(2))
+    assert not [name for name, _ in events if name == "raft_tpu.gc"]
+
+
+def test_serve_step_spans_once_per_batch(obs_on):
+    rng = np.random.default_rng(0)
+    data = rng.standard_normal((256, 16)).astype(np.float32)
+    server = serve.SearchServer(
+        data, serve.ServerConfig(buckets=(8,), max_wait_ms=0.0))
+    for _ in range(3):
+        fut = server.submit(rng.standard_normal((2, 16)).astype(np.float32),
+                            k=3)
+        assert server.step() == 1
+        fut.result(timeout=1.0)
+    for step in SERVE_STEPS:
+        assert obs.histogram(f"span.serve.{step}").count == 3, step
+    evs = [e for e in obs.snapshot()["events"] if e["kind"] == "span"]
+    parents = {(e["name"], e["parent"]) for e in evs}
+    assert ("serve.dispatch", "serve.batch") in parents
+    assert ("serve.fence", "serve.batch") in parents
+    assert ("serve.take", None) in parents
+    assert ("neighbors.brute_force.knn", "serve.dispatch") in parents
+
+
+def test_served_batch_spans_in_profiler_trace(tmp_path, obs_on):
+    rng = np.random.default_rng(0)
+    data = rng.standard_normal((256, 16)).astype(np.float32)
+    server = serve.SearchServer(
+        data, serve.ServerConfig(buckets=(8,), max_wait_ms=0.0))
+    server.warmup(3)
+
+    def work():
+        server.submit(rng.standard_normal((2, 16)).astype(np.float32), k=3)
+        server.step()
+    names = {n for n, _ in _traced(tmp_path, work)}
+    assert {f"raft_tpu.serve.{s}" for s in SERVE_STEPS} <= names
+
+
+def test_build_stage_spans_and_bit_identical_outputs(obs_on):
+    x = np.random.default_rng(1).standard_normal((800, 8)).astype(np.float32)
+    params = ivf_pq.IndexParams(n_lists=4, pq_dim=4, kmeans_n_iters=2)
+    on = ivf_pq.build(params, x)
+    for name in ("neighbors.ivf_pq.build", "ivf_pq.build.coarse",
+                 "ivf_pq.build.codebook", "neighbors.ivf_pq.extend"):
+        assert obs.histogram(f"span.{name}").count == 1, name
+    obs.disable()
+    off = ivf_pq.build(params, x)
+    for field in ("rotation", "centers", "pq_centers", "codes", "slot_rows",
+                  "list_sizes", "source_ids"):
+        np.testing.assert_array_equal(np.asarray(getattr(on, field)),
+                                      np.asarray(getattr(off, field)))
+
+
+def _stage_names(lowered):
+    return {s for s in ("ivf_pq.coarse", "ivf_pq.invert", "ivf_pq.scan",
+                        "ivf_pq.trim", "ivf_pq.merge", "refine",
+                        "brute_force.distance", "brute_force.select")
+            if f"{s}/" in lowered.as_text(debug_info=True)}
+
+
+@pytest.fixture(scope="module")
+def small_index():
+    rng = np.random.default_rng(2)
+    x = jnp.asarray(rng.standard_normal((2000, 16)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((32, 16)), jnp.float32)
+    index = ivf_pq.build(ivf_pq.IndexParams(n_lists=8, pq_dim=8,
+                                            kmeans_n_iters=2), x)
+    ivf_pq.build_reconstruction(index)
+    return x, q, index
+
+
+def test_listmajor_program_names_its_stages(small_index):
+    _, q, ix = small_index
+    lowered = ivf_pq._search_impl_recon8_listmajor.lower(
+        q, ix.rotation, ix.centers, ix.recon8, ix.recon_scale, ix.recon_norm,
+        ix.slot_rows_pad, 10, 4, DistanceType.L2Expanded)
+    assert _stage_names(lowered) == {"ivf_pq.coarse", "ivf_pq.invert",
+                                     "ivf_pq.scan", "ivf_pq.trim",
+                                     "ivf_pq.merge"}
+
+
+@pytest.mark.parametrize("engine", ["recon8", "lut"])
+def test_query_major_programs_name_their_stages(small_index, engine):
+    _, q, ix = small_index
+    L2 = DistanceType.L2Expanded
+    if engine == "recon8":
+        lowered = ivf_pq._search_impl_recon8.lower(
+            q, ix.rotation, ix.centers, ix.recon8, ix.recon_scale,
+            ix.recon_norm, ix.slot_rows_pad, 10, 4, L2)
+    else:
+        lowered = ivf_pq._search_impl.lower(
+            q, ix.rotation, ix.centers, ix.pq_centers, ix.codes,
+            ix.slot_rows, 10, 4, L2, False)
+    assert _stage_names(lowered) == {"ivf_pq.coarse", "ivf_pq.scan",
+                                     "ivf_pq.merge"}
+
+
+def test_refine_program_names_its_stage(small_index):
+    x, q, _ = small_index
+    lowered = refine_mod._refine_impl.lower(
+        x, q, jnp.zeros((32, 40), jnp.int32), 10, DistanceType.L2Expanded)
+    assert _stage_names(lowered) == {"refine"}
+
+
+@pytest.mark.parametrize("tile", [256, 1 << 15])  # tiled scan, one block
+def test_brute_force_program_names_its_stages(small_index, tile):
+    x, q, _ = small_index
+    lowered = brute_force._bf_knn_impl.lower(
+        x, q, 10, DistanceType.L2Expanded, tile=tile)
+    assert _stage_names(lowered) == {"brute_force.distance",
+                                     "brute_force.select"}

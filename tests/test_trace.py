@@ -1,7 +1,6 @@
 """Request-scope tracing, flight recorder, and SLO watchtower suite
 (ISSUE 18): exact deterministic trace pins over a step-mode serve run,
-Chrome trace-event export validity + byte-stability, telescoping stage
-accounting against measured e2e latency, flight-ring eviction and
+telescoping stage accounting against measured e2e latency, flight-ring eviction and
 atomic dumps (including a real SIGKILLed watchdog child), the two new
 chaos sites (`obs.flight.dump`, `serve.trace.stamp`) with their
 degrade-not-die contracts, and multi-window burn-rate math (fast trips
@@ -169,46 +168,6 @@ def test_stage_sum_covers_measured_e2e(obs_on, dataset):
         assert stage_sum == pytest.approx(
             marks["scattered"] - marks["admitted"])  # deltas telescope
         assert stage_sum >= 0.95 * e2e, (stage_sum, e2e)
-
-
-# ---------------------------------------------------------------------------
-# Chrome/Perfetto export
-# ---------------------------------------------------------------------------
-
-def test_chrome_trace_valid_and_byte_stable(obs_on, dataset):
-    with obs.span("drill.outer"):
-        _serve_three_batches(dataset)
-    one = obs.to_chrome_trace()
-    two = obs.to_chrome_trace()
-    assert one == two  # byte-identical across renders of the same bus
-    payload = json.loads(one)
-    assert payload["displayTimeUnit"] == "ms"
-    evs = payload["traceEvents"]
-    assert all(e["ph"] in ("M", "X") for e in evs)
-    xs = [e for e in evs if e["ph"] == "X"]
-    assert all(e["ts"] >= 0.0 and e["dur"] >= 0.0 for e in xs)
-    # stage segments on the worker track, named after the histograms
-    stage_names = {e["name"] for e in xs if e["pid"] == 1}
-    assert stage_names == {"queue_wait", "linger", "device", "scatter"}
-    # one whole-request event per request on the bucket-ladder track
-    reqs = [e for e in xs if e["pid"] == 2]
-    assert len(reqs) == 3
-    assert {e["name"] for e in reqs} == {
-        f"request {trace.trace_id(0, n):016x}" for n in (1, 2, 3)}
-    # span events land on the span track with their own duration
-    assert any(e["pid"] == 3 and e["name"] == "serve.batch" for e in xs)
-    # metadata rows name the tracks
-    metas = {(e["pid"], e["name"], e["args"]["name"])
-             for e in evs if e["ph"] == "M"}
-    assert (1, "process_name", "serve workers") in metas
-    assert (2, "process_name", "bucket ladder") in metas
-    assert (1, "thread_name", "MainThread") in metas
-    assert (2, "thread_name", "bucket=8") in metas
-
-
-def test_chrome_trace_empty_bus(obs_on):
-    payload = json.loads(obs.to_chrome_trace([]))
-    assert payload["traceEvents"] == []
 
 
 # ---------------------------------------------------------------------------
