@@ -69,16 +69,16 @@ class SearchParams:
       "list"  — list-major: probe pairs inverted into per-list chunks so
                 each list's vectors stream from HBM once per batch
                 (~nq*n_probes/n_lists x less gather traffic; best for
-                large query batches). Per-chunk candidate trimming uses
-                the TPU approximate top-k at recall_target=0.99; the
-                final per-query merge is exact.
+                large query batches). Each (query, list)'s candidates are
+                trimmed to their exact top-k (the select-k dispatch; the
+                TPU's partial-select TopK); the final per-query merge is
+                exact.
       "auto"  — "list" when the batch re-reads each list >= 4x, else
                 "query".
 
-    The default stays "query": IVF-Flat's contract is exact-within-probed-
-    lists (recall loss comes only from probing), and the list engine's
-    0.99-target chunk trim would bend that silently. Opt into "list"/"auto"
-    for batch-throughput workloads.
+    Both engines keep IVF-Flat's contract, exact-within-probed-lists
+    (recall loss comes only from probing). The default stays "query"; opt
+    into "list"/"auto" for batch-throughput workloads.
 
     Adaptive probing (neighbors/probe_budget, ROADMAP item 2): with
     `adaptive=True` (or a `recall_target` / explicit `budget_tau`) each
@@ -618,9 +618,9 @@ def _search_impl_listmajor(
     ~chunk probing queries and score with one MXU matmul — vs the
     query-major engine re-reading every probed list per query block
     (~nq*n_probes/n_lists x more gather traffic). Same candidate math; the
-    per-chunk trim uses the TPU approximate top-k (recall_target=0.99, like
-    the reference's filtered warp queues) and the final per-query merge is
-    exact. See neighbors/probe_invert.py for the pair-inversion scheme.
+    per-chunk trim keeps each (query, list)'s exact top-k and the final
+    per-query merge is exact. See neighbors/probe_invert.py for the
+    pair-inversion scheme.
     `pvalid` (adaptive probe budgets): masked pairs are dropped before
     inversion and masked again at the regroup."""
     from raft_tpu.distance.pairwise import _MATMUL_PRECISION

@@ -347,12 +347,11 @@ def score_and_select(
     chunk: int,
     chunk_block: int,
     max_list: int,
-    exact_trim: bool = False,
     *,
     stage: str,
 ):
     """Shared back half of a list-major search (traced inside the engine's
-    jit): two-level blocked scoring, per-superblock approximate trim,
+    jit): two-level blocked scoring, per-superblock exact trim,
     gather-based regroup to query-major, exact final merge — the stage
     scopes `<stage>.scan`, `<stage>.trim` and `<stage>.merge` in the
     program's op metadata (an op takes the innermost scope on its path).
@@ -361,14 +360,20 @@ def score_and_select(
     engine-specific candidate scores (IVF-Flat: raw-vector distances;
     IVF-PQ: int8-reconstruction distances) with invalid slots already
     masked to the worst value. `select_k_fn(scores, k, select_min)` is the
-    exact top-k used for the final merge.
+    exact 2-D top-k (the select-k dispatch) used for the trim and the
+    final merge.
 
     Superblocks of `sb` chunks bound the materialized score buffer to
-    ~2^27 elements regardless of max_list skew; each superblock is trimmed
-    with the TPU-native approximate top-k (PartialReduce,
-    jax.lax.approx_min_k) at recall_target=0.99 — the tradeoff the
-    reference makes with its warp-level filtered queues
-    (select_warpsort.cuh `warp_sort_filtered`).
+    ~2^27 elements regardless of max_list skew. Each superblock's
+    (chunk, max_list) rows are trimmed to their exact top-kk, ties to the
+    lower slot, through `select_k_fn` on a rank-2 (sb * chunk, max_list)
+    view: on the TPU that lowers to the partial-select `TopK` custom call,
+    where a rank-3 top-k (and `approx_min_k` whenever its reduction size
+    exceeds the row) lowers to a full sort of every row. The superblocks
+    are unrolled into the program rather than looped over, which keeps
+    each TopK in its fast layout; the score buffers are still bounded,
+    since XLA schedules the independent superblocks one after another,
+    and the engines' query macro-batches keep their count small.
 
     `chunk_block` controls the scoring granularity WITHIN a superblock:
     0 (the default dispatch) scores the whole superblock with one
@@ -409,20 +414,10 @@ def score_and_select(
         else:
             scores = block_fn((lofs, qids))
         with jax.named_scope(f"{stage}.trim"):
-            if exact_trim:
-                # exact per-superblock trim (lax.top_k): pays the full sort
-                # network but loses zero candidates — the option VERDICT r4
-                # #6 asks for, so the approx bin-trim's recall tax is a
-                # measured choice, not a forced one
-                if select_min:
-                    v, si = lax.top_k(-scores, kk)
-                    v = -v
-                else:
-                    v, si = lax.top_k(scores, kk)
-            elif select_min:
-                v, si = lax.approx_min_k(scores, kk, recall_target=0.99)
-            else:
-                v, si = lax.approx_max_k(scores, kk, recall_target=0.99)
+            # rank 2: the TPU lowers a rank-3 top-k to a full sort
+            v, si = select_k_fn(scores.reshape(sb * chunk, max_list), kk,
+                                select_min)
+            v, si = v.reshape(sb, chunk, kk), si.reshape(sb, chunk, kk)
             rows_sb = jnp.take_along_axis(slot_rows[lofs][:, None, :], si,
                                           axis=2)
         return v, rows_sb
@@ -433,11 +428,13 @@ def score_and_select(
             jnp.pad(qid_tbl, ((0, bpad), (0, 0)), constant_values=nq)
             if bpad else qid_tbl
         ).reshape(nsuper, sb, chunk)
-        # (nsuper, sb, chunk, kk)
-        vals, rows = lax.map(super_block, (lof_b, qid_b))
+        # unrolled, not lax.map: inside a loop body the TPU's TopK takes
+        # the row-major layout (slots on lanes), about 4x slower on the
+        # chip than the rows-on-lanes layout it gets outside one
+        outs = [super_block((lof_b[i], qid_b[i])) for i in range(nsuper)]
     with jax.named_scope(f"{stage}.merge"):
-        vals = vals.reshape(-1, chunk, kk)[:ncb]
-        rows = rows.reshape(-1, chunk, kk)[:ncb]
+        vals = jnp.concatenate([v for v, _ in outs])[:ncb]
+        rows = jnp.concatenate([r for _, r in outs])[:ncb]
         return regroup_merge(tables, vals, rows, select_k_fn, nq, n_probes,
                              k, select_min)
 

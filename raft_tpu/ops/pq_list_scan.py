@@ -5,7 +5,7 @@ detail/ivf_pq_search.cuh:611) fuses LUT scoring with an optional in-kernel
 warpsort top-k queue so per-candidate scores never leave the SM. The XLA
 list-major engine (neighbors/ivf_pq.py `_search_impl_recon8_listmajor`)
 must instead materialize each (chunk, max_list) score tile in HBM for
-`lax.approx_min_k` — at bench shape that round-trip is ~10x the byte
+its exact TopK trim — at bench shape that round-trip is ~10x the byte
 volume of the code stream it scores. This kernel is the TPU analogue of
 the reference's fused queue:
 
@@ -24,8 +24,8 @@ multiply. Invalid/padded slots arrive pre-masked to +inf in the `base`
 row operand. The selected candidates are the exact two minima of each
 lane-column class; a (chunk, 512) -> top-k pass outside the kernel
 (tiny) finishes the per-chunk trim. Only 3+ true top-k members landing
-in one bin can drop a candidate — a strictly smaller loss term than
-approx_min_k's — and the engine's exact final merge bounds the effect.
+in one bin can drop a candidate, where the XLA trim drops none, and
+the engine's exact final merge bounds the effect.
 
 Compiled-path status: validated in interpret mode (CPU tests); first
 on-chip Mosaic compile may need block-shape adjustments — the engine

@@ -583,27 +583,169 @@ def test_lut_fenced_on_tpu(dataset, index16, monkeypatch):
     assert np.asarray(i).shape == (4, 5)
 
 
-def test_exact_trim_engine(dataset, truth10, index16):
-    """trim_engine='exact' (per-superblock lax.top_k) loses zero
-    candidates: recall >= the approx bin-trim's on the same index."""
-    data, queries = dataset
-    p_ex = ivf_pq.SearchParams(
-        n_probes=16, score_mode="recon8_list", trim_engine="exact"
-    )
-    p_ap = ivf_pq.SearchParams(
-        n_probes=16, score_mode="recon8_list", trim_engine="approx"
-    )
-    d_ex, i_ex = ivf_pq.search(p_ex, index16, queries, 10)
-    _, i_ap = ivf_pq.search(p_ap, index16, queries, 10)
-    assert recall(i_ex, truth10) >= recall(i_ap, truth10) - 1e-9
-    # sorted best-first, ids valid
-    assert np.all(np.diff(np.asarray(d_ex), axis=1) >= -1e-5)
-    assert np.asarray(i_ex).min() >= 0
-    # exact trim requires the list-major engine
+@pytest.fixture(scope="module")
+def trim_data():
+    """3,000 rows in 64 lists (about 47 rows a list), so a probed list
+    can hold fewer rows than k = 40."""
+    data, _ = make_blobs(3000, 32, n_clusters=24, cluster_std=2.0, seed=41)
+    q, _ = make_blobs(50, 32, n_clusters=24, cluster_std=2.0, seed=42)
+    keep = np.random.default_rng(43).random(3000) < 0.15
+    return np.asarray(data), np.asarray(q), keep
+
+
+@pytest.fixture(scope="module")
+def trim_pq(trim_data):
+    return ivf_pq.build(ivf_pq.IndexParams(n_lists=64, pq_dim=16),
+                        trim_data[0])
+
+
+@pytest.fixture(scope="module")
+def trim_flat(trim_data):
+    from raft_tpu.neighbors import ivf_flat
+
+    return ivf_flat.build(ivf_flat.IndexParams(n_lists=64), trim_data[0])
+
+
+def _spy_trim(monkeypatch, jitted):
+    """Record, on the host, what the list-major trim sees: the pair
+    tables, the slot table and every scored block. `jitted` is cleared so
+    the next call traces through the spy."""
+    import jax
+
+    from raft_tpu.neighbors import probe_invert
+
+    rec = {"blocks": []}
+    orig = probe_invert.score_and_select
+
+    def spy(tables, block_fn, slot_rows, *args, **kw):
+        def block(inp):
+            s = block_fn(inp)
+            jax.debug.callback(
+                lambda *a: rec["blocks"].append([np.asarray(x) for x in a]),
+                inp[0], inp[1], s)
+            return s
+
+        jax.debug.callback(
+            lambda *a: rec.update(zip(("g0", "s0", "lof", "qid", "rows"),
+                                      [np.asarray(x) for x in a])),
+            tables.g0, tables.s0, tables.lof, tables.qid_tbl, slot_rows)
+        return orig(tables, block, slot_rows, *args, **kw)
+
+    monkeypatch.setattr(probe_invert, "score_and_select", spy)
+    jitted.clear_cache()
+    return rec
+
+
+def _trim_reference(rec, nq, n_probes, k, source_ids):
+    """Each (query, probed list)'s exact top-kk by (score, slot), merged
+    per query by (score, probe rank): the list-major contract in NumPy.
+    Also returns how many live pairs had fewer than kk real candidates."""
+    n_pad = len(rec["g0"]) // n_probes  # the pad id: the slice's size
+    rows_of = {}
+    for lofs, qids, s in rec["blocks"]:
+        for c, r in zip(*np.nonzero(qids != n_pad)):
+            rows_of[(int(lofs[c]), int(qids[c, r]))] = s[c, r].astype(
+                np.float32)
+    max_list = rec["rows"].shape[1]
+    kk = min(k, max_list)
+    slots = np.arange(max_list)
+    vals, ids, short = [], [], 0
+    for q in range(nq):
+        cv, cr = [], []
+        for p in range(n_probes):
+            c, r = rec["g0"][q * n_probes + p], rec["s0"][q * n_probes + p]
+            lst = int(rec["lof"][c])
+            assert rec["qid"][c, r] == q
+            sc = rows_of[(lst, q)]
+            short += int(np.isfinite(sc).sum() < kk)
+            top = np.lexsort((slots, sc))[:kk]
+            cv.append(sc[top])
+            cr.append(rec["rows"][lst][top])
+        cv, cr = np.concatenate(cv), np.concatenate(cr)
+        best = np.argsort(cv, kind="stable")[:k]
+        vals.append(cv[best])
+        ids.append(np.where(cr[best] >= 0,
+                            source_ids[np.maximum(cr[best], 0)], -1))
+    return np.stack(vals), np.stack(ids), short
+
+
+TRIM_CASES = {
+    # name: (index, internal_distance_dtype, k, prefilter); "pq16" is the
+    # shared 20,000-row index, on which recall is checked besides
+    "pq-bf16-k40": ("pq", "bfloat16", 40, False),
+    "pq-bf16-k10-prefilter": ("pq", "bfloat16", 10, True),
+    "pq-f32-k40": ("pq", "float32", 40, False),
+    "pq-f32-k10-prefilter": ("pq", "float32", 10, True),
+    "pq16-recall": ("pq16", "float32", 10, False),
+    "flat-k40": ("flat", "float32", 40, False),
+    "flat-k10-prefilter": ("flat", "float32", 10, True),
+}
+
+
+@pytest.mark.parametrize("case", list(TRIM_CASES))
+def test_listmajor_trim_is_exact(case, trim_data, trim_pq, trim_flat,
+                                 dataset, truth10, index16, monkeypatch):
+    """The list-major trim keeps each (query, probed list)'s exact top-kk,
+    ties to the lower slot, and the merge then takes the best k: the
+    answers equal a NumPy reference built from the engine's own scores,
+    including lists with fewer than kk live rows. Every IVF-PQ
+    trim_engine name that runs the XLA trim returns the same bits."""
+    from raft_tpu.neighbors import ivf_flat
+
+    which, idd, k, filtered = TRIM_CASES[case]
+    _, queries, keep = trim_data
+    prefilter = keep if filtered else None
+    index = {"pq": trim_pq, "pq16": index16, "flat": trim_flat}[which]
+    n_probes = 8
+    if which == "pq16":
+        queries, n_probes = dataset[1], 16
+    if which == "flat":
+        jitted = ivf_flat._search_impl_listmajor
+
+        def run(trim):
+            return ivf_flat.search(
+                ivf_flat.SearchParams(n_probes=n_probes, engine="list"),
+                index, queries, k, prefilter=prefilter)
+    else:
+        jitted = ivf_pq._search_impl_recon8_listmajor
+
+        def run(trim):
+            return ivf_pq.search(
+                ivf_pq.SearchParams(n_probes=n_probes,
+                                    score_mode="recon8_list",
+                                    internal_distance_dtype=idd,
+                                    trim_engine=trim),
+                index, queries, k, prefilter=prefilter)
+
+        d0, i0 = run("approx")
+        for trim in ("auto", "exact"):
+            d, i = run(trim)
+            np.testing.assert_array_equal(np.asarray(d), np.asarray(d0))
+            np.testing.assert_array_equal(np.asarray(i), np.asarray(i0))
+
+    rec = _spy_trim(monkeypatch, jitted)
+    try:
+        d, i = (np.asarray(x) for x in run("auto"))
+    finally:
+        monkeypatch.undo()
+        jitted.clear_cache()
+    ref_d, ref_i, short = _trim_reference(rec, len(queries), n_probes, k,
+                                          np.asarray(index.source_ids))
+    np.testing.assert_array_equal(i, ref_i)
+    np.testing.assert_array_equal(d, ref_d)
+    if filtered:
+        assert np.all(keep[i[i >= 0]])
+    if which != "pq16":
+        assert short > 0, "no probed list shorter than kk"
+        return
+    assert recall(i, truth10) >= 0.45  # measured 0.505
+    assert np.all(np.diff(d, axis=1) >= -1e-5)
+    assert i.min() >= 0
+    # "exact" still names the list-major engine's trim
     with pytest.raises(ValueError, match="exact"):
         ivf_pq.search(
             ivf_pq.SearchParams(score_mode="recon8", trim_engine="exact"),
-            index16, queries, 10,
+            index, queries, 10,
         )
 
 

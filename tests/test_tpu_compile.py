@@ -90,3 +90,32 @@ def test_kernel_compiles_for_v5e(one_chip, name):
             for s, dt in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_listmajor_trim_lowers_to_topk(one_chip):
+    """At the batch cell's widths (a 4,096-query slice, 1,024 lists x
+    2,944 slots, rot 96, int8 store, k = 40, 8 probes, bf16 trim) the
+    list-major trim compiles to the TPU's partial-select TopK over the
+    2,944-slot rows, each in the rows-on-lanes layout ({0,1}) it gets
+    outside a loop body, and no sort runs over that axis."""
+    import re
+
+    from raft_tpu.distance.distance_types import DistanceType
+    from raft_tpu.neighbors.ivf_pq import _search_impl_recon8_listmajor
+
+    slots = 2944
+    shapes = [((4096, D), f32), ((D, D), f32), ((N_LISTS, D), f32),
+              ((N_LISTS, slots, D), jnp.int8), ((D,), f32),
+              ((N_LISTS, slots), f32), ((N_LISTS, slots), i32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    txt = _search_impl_recon8_listmajor.lower(
+        *args, k=40, n_probes=8, metric=DistanceType.L2Expanded,
+        trim_bf16=True).compile().as_text()
+    defs = {name: (dims.split(",")[-1], layout) for name, dims, layout in
+            re.findall(r"(%[\w.-]+) = \w+\[([\d,]*)\]\{([\d,]*)", txt)}
+    operands = re.findall(
+        r"custom-call\((%[\w.-]+)\), custom_call_target=\"TopK\"", txt)
+    trims = [defs[o][1] for o in operands if defs[o][0] == str(slots)]
+    assert trims and all(layout == "0,1" for layout in trims), trims
+    assert not re.search(rf"\[[\d,]*\b{slots}\b[\d,]*\][^\n]* sort\(", txt)
