@@ -44,7 +44,7 @@ def main():
     args = ap.parse_args()
 
     from raft_tpu import obs
-    from raft_tpu.neighbors import brute_force, ivf_pq, ivf_rabitq
+    from raft_tpu.neighbors import brute_force, ivf_pq
 
     obs.enable()
 
@@ -125,22 +125,6 @@ def main():
         bank.add(rec, echo=False)
         return {"qps": rec.get("value")}
 
-    def rabitq_bitplane_fused(ctx):
-        # the fused RaBitQ bit-plane scan next to its XLA reference —
-        # popcount ops charged as integer ops against the "int" peak
-        idx = ivf_rabitq.build(
-            ivf_rabitq.IndexParams(n_lists=args.n_lists, kmeans_n_iters=4),
-            data)
-        sp = ivf_rabitq.SearchParams(n_probes=8, scan_engine="fused")
-        rec = run_case(
-            "perf_smoke",
-            f"rabitq_bitplane_fused_{args.rows}_q{args.queries}_k{args.k}"
-            "_probes8",
-            lambda: ivf_rabitq.search(sp, idx, q, args.k),
-            iters=3, warmup=1, items=float(args.queries), unit="qps")
-        bank.add(rec, echo=False)
-        return {"qps": rec.get("value")}
-
     geometry = {"rows": args.rows, "dim": args.dim,
                 "queries": args.queries, "k": args.k}
     env_dir = os.environ.get("RAFT_TPU_JOB_DIR", "").strip() or None
@@ -156,10 +140,6 @@ def main():
         job.add_stage("ivf_pq_int8_fused", pq_int8_fused,
                       inputs={**geometry, "n_lists": args.n_lists,
                               "engine": "fused_int8"},
-                      deadline_s=deadline_s)
-        job.add_stage("rabitq_bitplane_fused", rabitq_bitplane_fused,
-                      inputs={**geometry, "n_lists": args.n_lists,
-                              "engine": "fused_bitplane"},
                       deadline_s=deadline_s)
         # independent cases: one timed-out case must not zero the whole
         # sweep — bank what completes, then fail loudly below

@@ -4,8 +4,7 @@ fused distance+select-k kernel vs the materializing two-phase scan
 (`matrix.scan_select_k`), the measurement behind the tuned
 `select_k_strategy` key, and (ISSUE 11) the INTEGER-scan races behind
 `select_k_strategy_int8` (exact fused int8 PQ-recon trim vs the pallas
-bin trim) and `select_k_strategy_bitplane` (fused RaBitQ AND+popcount
-scan vs the XLA bit-plane reference).
+bin trim).
 
 Reference parity: matrix/detail/select_k.cuh:67-88 picks warpsort vs radix
 from an empirically-derived (batch, len, k) heuristic measured with
@@ -177,10 +176,7 @@ def main(smoke: bool = False):
     #     the int8 MXU path with identical quantization, so the race is
     #     purely the trim geometry; a fused sweep flips the tuned
     #     `select_k_strategy_int8` key.
-    # (b) RaBitQ bit-plane scan: the fused AND+popcount kernel vs the
-    #     XLA bit-plane reference (identical estimator scores); a fused
-    #     sweep flips `select_k_strategy_bitplane`.
-    from raft_tpu.neighbors import ivf_pq, ivf_rabitq
+    from raft_tpu.neighbors import ivf_pq
 
     int_winners = {}
     if smoke or interp:
@@ -225,35 +221,6 @@ def main(smoke: bool = False):
     })
     int_winners["pq_int8"] = (best[0], timings)
 
-    rb_idx = ivf_rabitq.build(
-        ivf_rabitq.IndexParams(n_lists=nl_i, kmeans_n_iters=4), data_i)
-    rb_entries = {
-        "bitplane_fused": ivf_rabitq.SearchParams(
-            n_probes=probes_i, scan_engine="fused"),
-        "bitplane_xla": ivf_rabitq.SearchParams(
-            n_probes=probes_i, scan_engine="xla"),
-    }
-    best = None
-    timings = {}
-    for name, sp in rb_entries.items():
-        rec = run_case(
-            "select_k_strategy",
-            f"rabitq_{name}_{nq_i}x{n_i}x{d_i}_k{k_i}",
-            lambda sp=sp: ivf_rabitq.search(sp, rb_idx, q_i, k_i),
-            items=float(nq_i),
-            unit="qps",
-        )
-        bank.record["rows"].append(rec)
-        bank.flush()
-        timings[name] = rec["value"]
-        if best is None or rec["value"] > best[1]:
-            best = (name, rec["value"])
-    bank.add({
-        "suite": "select_k_strategy",
-        "case": f"rabitq_winner_{nq_i}x{n_i}x{d_i}_k{k_i}",
-        "winner": best[0], "value": best[1], "unit": "qps",
-    })
-    int_winners["rabitq_bitplane"] = (best[0], timings)
     return winners, scan_winners, int_winners
 
 
@@ -305,18 +272,14 @@ def apply_winners(winners: dict, scan_winners: dict = None,
         }}
         if all(w == "fused" for w, _ in scan_winners.values()):
             updates["select_k_strategy"] = "fused"
-    # the integer-scan keys (ISSUE 11): each flips INDEPENDENTLY on its
-    # own race — the int8 trim and the bit-plane scan serve different
-    # engines, so one losing must not block the other's measured win.
-    # Chip data only (the smoke/CPU refusal above covers both).
+    # the int8 trim's key flips on its own race. Chip data only (the
+    # smoke/CPU refusal above).
     if int_winners:
         updates["hints"] = {**updates.get("hints", {}), **{
             f"int_scan_{kind}": w for kind, (w, _) in int_winners.items()
         }}
         if int_winners.get("pq_int8", (None,))[0] == "int8_fused":
             updates["select_k_strategy_int8"] = "fused_int8"
-        if int_winners.get("rabitq_bitplane", (None,))[0] == "bitplane_fused":
-            updates["select_k_strategy_bitplane"] = "fused_bitplane"
     tuned.merge(updates)
     print(json.dumps({"applied": tuned.path(),
                       "keys": [k for k in updates if k != "hints"]}))

@@ -73,14 +73,6 @@ class DistributedIvfRabitq:
         self.bridged = bridged
         self.extended = False  # no distributed extend yet (ROADMAP 5c)
         self.replicas = None  # see DistributedIvfFlat.replicas
-        # fused bit-plane scan's lazy per-rank derived store (see
-        # _build_distributed_bitplane): word-transposed lane-padded
-        # codes, per-slot estimator meta rows, padded gid table, and
-        # the monotonically-grown candidate-buffer width
-        self.codes_t = None
-        self.bp_meta = None
-        self.slot_gids_pad = None
-        self.fused_kb = None
         self._refine_cache = None
         self._id_bound = None
 
@@ -198,40 +190,13 @@ def ivf_rabitq_build(comms: Comms, params, dataset, seed: int = 0,
     ), replication)
 
 
-def _build_distributed_bitplane(index: DistributedIvfRabitq, k: int) -> None:
-    """Lazy per-rank derived store for the distributed fused bit-plane
-    scan (the RaBitQ analogue of `_build_distributed_recon`): the packed
-    sign codes word-transposed to (R, n_lists, W, L) with the slot axis
-    lane-padded, the (R, n_lists, 3, L) per-slot estimator meta rows
-    [popcount, |r|, <o, x_bar>], and a width-matched padded gid table —
-    all computed on the sharded arrays (XLA keeps everything
-    rank-local). `index.fused_kb` records the compiled candidate-buffer
-    width and grows monotonically (the shared invalidation contract)."""
-    from raft_tpu.neighbors.ivf_rabitq import derive_bitplane_tables
-    from raft_tpu.ops.fused_scan import fused_kbuf
-    from raft_tpu.ops.pq_list_scan import lane_padded
-
-    lpad = lane_padded(int(index.codes.shape[2]))
-    if index.codes_t is None or int(index.codes_t.shape[3]) != lpad:
-        # one shared derivation with the single-chip store (leading
-        # rank axis rides the ellipsis) — the kernel operand contract
-        # has exactly one author
-        index.codes_t, index.bp_meta, index.slot_gids_pad = (
-            derive_bitplane_tables(index.codes, index.aux,
-                                   index.slot_gids, lpad)
-        )
-    kb = fused_kbuf(int(k))
-    if index.fused_kb is None or kb > index.fused_kb:
-        index.fused_kb = kb
-
-
 @rank_captured("mnmg.ivf_rabitq_search")
 @obs.spanned("mnmg.ivf_rabitq_search")
 def ivf_rabitq_search(index: DistributedIvfRabitq, queries, k: int,
                       n_probes: int = 20, refine_dataset=None,
                       refine_mult: int = 4, prefilter=None,
                       query_mode: str = "auto", query_bits: int = 0,
-                      scan_engine: str = "auto", health=None,
+                      health=None,
                       adaptive: bool = False, recall_target=None,
                       budget_tau=None, min_probes: int = 1,
                       quantization: str = "auto"):
@@ -243,17 +208,10 @@ def ivf_rabitq_search(index: DistributedIvfRabitq, queries, k: int,
     each rank re-ranks its OWN candidates against its dataset shard, so
     the merged distances are exact. `prefilter`, `health`, replica
     failover and `DegradedSearchResult` behave exactly as in
-    `ivf_pq_search` (shared plumbing).
-
-    `scan_engine` mirrors the single-chip `SearchParams.scan_engine`:
-    "xla" (the materializing bit-plane reference), "fused" (the fused
-    AND+popcount scan per rank through the matrix/select_k dispatch —
-    explicit requests past the kernel's envelope raise), or "auto"
-    (fused only on the chip-measured tuned winner,
-    matrix/select_k.BITPLANE_SCAN_KEY)."""
+    `ivf_pq_search` (shared plumbing). Every rank runs the single-chip
+    bit-plane scan (`ivf_rabitq._search_impl_rabitq`) on its shard."""
     from raft_tpu.neighbors.ivf_rabitq import (
-        _search_impl_rabitq, _search_impl_rabitq_fused, rerank_depth,
-        resolve_query_bits,
+        _search_impl_rabitq, rerank_depth, resolve_query_bits,
     )
     from raft_tpu.neighbors.ivf_pq import _coarse_select  # noqa: F401 (doc)
     from raft_tpu.comms.mnmg_ivf_search import _refine_layout, _refine_local
@@ -277,36 +235,8 @@ def ivf_rabitq_search(index: DistributedIvfRabitq, queries, k: int,
     n_probes = int(min(n_probes, index.params.n_lists))
     qbits = resolve_query_bits(query_bits)
 
-    # scan-engine resolution through the dispatch layer (identical to
-    # the single-chip search: explicit "fused" raises past the
-    # envelope, "auto" promotes only on the tuned chip winner). The
-    # geometry is global across ranks, so every controller resolves the
-    # same engine — no rank diverges.
-    if scan_engine not in ("auto", "xla", "fused"):
-        raise ValueError(f"unknown scan_engine {scan_engine!r}")
-    from raft_tpu.matrix.select_k import (
-        check_bitplane_request, resolve_bitplane_strategy,
-    )
-    from raft_tpu.ops.fused_scan import FUSED_MAX_K, fused_kbuf
-    from raft_tpu.ops.pq_list_scan import lane_padded
-
     kk_depth = (rerank_depth(int(k), max(refine_mult, 1))
                 if refine_dataset is not None else int(k))
-    lpad = lane_padded(int(index.codes.shape[2]))
-    words = int(index.codes.shape[3])
-    if scan_engine == "fused":
-        fused_kb = check_bitplane_request(
-            "scan_engine='fused'", lpad, words, int(qbits), kk_depth,
-            index.fused_kb, "scan_engine='xla'",
-        )
-        strat = "fused_bitplane"
-    elif scan_engine == "auto" and 0 < kk_depth <= FUSED_MAX_K:
-        fused_kb = max(fused_kbuf(kk_depth), index.fused_kb or 0)
-        strat = resolve_bitplane_strategy(lpad, words, int(qbits),
-                                          kk_depth, kbuf=fused_kb)
-    else:
-        fused_kb, strat = None, "xla"
-    use_fused = strat == "fused_bitplane"
 
     # adaptive per-rank probe budgets (see ivf_flat_search: replicated
     # rotation/centers make one host-side plan the every-rank plan;
@@ -337,8 +267,7 @@ def ivf_rabitq_search(index: DistributedIvfRabitq, queries, k: int,
                        * index.codes.shape[2]),
             dim=int(index.centers.shape[-1]), k=int(k),
             query_bits=int(qbits),
-            rerank_mult=int(refine_mult) if refine_dataset is not None else 0,
-            fused=use_fused))
+            rerank_mult=int(refine_mult) if refine_dataset is not None else 0))
     mode = _resolve_query_mode(query_mode, comms, q.shape[0], k)
     live_rep, mode, coverage = _resolve_health(comms, health, query_mode, mode)
     nq = q.shape[0]
@@ -388,59 +317,6 @@ def ivf_rabitq_search(index: DistributedIvfRabitq, queries, k: int,
         v = faults.corrupt_in_trace(SCORES_SITE, v, rank)
         v, gid = _mask_dead_rank(v, gid, live, rank, worst)
         return merge(ac, v, gid, k, select_min, quant=qcfg)
-
-    if use_fused:
-        _build_distributed_bitplane(index, kk_depth)
-        fused_kb = index.fused_kb  # monotone: may exceed this call's kk
-        interp = jax.default_backend() == "cpu"
-        from raft_tpu.neighbors.probe_invert import resolve_setup_impls
-
-        setup_impls = resolve_setup_impls(
-            int(index.params.n_lists), engine="flat")
-
-        def build_run_fused():
-            @functools.partial(jax.jit, static_argnames=("k", "use_pf"))
-            def run(rotation, centers, codes_t, bp_meta, gid_tbl, q, xs,
-                    base, valid, bits, live, pv, k: int, use_pf: bool):
-                def body(rotation, centers, codes_t, bp_meta, gid_tbl, q,
-                         xs, base, valid, bits, live, pv):
-                    srows = _shard_filtered(gid_tbl[0], bits, pf_n, use_pf)
-                    v, gid = _search_impl_rabitq_fused(
-                        q, rotation, centers, codes_t[0], bp_meta[0],
-                        srows, kk, n_probes, metric, query_bits=qbits,
-                        kb=fused_kb, interpret=interp,
-                        setup_impls=setup_impls,
-                        pvalid=pv if adaptive_on else None,
-                    )
-                    return finish_body(v, gid, q, xs, base, valid, live)
-
-                return jax.shard_map(
-                    body, mesh=comms.mesh,
-                    in_specs=(P(None, None), P(None, None),
-                              P(comms.axis, None, None, None),
-                              P(comms.axis, None, None, None),
-                              P(comms.axis, None, None),
-                              P(None, None), P(comms.axis, None), P(None),
-                              P(None), P(None), P(None), P(None, None)),
-                    out_specs=(out_spec, out_spec), check_vma=False,
-                )(rotation, centers, codes_t, bp_meta, gid_tbl, q, xs,
-                  base, valid, bits, live, pv)
-
-            return run
-
-        run = _cached_wrapper(
-            wrapper_key(
-                "rabitq_fused", comms, mode, metric, int(k),
-                kk, n_probes, refine, pf_n, qbits, fused_kb, interp,
-                setup_impls, adaptive_on, qcfg),
-            build_run_fused,
-        )
-        v, gid = run(
-            index.rotation, index.centers, index.codes_t, index.bp_meta,
-            index.slot_gids_pad, qr, xs_r, base_rep, valid_rep, pf_bits,
-            live_rep, pv_rep, int(k), prefilter is not None,
-        )
-        return _pack_result(v, gid, nq, coverage, repaired)
 
     def build_run():
         @functools.partial(jax.jit, static_argnames=("k", "use_pf"))

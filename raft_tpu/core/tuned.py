@@ -105,9 +105,6 @@ TUNED_KEYS = {
         "kind": "choice", "choices": ("topk", "two_phase", "counting",
                                       "fused"),
         "bench": "bench/bench_select_k_strategies.py"},
-    "select_k_strategy_bitplane": {
-        "kind": "choice", "choices": ("fused_bitplane", "xla"),
-        "bench": "bench/bench_select_k_strategies.py"},
     "select_k_strategy_int8": {
         "kind": "choice", "choices": ("fused_int8",),
         "bench": "bench/bench_select_k_strategies.py"},
@@ -118,7 +115,6 @@ TUNED_KEYS = {
 #: `tuned-key-registry` flags any `*_KEY` constant whose value is not
 #: registered above).
 INT8_SCAN_KEY = "select_k_strategy_int8"
-BITPLANE_SCAN_KEY = "select_k_strategy_bitplane"
 POLICY_KEY = "adaptive_probe_policy"
 
 

@@ -26,7 +26,7 @@ from typing import Dict, List, Optional, Tuple
 
 import numpy as np
 
-from raft_tpu.core.serialize import crc32c
+from raft_tpu.core.serialize import crc32c, crc32c_rows
 
 # kind -> {serialized array field -> digest granularity}.
 # The sidecar fields themselves ("list_digests" array, "table_digests"
@@ -96,10 +96,10 @@ def _canon(field: str, arr) -> np.ndarray:
 
 def _row_digests(field: str, arr, rows) -> np.ndarray:
     a = _canon(field, arr)
-    out = np.empty(len(rows), np.uint32)
-    for j, i in enumerate(rows):
-        out[j] = crc32c(a[int(i)])
-    return out
+    idx = np.asarray(rows, np.int64).reshape(-1)
+    if idx.size == 0:
+        return np.empty(0, np.uint32)
+    return crc32c_rows(a[idx])
 
 
 def _table_digest(field: str, arr) -> int:

@@ -165,9 +165,7 @@ def mnmg_digests(index) -> Dict[str, np.ndarray]:
 
     out: Dict[str, np.ndarray] = {}
     for name in _replicated_attrs(index):
-        arr = np.ascontiguousarray(np.asarray(getattr(index, name)))
-        out[name] = np.asarray(
-            [digest.crc32c(arr[r]) for r in range(arr.shape[0])], np.uint32)
+        out[name] = digest.crc32c_rows(np.asarray(getattr(index, name)))
     return out
 
 

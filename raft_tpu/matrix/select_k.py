@@ -449,7 +449,7 @@ def scan_select_k(
 # list-scan dispatch: the IVF engines' fused kernels, one chooser
 # ---------------------------------------------------------------------------
 #
-# The IVF list-major engines (ivf_flat / ivf_pq / ivf_rabitq and their
+# The IVF list-major engines (ivf_flat / ivf_pq and their
 # MNMG drivers) never import a kernel from ops directly — they ask THIS
 # layer for a per-list fused scan+select. Strategy names extend the
 # scan_select_k family onto the integer datapath (ISSUE 11):
@@ -457,25 +457,20 @@ def scan_select_k(
 #   "fused"          bf16 MXU scoring (the PR-10 family)
 #   "fused_int8"     int8 x int8 -> int32 MXU scoring, per-row dequant
 #                    (v5e: 394 int8 TOPS vs 197 bf16 TFLOP/s)
-#   "fused_bitplane" uint32 AND+popcount RaBitQ bit-plane scoring with
-#                    the unbiased estimator correction in-kernel
 #
-# Tuned promotion mirrors `select_k_strategy`: each integer family has
-# its own measured key (flipped by bench_select_k_strategies --apply on
-# chip data only), consulted ONLY by auto resolution — explicit
-# strategies always win, and an explicit request past the kernel's
-# envelope raises instead of silently falling back.
+# Tuned promotion mirrors `select_k_strategy` for the int8 family: its
+# own measured key (flipped by bench_select_k_strategies --apply on chip
+# data only), consulted ONLY by auto resolution. Explicit strategies
+# always win, and an explicit request past the kernel's envelope raises
+# instead of silently falling back.
 
 #: strategies the list-scan dispatch accepts
 LIST_SCAN_STRATEGIES = ("fused", "fused_int8")
 
-#: tuned keys promoting the integer fused scans — re-exported from the
-#: ONE registry spelling (core.tuned.TUNED_KEYS; raftlint's
+#: tuned key promoting the int8 fused scan — re-exported from the ONE
+#: registry spelling (core.tuned.TUNED_KEYS; raftlint's
 #: `tuned-key-registry` pins every `*_KEY` constant to it)
-from raft_tpu.core.tuned import (  # noqa: E402
-    BITPLANE_SCAN_KEY,
-    INT8_SCAN_KEY,
-)
+from raft_tpu.core.tuned import INT8_SCAN_KEY  # noqa: E402
 
 
 def resolve_int8_trim_strategy(L: int, rot: int, k: int,
@@ -503,31 +498,6 @@ def resolve_int8_trim_strategy(L: int, rot: int, k: int,
     return None
 
 
-def resolve_bitplane_strategy(L: int, words: int, bits: int, k: int,
-                              kbuf: Optional[int] = None,
-                              strategy: Optional[str] = None) -> str:
-    """Resolve the RaBitQ scan engine: "xla" is the materializing
-    bit-plane reference (`_search_impl_rabitq`); "fused_bitplane" the
-    in-kernel scan. Explicit wins (the call site validates the envelope
-    and raises past it); None/"auto" promotes fused only on a tuned
-    chip-measured winner where the kernel fits."""
-    if strategy in ("xla", "fused_bitplane"):
-        return strategy
-    if strategy not in (None, "auto"):
-        raise ValueError(f"unknown bitplane scan strategy {strategy!r}")
-    from raft_tpu.core import tuned
-
-    if tuned.get(BITPLANE_SCAN_KEY) != "fused_bitplane":
-        return "xla"
-    from raft_tpu.core.config import is_tpu_backend
-    from raft_tpu.ops.fused_scan import fits_fused_bitplane
-
-    if is_tpu_backend() and fits_fused_bitplane(128, L, words, bits,
-                                                int(k), kbuf=kbuf):
-        return "fused_bitplane"
-    return "xla"
-
-
 def check_fused_list_request(label: str, L: int, rot: int, k: int,
                              store_itemsize: int, kbuf: Optional[int],
                              fallback: str) -> int:
@@ -547,29 +517,6 @@ def check_fused_list_request(label: str, L: int, rot: int, k: int,
     kb = max(fused_kbuf(int(k)), kbuf or 0)
     if not fits_fused_list(128, L, rot, int(k),
                            store_itemsize=store_itemsize, kbuf=kb):
-        raise ValueError(
-            f"{label}: list length {L} exceeds the kernel's VMEM "
-            f"envelope; use {fallback}"
-        )
-    return kb
-
-
-def check_bitplane_request(label: str, L: int, words: int, bits: int,
-                           k: int, kbuf: Optional[int],
-                           fallback: str) -> int:
-    """`check_fused_list_request` for the bit-plane geometry (the
-    RaBitQ scan engines, single-chip and MNMG)."""
-    from raft_tpu.ops.fused_scan import (
-        FUSED_MAX_K, fits_fused_bitplane, fused_kbuf,
-    )
-
-    if int(k) > FUSED_MAX_K:
-        raise ValueError(
-            f"{label} caps scan candidates at {FUSED_MAX_K}; "
-            f"rerank depth {k}"
-        )
-    kb = max(fused_kbuf(int(k)), kbuf or 0)
-    if not fits_fused_bitplane(128, L, words, int(bits), int(k), kbuf=kb):
         raise ValueError(
             f"{label}: list length {L} exceeds the kernel's VMEM "
             f"envelope; use {fallback}"
@@ -615,31 +562,6 @@ def list_scan_select_k(
 
     return fused_list_topk(
         lof, qres, store, base, int(k), kbuf=kbuf,
-        inner_product=inner_product, interpret=interpret,
-        fault_key=fault_key, chunk_valid=chunk_valid,
-    )
-
-
-def bitplane_scan_select_k(
-    lof, planes, codes_t, meta, base, qmeta, k: int,
-    rot_dim: int,
-    bits: int,
-    kbuf: Optional[int] = None,
-    inner_product: bool = False,
-    interpret: bool = False,
-    fault_key=None,
-    chunk_valid=None,
-) -> Tuple[jax.Array, jax.Array]:
-    """The RaBitQ bit-plane fused scan+select (strategy
-    "fused_bitplane") — operand contract of
-    `ops.fused_scan.fused_bitplane_topk`, reached through this layer so
-    the kernel has exactly one consumer-facing door. `chunk_valid`:
-    the empty-chunk skip path (see `list_scan_select_k`)."""
-    from raft_tpu.ops.fused_scan import fused_bitplane_topk
-
-    return fused_bitplane_topk(
-        lof, planes, codes_t, meta, base, qmeta, int(k),
-        rot_dim=int(rot_dim), bits=int(bits), kbuf=kbuf,
         inner_product=inner_product, interpret=interpret,
         fault_key=fault_key, chunk_valid=chunk_valid,
     )

@@ -73,9 +73,12 @@ from raft_tpu.neighbors.quantizer import (
 #: flaky encode pass degrades latency, never results)
 ENCODE_SITE = "ivf_rabitq.build.encode"
 
-#: exact-rerank gather cap, matching the distributed refine's 256-row
-#: shortlist cap (mnmg_ivf_search) so serve/MNMG depths agree
-_MAX_RERANK = 256
+#: exact-rerank depth cap, shared by the single-chip and distributed
+#: searches (`rerank_depth`). Refine gathers the shortlist a block of
+#: queries at a time, so the cap bounds work, not memory: 1-bit codes of
+#: 768-d rows need a few hundred re-ranked rows where near neighbours
+#: lie within a few percent of each other's distances
+_MAX_RERANK = 1024
 
 
 @dataclasses.dataclass
@@ -108,37 +111,17 @@ class SearchParams:
                  ("rabitq_query_bits") when a chip profile wrote one,
                  else 8.
     rerank_mult  exact-rerank depth multiplier: the scan keeps
-                 rerank_mult * k candidates (capped at 256) for the
+                 rerank_mult * k candidates (capped at 1,024) for the
                  refine stage; 0 = auto — tuned key
                  ("rabitq_rerank_mult"), else 4. Rerank engages whenever
                  original rows are available (index.dataset or
                  refine_dataset); without them the estimator ranking is
                  returned directly.
-    scan_engine  bit-plane scan implementation:
-                 "xla"   — the materializing reference scan
-                           (`_search_impl_rabitq`: gather + AND+popcount
-                           in XLA).
-                 "fused" — the fused bit-plane list scan (ISSUE 11):
-                           matrix/select_k.bitplane_scan_select_k runs
-                           AND+popcount scoring AND the exact partial
-                           top-k inside one kernel, with the unbiased
-                           estimator correction applied in-kernel — the
-                           candidate bit planes never materialize in
-                           HBM, only (queries, rerank_mult*k) survivors
-                           flow to the exact rerank. Same integer
-                           scores, explicit requests past the kernel's
-                           envelope raise.
-                 "auto"  — "xla" unless the measured tuned key
-                           (matrix/select_k.BITPLANE_SCAN_KEY, flipped
-                           by bench_select_k_strategies --apply on chip
-                           data) promotes the fused scan where the
-                           geometry fits.
     """
 
     n_probes: int = 20
     query_bits: int = 0
     rerank_mult: int = 0
-    scan_engine: str = "auto"
     # -- adaptive probing (neighbors/probe_budget, ROADMAP item 2) --
     # per-query probe budgets from the rotated coarse gap profile;
     # early-termination bounds come FREE here — the aux table already
@@ -197,15 +180,6 @@ class Index:
         # raw rows in insertion order (store_dataset=True) — the rerank
         # stage's gather source; None on loaded / quantized-only indexes
         self.dataset = dataset
-        # fused bit-plane scan's derived store (build_bitplane_store):
-        # codes_t (n_lists, W, L) word-transposed lane-padded uint32,
-        # bp_meta (n_lists, 3, L) f32 [popcount, |r|, <o,x_bar>],
-        # slot_rows_pad (n_lists, L) int32 (-1 pads), fused_kb the
-        # monotonically-grown candidate-buffer width (ivf_flat contract)
-        self.codes_t = None
-        self.bp_meta = None
-        self.slot_rows_pad = None
-        self.fused_kb = None
         # adaptive probing's per-list radii, derived lazily from the
         # aux table's stored |r| column (extend returns a new Index,
         # so the cache can never go stale)
@@ -213,7 +187,7 @@ class Index:
         # live-mutation state (neighbors/mutation): optional dead-row
         # mask (n_lists, max_list; nonzero = dead, None = all-live),
         # applied-log cursor at the last checkpoint commit, reserved
-        # per-list append slack. Masked into slot_rows/slot_rows_pad by
+        # per-list append slack. Masked into slot_rows by
         # `core.bitset.make_slot_filter` (pad-aware).
         self.tombstones = None
         self.mut_cursor = 0
@@ -327,50 +301,62 @@ def label_and_encode(vectors, rotation, centers, metric: DistanceType):
     return labels, codes, aux
 
 
-@obs.spanned("neighbors.ivf_rabitq.build")
 def build(params: IndexParams, dataset, resources=None, seed: int = 0) -> Index:
     """Train rotation + coarse centers, then encode + pack lists. No
     codebook stage — the build is coarse-kmeans-bound, the fast-build
     half of the RaBitQ paper (measured vs IVF-PQ in
-    bench/bench_ivf_rabitq.py)."""
-    from raft_tpu.core.validation import check_matrix
+    bench/bench_ivf_rabitq.py).
 
-    x = check_matrix(dataset, name="dataset").astype(jnp.float32)
-    n, dim = x.shape
-    if params.n_lists > n:
-        raise ValueError(f"n_lists={params.n_lists} > dataset rows {n}")
-    rot_dim = rabitq_rot_dim(dim)
-    key = jax.random.PRNGKey(seed)
-    key, rk = jax.random.split(key)
-    # always a random rotation: sign binarization is only unbiased under
-    # an isotropic basis (identity would bias toward axis-aligned data)
-    rotation = _make_rotation(rk, rot_dim, dim, True)
+    Spans, with obs enabled: `neighbors.ivf_rabitq.build` around it
+    all, `ivf_rabitq.build.coarse` (trainset, balanced k-means) and,
+    inside `neighbors.ivf_rabitq.extend`, `ivf_rabitq.build.encode`
+    (rotate, label, sign-encode). Each blocks on its outputs as it
+    closes, so its time is device time; disabled, the build adds no
+    sync."""
+    with obs.span("neighbors.ivf_rabitq.build") as sp:
+        from raft_tpu.core.validation import check_matrix
 
-    # the ONE single-chip coarse-fit scaffolding shared with ivf_pq.build
-    # — and the whole training: no codebook stage follows
-    from raft_tpu.neighbors.ivf_pq import _coarse_fit
+        x = check_matrix(dataset, name="dataset").astype(jnp.float32)
+        n, dim = x.shape
+        if params.n_lists > n:
+            raise ValueError(f"n_lists={params.n_lists} > dataset rows {n}")
+        rot_dim = rabitq_rot_dim(dim)
+        key = jax.random.PRNGKey(seed)
+        key, rk = jax.random.split(key)
+        # always a random rotation: sign binarization is only unbiased
+        # under an isotropic basis (identity would bias toward
+        # axis-aligned data)
+        rotation = _make_rotation(rk, rot_dim, dim, True)
 
-    centers, _, key = _coarse_fit(params, x, rotation, key, seed)
+        # the ONE single-chip coarse-fit scaffolding shared with
+        # ivf_pq.build — and the whole training: no codebook stage follows
+        from raft_tpu.neighbors.ivf_pq import _coarse_fit
 
-    W = packed_words(rot_dim)
-    index = Index(
-        params,
-        rotation,
-        centers,
-        jnp.zeros((params.n_lists, 1, W), jnp.uint32),
-        jnp.zeros((params.n_lists, 1, 2), jnp.float32),
-        jnp.full((params.n_lists, 1), -1, jnp.int32),
-        jnp.zeros((params.n_lists,), jnp.int32),
-        jnp.zeros((0,), jnp.int32),
-    )
-    if params.add_data_on_build:
-        index = extend(index, x, jnp.arange(n, dtype=jnp.int32))
-    # build-time integrity sidecar (kept fresh incrementally after)
-    from raft_tpu.integrity.digest import attach as _attach_digests
+        with obs.span("ivf_rabitq.build.coarse") as st:
+            centers, _, key = st.settle(
+                _coarse_fit(params, x, rotation, key, seed))
 
-    _attach_digests(index, "ivf_rabitq")
-    if resources is not None:
-        resources.track(index.codes)
+        W = packed_words(rot_dim)
+        index = Index(
+            params,
+            rotation,
+            centers,
+            jnp.zeros((params.n_lists, 1, W), jnp.uint32),
+            jnp.zeros((params.n_lists, 1, 2), jnp.float32),
+            jnp.full((params.n_lists, 1), -1, jnp.int32),
+            jnp.zeros((params.n_lists,), jnp.int32),
+            jnp.zeros((0,), jnp.int32),
+        )
+        if params.add_data_on_build:
+            index = extend(index, x, jnp.arange(n, dtype=jnp.int32))
+        # build-time integrity sidecar (kept fresh incrementally after)
+        from raft_tpu.integrity.digest import attach as _attach_digests
+
+        _attach_digests(index, "ivf_rabitq")
+        if resources is not None:
+            resources.track(index.codes)
+        sp.settle([v for v in vars(index).values()
+                   if isinstance(v, jax.Array)])
     return index
 
 
@@ -391,17 +377,18 @@ def extend(index: Index, new_vectors, new_indices=None) -> Index:
     # chaos site (host-side, per call): slow_rank models a slow encode
     # pass — latency only, results untouched; flaky_bootstrap a
     # transient dispatch failure retried by callers
-    faults.fault_point(ENCODE_SITE)
-    labels, new_codes, new_aux = label_and_encode(
-        nv, index.rotation, index.centers, index.metric
-    )
+    with obs.span("ivf_rabitq.build.encode") as st:
+        faults.fault_point(ENCODE_SITE)
+        labels, new_codes, new_aux = st.settle(label_and_encode(
+            nv, index.rotation, index.centers, index.metric
+        ))
 
     labels_np = np.asarray(labels, np.int64)
     old_sizes = np.asarray(index.list_sizes, np.int64)
     slot_abs, new_sizes, new_max = _append_slots(labels_np, old_sizes,
                                                  index.n_lists)
-    # a store padded wider than the sizes imply (fused-engine lanes,
-    # mutation append slack) must never shrink — slots stay where they are
+    # a store padded wider than the sizes imply (mutation append slack)
+    # must never shrink — slots stay where they are
     new_max = max(new_max, int(index.slot_rows.shape[1]))
     positions = jnp.arange(old_n, old_n + nv.shape[0], dtype=jnp.int32)
     # one shared placement sort grows BOTH payload tables
@@ -449,9 +436,13 @@ def extend(index: Index, new_vectors, new_indices=None) -> Index:
 
 def _rabitq_query_block(n_probes: int, max_list: int, query_bits: int,
                         words: int) -> int:
-    # keep the (qb, np, max_list, bits, W) popcount intersection tensor
-    # ~<= 2^22 int32 elements (16MB) — the scan's dominant intermediate
-    qb = max(1, (1 << 22) // max(1, n_probes * max_list * query_bits * words))
+    # the (qb, np, max_list, bits, W) popcount intersection tensor, kept
+    # ~<= 2^26 int32 elements; XLA fuses it into the per-slot sums, so a
+    # block's temp does not grow with qb (0.97 GB at qb 1 and at qb 16
+    # for 768-d, 16 probes of 2,016 slots, compiled for a v5e), while
+    # each step of the block loop runs a whole top-k: at those widths
+    # 2^22 would give one query a step, 10,000 steps a call
+    qb = max(1, (1 << 26) // max(1, n_probes * max_list * query_bits * words))
     return int(min(qb, 16))
 
 
@@ -487,7 +478,9 @@ def _search_impl_rabitq(
     ip = metric == DistanceType.InnerProduct
     worst = jnp.inf if select_min else -jnp.inf
 
-    q_rot, probes = _coarse_select(queries, rotation, centers, n_probes, metric)
+    with jax.named_scope("ivf_rabitq.coarse"):
+        q_rot, probes = _coarse_select(queries, rotation, centers, n_probes,
+                                       metric)
     rnorm = aux[..., 0]
     o_dot = aux[..., 1]
     # a candidate depth beyond the probed width selects everything there
@@ -543,192 +536,32 @@ def _search_impl_rabitq(
         rows = rows.reshape(qb, -1)
         scores = scores.reshape(qb, -1)
         scores = jnp.where(rows >= 0, scores, worst)
-        v, pos = _select_k_impl(scores, k_sel, select_min)
-        r = jnp.take_along_axis(rows, pos, axis=1)
-        if k_sel < k:  # pad the tail: worst score, row -1 (static shapes)
-            v = jnp.pad(v, ((0, 0), (0, k - k_sel)), constant_values=worst)
-            r = jnp.pad(r, ((0, 0), (0, k - k_sel)), constant_values=-1)
+        with jax.named_scope("ivf_rabitq.merge"):
+            v, pos = _select_k_impl(scores, k_sel, select_min)
+            r = jnp.take_along_axis(rows, pos, axis=1)
+            if k_sel < k:  # pad the tail: worst score, row -1 (static)
+                v = jnp.pad(v, ((0, 0), (0, k - k_sel)),
+                            constant_values=worst)
+                r = jnp.pad(r, ((0, 0), (0, k - k_sel)), constant_values=-1)
         return v, r
 
-    vals, rows = lax.map(
-        block,
-        (qblocks, pblocks, pvblocks) if pvalid is not None
-        else (qblocks, pblocks))
-    vals = vals.reshape(-1, k)[:nq]
-    rows = rows.reshape(-1, k)[:nq]
-    if metric == DistanceType.L2SqrtExpanded:
-        vals = jnp.sqrt(jnp.maximum(vals, 0.0))
+    with jax.named_scope("ivf_rabitq.scan"):
+        vals, rows = lax.map(
+            block,
+            (qblocks, pblocks, pvblocks) if pvalid is not None
+            else (qblocks, pblocks))
+    with jax.named_scope("ivf_rabitq.merge"):
+        vals = vals.reshape(-1, k)[:nq]
+        rows = rows.reshape(-1, k)[:nq]
+        if metric == DistanceType.L2SqrtExpanded:
+            vals = jnp.sqrt(jnp.maximum(vals, 0.0))
     return vals, rows
 
 
 def rerank_depth(k: int, rerank_mult: int) -> int:
     """Candidate depth the scan keeps for the exact rerank: never below
-    k, capped at the shared 256-row gather bound (the distributed
-    refine's shortlist cap)."""
+    k, capped at `_MAX_RERANK`."""
     return max(int(k), min(int(rerank_mult) * int(k), _MAX_RERANK))
-
-
-def derive_bitplane_tables(codes, aux, slot_table, lpad: int):
-    """The fused bit-plane store derivation — ONE recipe shared by the
-    single-chip builder and the distributed per-rank builder
-    (`mnmg_rabitq._build_distributed_bitplane`), over arbitrary leading
-    axes: lane-pad the slot axis to `lpad`, word-TRANSPOSE the packed
-    codes (L onto the 128-lane register axis), and stack the per-slot
-    estimator meta rows [popcount(code), |r|, <o, x_bar>] the kernel's
-    operand contract depends on. Pad slots carry zero codes/meta and
-    slot value -1. The two stores cannot drift because they both call
-    here.
-
-    codes (..., S, W) uint32, aux (..., S, 2) f32, slot_table (..., S)
-    -> (codes_t (..., W, L), meta (..., 3, L), slots_pad (..., L))."""
-    extra = lpad - int(codes.shape[-2])
-    pad3 = [(0, 0)] * (codes.ndim - 2) + [(0, extra), (0, 0)]
-    codes_p = jnp.pad(codes, pad3)
-    aux_p = jnp.pad(aux, pad3)
-    codes_t = jnp.swapaxes(codes_p, -1, -2)
-    # per-slot set-bit counts: the SAME popcount-and-sum the XLA
-    # reference computes per probed row, hoisted to build time (it is
-    # query-independent) — pad slots popcount 0
-    pop = jnp.sum(
-        lax.population_count(codes_p).astype(jnp.int32), axis=-1
-    ).astype(jnp.float32)
-    meta = jnp.stack([pop, aux_p[..., 0], aux_p[..., 1]], axis=-2)
-    slots_pad = jnp.pad(
-        slot_table, [(0, 0)] * (slot_table.ndim - 1) + [(0, extra)],
-        constant_values=-1,
-    )
-    return codes_t, meta, slots_pad
-
-
-def build_bitplane_store(index: Index, k: int) -> None:
-    """Populate the fused bit-plane scan's derived store: the packed
-    sign codes word-TRANSPOSED to (n_lists, W, L) with the slot axis
-    lane-padded (L on the 128-lane register axis — the kernel
-    broadcasts each code word row against the query's plane column),
-    plus the (n_lists, 3, L) per-slot estimator meta rows
-    [popcount(code), |r|, <o, x_bar>] the in-kernel correction reads.
-    Pad slots carry zero codes / zero meta and slot_rows_pad -1, so the
-    per-call +inf base masks them before selection.
-
-    `k` sizes the compiled candidate-buffer width (`Index.fused_kb`,
-    ops/fused_scan.fused_kbuf): monotone growth, exactly the ivf_flat
-    lazy-store invalidation contract — a narrower compiled buffer on a
-    later larger-k search would silently truncate per-list candidates."""
-    from raft_tpu.ops.fused_scan import fused_kbuf
-    from raft_tpu.ops.pq_list_scan import lane_padded
-
-    lpad = lane_padded(int(index.codes.shape[1]))
-    if index.codes_t is None or int(index.codes_t.shape[2]) != lpad:
-        index.codes_t, index.bp_meta, index.slot_rows_pad = (
-            derive_bitplane_tables(index.codes, index.aux,
-                                   index.slot_rows, lpad)
-        )
-    kb = fused_kbuf(int(k))
-    if index.fused_kb is None or kb > index.fused_kb:
-        index.fused_kb = kb
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("k", "n_probes", "metric", "query_bits", "chunk",
-                     "kb", "interpret", "setup_impls", "fault_key"),
-)
-def _search_impl_rabitq_fused(
-    queries,
-    rotation,
-    centers,
-    codes_t,
-    bp_meta,
-    slot_rows_pad,
-    k: int,
-    n_probes: int,
-    metric: DistanceType,
-    query_bits: int = DEFAULT_QUERY_BITS,
-    chunk: int = 128,
-    kb: int = None,
-    interpret: bool = False,
-    setup_impls: tuple = ("sort", "gather"),
-    fault_key=None,
-    pvalid: jax.Array = None,
-):
-    """List-major bit-plane search with the fused scan+select kernel
-    (matrix/select_k.bitplane_scan_select_k): probe pairs invert to
-    per-list chunks (the shared `probe_invert` machinery), each chunk's
-    query residuals quantize to bit planes through the SAME
-    `quantizer.quantize_queries` the XLA reference uses, and one kernel
-    per chunk runs AND+popcount scoring, the unbiased estimator
-    correction, AND the exact partial top-k — per-(query, slot)
-    estimator scores are computed with the reference's exact op order
-    (integer bit-plane sums are associative; the f32 correction applies
-    the same expression), so the two engines' scores agree. Returns
-    (estimator distances, slot-table values), the `_search_impl_rabitq`
-    contract."""
-    from raft_tpu.matrix.select_k import bitplane_scan_select_k
-    from raft_tpu.neighbors.probe_invert import (
-        chunk_validity,
-        gather_query_rows,
-        invert_probes_count,
-        invert_probes_sort,
-        regroup_merge,
-    )
-
-    nq = queries.shape[0]
-    n_lists, W, L = codes_t.shape
-    rot_dim = rotation.shape[0]
-    select_min = metric != DistanceType.InnerProduct
-    ip = metric == DistanceType.InnerProduct
-
-    q_rot, probes = _coarse_select(queries, rotation, centers, n_probes,
-                                   metric)
-    invert_impl, qs_impl = setup_impls
-    invert = (invert_probes_count if invert_impl == "count"
-              else invert_probes_sort)
-    tables = invert(probes, n_lists, chunk, pvalid)
-    lof, qid_tbl = tables.lof, tables.qid_tbl
-    cvalid = chunk_validity(qid_tbl, nq)  # empty chunks skip in-kernel
-
-    q_pad = jnp.concatenate([q_rot, jnp.zeros((1, rot_dim), q_rot.dtype)])
-    qs = gather_query_rows(q_pad, qid_tbl, qs_impl)  # (ncb, chunk, rot)
-    cent = centers[lof]
-    qres = qs if ip else qs - cent[:, None, :]
-    planes, lo, delta = quantize_queries(qres, query_bits)
-    planes = planes.reshape(planes.shape[0], planes.shape[1], -1)
-    qsum = jnp.sum(qres, axis=-1)  # (ncb, chunk)
-    if ip:
-        qconst = jnp.einsum("cqd,cd->cq", qs, cent)  # q . center
-    else:
-        qconst = jnp.sum(qres**2, axis=2)  # |q - center|^2
-    qmeta = jnp.stack(
-        [lo[..., 0], delta[..., 0], qsum, qconst], axis=1
-    )  # (ncb, 4, chunk)
-
-    base = jnp.where(slot_rows_pad >= 0, 0.0, jnp.inf)[:, None, :]
-
-    vals, slot_idx = bitplane_scan_select_k(
-        lof, planes, codes_t, bp_meta, base, qmeta, k,
-        rot_dim=rot_dim, bits=query_bits, kbuf=kb, inner_product=ip,
-        interpret=interpret, fault_key=fault_key, chunk_valid=cvalid,
-    )  # (ncb, chunk, kb) exact best-first, canonical-minimizing
-    vals = vals[:, :, :k]
-    slot_idx = slot_idx[:, :, :k]
-
-    invalid = ~jnp.isfinite(vals)
-    slot_idx = jnp.where(invalid, 0, slot_idx)  # sentinel -> safe gather
-    rows = jnp.take_along_axis(
-        slot_rows_pad[lof][:, None, :], slot_idx, axis=2
-    )
-    rows = jnp.where(invalid, -1, rows)
-    if ip:
-        # kernel returned the negated estimator similarity
-        vals = jnp.where(invalid, -jnp.inf, -vals)
-
-    v, rows_out = regroup_merge(
-        tables, vals, rows, _select_k_impl, nq, n_probes, int(k),
-        select_min,
-    )
-    if metric == DistanceType.L2SqrtExpanded:
-        v = jnp.sqrt(jnp.maximum(v, 0.0))
-    return v, rows_out
 
 
 @obs.spanned("neighbors.ivf_rabitq.search")
@@ -772,32 +605,6 @@ def search(
     ds = refine_dataset if refine_dataset is not None else index.dataset
     kk = rerank_depth(k, rerank_mult) if ds is not None else k
 
-    # scan-engine resolution through the dispatch layer (the single
-    # chooser): explicit "fused" validates the envelope and RAISES past
-    # it; "auto" promotes fused only on a chip-measured tuned winner
-    if params.scan_engine not in ("auto", "xla", "fused"):
-        raise ValueError(f"unknown scan_engine {params.scan_engine!r}")
-    from raft_tpu.matrix.select_k import (
-        check_bitplane_request, resolve_bitplane_strategy,
-    )
-    from raft_tpu.ops.fused_scan import FUSED_MAX_K, fused_kbuf
-    from raft_tpu.ops.pq_list_scan import lane_padded
-
-    lpad = lane_padded(int(index.codes.shape[1]))
-    if params.scan_engine == "fused":
-        check_bitplane_request(
-            "scan_engine='fused'", lpad, index.words, int(query_bits),
-            kk, index.fused_kb, "scan_engine='xla'",
-        )
-        strat = "fused_bitplane"
-    elif params.scan_engine == "auto" and 0 < kk <= FUSED_MAX_K:
-        strat = resolve_bitplane_strategy(
-            lpad, index.words, int(query_bits), kk,
-            kbuf=max(fused_kbuf(kk), index.fused_kb or 0),
-        )
-    else:
-        strat = "xla"
-
     # adaptive probing: one (nq, n_probes) keep mask from the rotated
     # coarse geometry; radii come free from the aux |r| column. Plan
     # depth = kk (the rerank shortlist must survive early termination)
@@ -822,10 +629,9 @@ def search(
             "ivf_rabitq", scanned, int(q.shape[0]), n_probes)
     if obs.enabled():
         # n_rows = padded slot count (n_lists * max_list) — the scan
-        # streams pad slots of each probed list too. The fused engine
-        # charges the fused geometry: popcount ops against the integer
-        # peak, no score-matrix bytes. Adaptive budgets charge the
-        # ACTUAL per-query scanned mean, not worst-case n_probes.
+        # streams pad slots of each probed list too. Adaptive budgets
+        # charge the ACTUAL per-query scanned mean, not worst-case
+        # n_probes.
         obs.span_cost(**obs.perf.cost_for(
             "neighbors.ivf_rabitq.search", nq=int(q.shape[0]),
             n_probes=(scanned_mean if scanned_mean is not None
@@ -835,39 +641,22 @@ def search(
             - index.n_tombstones,
             dim=int(index.dim), k=k,
             query_bits=int(query_bits),
-            rerank_mult=int(rerank_mult) if ds is not None else 0,
-            fused=strat == "fused_bitplane"))
+            rerank_mult=int(rerank_mult) if ds is not None else 0))
+        # the scan's rows from static shapes, no device sync: the live
+        # (query, list) pairs, and the (query, list) rows the scan
+        # program scores for them, query-block padding included
+        nq = int(q.shape[0])
+        qb = _rabitq_query_block(n_probes, int(index.codes.shape[1]),
+                                 int(query_bits), index.words)
+        scanned_rows = -(-nq // qb) * qb * n_probes
+        obs.counter("ivf_rabitq.scan.pairs").inc(nq * n_probes)
+        obs.counter("ivf_rabitq.scan.rows").inc(scanned_rows)
 
-    if strat == "fused_bitplane":
-        from raft_tpu.neighbors.probe_invert import (
-            macro_batched, resolve_setup_impls,
-        )
-
-        build_bitplane_store(index, kk)  # fused_kb grows monotonically
-        srows_pad = maybe_filter(index.slot_rows_pad)
-        # qs impl resolved like the flat engines (f32-exact gate): the
-        # plane quantization must see the reference's exact query rows
-        setup = resolve_setup_impls(index.n_lists, engine="flat")
-        kb = index.fused_kb
-        vals, rows = macro_batched(
-            lambda sl, pv=None: _search_impl_rabitq_fused(
-                sl, index.rotation, index.centers, index.codes_t,
-                index.bp_meta, srows_pad, kk, n_probes, index.metric,
-                query_bits=query_bits, kb=kb,
-                interpret=jax.default_backend() == "cpu",
-                setup_impls=setup, fault_key=faults.trace_key(),
-                pvalid=pv,
-            ),
-            jnp.asarray(q),
-            kk,
-            extra=pvalid,
-        )
-    else:
-        vals, rows = _search_impl_rabitq(
-            jnp.asarray(q), index.rotation, index.centers, index.codes,
-            index.aux, maybe_filter(index.slot_rows), kk, n_probes,
-            index.metric, query_bits=query_bits, pvalid=pvalid,
-        )
+    vals, rows = _search_impl_rabitq(
+        jnp.asarray(q), index.rotation, index.centers, index.codes,
+        index.aux, maybe_filter(index.slot_rows), kk, n_probes,
+        index.metric, query_bits=query_bits, pvalid=pvalid,
+    )
     if ds is not None:
         # exact rerank through the shared refine stage: candidates are
         # dataset POSITIONS (insertion order; -1 pads skipped), the id

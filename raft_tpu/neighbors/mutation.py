@@ -17,7 +17,7 @@ rides it end to end:
   the new row through the index's own `extend` (label + encode +
   scatter) — rows land in reserved tail slots (`ensure_append_slack`)
   so steady-state churn never re-pads the store, and the
-  `resid_bf16`/`recon8`/`codes_t` lazy-store + `fused_kb` invalidation
+  `resid_bf16`/`recon8` lazy-store + `fused_kb` invalidation
   contracts do the rest.
 - **rebalance** compacts tombstone-heavy lists: live rows pack left in
   slot order (deterministic), the store re-pads to the live geometry
@@ -115,8 +115,7 @@ def _payload_attrs(kind: str) -> Tuple[str, ...]:
 #: each rebuilds lazily on first use; `fused_kb` survives (monotone
 #: candidate-buffer contract, ivf_flat `_pad_store_to_lanes`)
 _DERIVED_ATTRS = ("resid_bf16", "resid_norm", "recon8", "recon_scale",
-                  "recon_norm", "slot_rows_pad", "codes_t", "bp_meta",
-                  "_list_radii")
+                  "recon_norm", "slot_rows_pad", "_list_radii")
 
 
 def _clone(index):

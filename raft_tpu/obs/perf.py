@@ -248,31 +248,24 @@ def ivf_pq_scan(nq: int, n_probes: int, n_lists: int, n_rows: int,
 
 def rabitq_scan(nq: int, n_probes: int, n_lists: int, n_rows: int,
                 dim: int, k: int, query_bits: int = 8,
-                rerank_mult: int = 0, fused: bool = False) -> dict:
+                rerank_mult: int = 0) -> dict:
     """Binary-code integer scan: per (query, candidate) one AND+popcount
     per 32-bit word per query bit plane — charged as "int" ops (uint32
     VPU popcount/logical class, its own peak row: these ops never touch
     the MXU, so weighing them against a matmul peak would be
     meaningless), plus the exact rerank of rerank_mult*k candidates when
-    enabled. `fused=True` (the fused bit-plane kernel) drops the
-    score-matrix bytes from the select stage AND the materialized
-    bit-plane intersection tensor bytes the XLA reference pays — the
-    packed-code stream itself stays (fusion cannot delete the store
-    read)."""
+    enabled."""
     rows = _probed_rows(n_rows, n_lists, n_probes)
     words = (int(dim) + 31) // 32
     bits = max(1, int(query_bits))
     coarse = pairwise_l2(nq, n_lists, dim, "f32")
     # AND + popcount + shift-add per (pair, word, plane): 2 ops modeled,
     # the multiply+add convention applied to the integer unit
-    scan_bytes = nq * rows * words * 4.0
-    if not fused:
-        # the XLA reference materializes the (nq, probes, rows, bits, W)
-        # intersection tensor in blocks — charge its dominant write-out
-        scan_bytes += nq * rows * bits * words * 4.0
+    # the packed codes, and the (nq, probes, rows, bits, W) intersection
+    # tensor the scan materializes in blocks — its dominant write-out
+    scan_bytes = nq * rows * words * 4.0 + nq * rows * bits * words * 4.0
     scan = _cost(2.0 * nq * rows * words * bits, scan_bytes, "int")
-    parts = [coarse, scan,
-             select_k(nq, rows, max(k, rerank_mult * k or k), fused=fused)]
+    parts = [coarse, scan, select_k(nq, rows, max(k, rerank_mult * k or k))]
     if rerank_mult:
         # exact rerank: EVERY query gathers its own distinct
         # rerank_mult*k-row shortlist from the dataset, so the bytes

@@ -35,20 +35,6 @@ Four geometries share one epilogue:
                       `pq_list_scan`'s q_int8 path (same quantization,
                       same op order), which is what the bit-agreement
                       tests pin.
-  `fused_bitplane_topk`
-                    — the RaBitQ bit-plane list scan: uint32 AND +
-                      popcount of packed sign codes against the query's
-                      quantized bit planes, entirely on the integer
-                      VPU, with the unbiased RaBitQ estimator
-                      correction applied IN-KERNEL — candidate bit
-                      planes never materialize in HBM and only
-                      (chunk, kbuf) estimator scores leave. The
-                      estimator math mirrors
-                      `neighbors/quantizer.binary_dot`/`estimate_dot`
-                      op for op (it cannot import them — ops never
-                      reaches back into neighbors, ANY_LEVEL_BAN);
-                      tests/test_fused_int_scan.py pins exact
-                      agreement against those reference helpers.
 
 The epilogue is an EXACT partial selection, unlike `pq_list_scan`'s
 lane-bin trim: `k` extraction passes over the merged candidate window
@@ -109,7 +95,6 @@ KERNEL_ENVELOPES = {
     "fused_topk": ("fits_fused", {}),
     "fused_list_topk": ("fits_fused_list", {}),
     "fused_list_topk_int8": ("fits_fused_list", {"store_itemsize": 1}),
-    "fused_bitplane_topk": ("fits_fused_bitplane", {}),
 }
 
 
@@ -584,212 +569,4 @@ def fused_list_topk_int8(
             dimension_semantics=("parallel",)
         ),
     )(*scalars, q8, store, base, q_scale)
-    return _maybe_corrupt(vals), idx
-
-
-# ---------------------------------------------------------------------------
-# bit-plane list scan: fused_bitplane_topk (RaBitQ)
-# ---------------------------------------------------------------------------
-
-#: query scalar-quantization depth cap (mirrors quantizer.DEFAULT_QUERY_BITS'
-#: admissible range; a static kernel parameter, so it bounds the unrolled
-#: AND+popcount plane loop)
-BITPLANE_MAX_BITS = 8
-
-
-def _make_bitplane_kernel(W: int, bits: int, kbuf: int, k: int,
-                          inner_product: bool, rot_dim: int,
-                          with_valid: bool = False):
-    import math
-
-    sqrt_d = math.sqrt(float(rot_dim))  # divide by it, like estimate_dot
-
-    def kernel(lof_ref, *refs):
-        del lof_ref  # consumed by the index maps
-        if with_valid:
-            (cva_ref, planes_ref, codes_ref, meta_ref, base_ref,
-             qmeta_ref, vals_ref, idx_ref) = refs
-        else:
-            (planes_ref, codes_ref, meta_ref, base_ref,
-             qmeta_ref, vals_ref, idx_ref) = refs
-        if with_valid:
-            i = pl.program_id(0)  # empty chunk: skip (see _make_list_kernel)
-
-            @pl.when(cva_ref[i] != 0)
-            def _():
-                _compute(planes_ref, codes_ref, meta_ref, base_ref,
-                         qmeta_ref, vals_ref, idx_ref)
-
-            @pl.when(cva_ref[i] == 0)
-            def _():
-                vals_ref[0] = jnp.full(vals_ref.shape[1:], jnp.inf,
-                                       jnp.float32)
-                idx_ref[0] = jnp.full(idx_ref.shape[1:], _ID_SENTINEL,
-                                      jnp.int32)
-        else:
-            _compute(planes_ref, codes_ref, meta_ref, base_ref,
-                     qmeta_ref, vals_ref, idx_ref)
-
-    def _compute(planes_ref, codes_ref, meta_ref, base_ref,
-                 qmeta_ref, vals_ref, idx_ref):
-        planes = planes_ref[0]  # (chunk, bits*W) uint32
-        codes = codes_ref[0]    # (W, L) uint32 word-transposed sign codes
-        chunk = planes.shape[0]
-        L = codes.shape[1]
-        # S_u[c, s] = sum_j 2^j * popcount(codes[s] & plane_j[c]) — the
-        # AND+popcount fast-scan core, int32 end to end (associative, so
-        # this accumulation order is EXACTLY quantizer.binary_dot's sum)
-        acc = jnp.zeros((chunk, L), jnp.int32)
-        for j in range(bits):
-            pp = jnp.zeros((chunk, L), jnp.int32)
-            for w in range(W):
-                inter = planes[:, j * W + w][:, None] & codes[w][None, :]
-                pp = pp + lax.population_count(inter).astype(jnp.int32)
-            acc = acc + pp * (1 << j)
-        s_u = acc.astype(jnp.float32)
-
-        pop = meta_ref[0, 0][None, :]    # (1, L) per-slot code popcount
-        rn = meta_ref[0, 1][None, :]     # (1, L) |r|
-        o_dot = meta_ref[0, 2][None, :]  # (1, L) <o, x_bar>
-        lo = qmeta_ref[0, 0][:, None]    # (chunk, 1) query quant offset
-        delta = qmeta_ref[0, 1][:, None]  # (chunk, 1) query quant step
-        qsum = qmeta_ref[0, 2][:, None]  # (chunk, 1) sum of residual
-        qconst = qmeta_ref[0, 3][:, None]  # (chunk, 1) |q-c|^2 or q.c
-        # the unbiased estimator, op for op the quantizer reference:
-        # s = lo*pop + delta*S_u;  est = ((2s - qsum)/sqrt(D)) / o_dot
-        s = lo * pop + delta * s_u
-        est = ((2.0 * s - qsum) / sqrt_d) / jnp.maximum(o_dot, 1e-12)
-        if inner_product:
-            # reference maximizes qdotc + rn*est; canonical-minimizing
-            score = -(qconst + rn * est)
-        else:
-            score = (qconst + rn * rn) - (2.0 * rn) * est
-        score = score + base_ref[0]  # +inf on invalid/tombstoned slots
-        slot = lax.broadcasted_iota(jnp.int32, score.shape, 1)
-        ov, oi = _extract_topk(score, slot, (chunk, kbuf), k)
-        vals_ref[0] = ov
-        idx_ref[0] = oi
-
-    return kernel
-
-
-def fits_fused_bitplane(chunk: int, L: int, words: int, bits: int, k: int,
-                        kbuf: Optional[int] = None) -> bool:
-    """VMEM envelope for one bit-plane grid step: the int32 popcount
-    accumulator, the f32 score + slot planes, the uint32 code block and
-    query bit planes, the per-slot meta rows and the output buffers.
-    `kbuf` follows the `fits_fused_list` convention (pass the recorded
-    monotonically-grown width when one exists)."""
-    if not (0 < k <= FUSED_MAX_K and 1 <= bits <= BITPLANE_MAX_BITS):
-        return False
-    kbuf = fused_kbuf(k) if kbuf is None else int(kbuf)
-    step_bytes = (
-        12 * chunk * L                # popcount accum + score + slot planes
-        + 4 * words * L               # uint32 code block
-        + 4 * 4 * L                   # meta rows + base row
-        + 4 * chunk * bits * words    # query bit planes
-        + 4 * 4 * chunk               # qmeta rows
-        + 8 * chunk * kbuf            # output buffers
-    )
-    return L % _LANES == 0 and step_bytes <= 10 * 1024 * 1024
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("k", "kbuf", "bits", "rot_dim", "inner_product",
-                     "interpret", "fault_key"),
-)
-def fused_bitplane_topk(
-    lof: jax.Array,      # (ncb,) int32 chunk -> list id (scalar prefetch)
-    planes: jax.Array,   # (ncb, chunk, bits*W) uint32 query bit planes
-    codes_t: jax.Array,  # (n_lists, W, L) uint32 word-transposed codes
-    meta: jax.Array,     # (n_lists, 3, L) f32 [popcount, |r|, <o,x_bar>]
-    base: jax.Array,     # (n_lists, 1, L) f32 0 valid / +inf invalid
-    qmeta: jax.Array,    # (ncb, 4, chunk) f32 [lo, delta, qsum, qconst]
-    k: int,
-    *,
-    rot_dim: int,
-    bits: int,
-    kbuf: Optional[int] = None,
-    inner_product: bool = False,
-    interpret: bool = False,
-    fault_key=None,
-    chunk_valid: Optional[jax.Array] = None,  # (ncb,) int32 0 = skip
-) -> Tuple[jax.Array, jax.Array]:
-    """Exact fused RaBitQ bit-plane scan+select of each chunk's probed
-    list: AND+popcount scoring of the packed sign codes against the
-    query's quantized bit planes with the unbiased estimator correction
-    applied in-kernel — the integer-dominated inner loop of the
-    IVF-RaBitQ paper (arxiv 2602.23999) fused so candidate bit planes
-    never touch HBM.
-
-    Returns ((ncb, chunk, kbuf) canonical-minimizing estimator scores,
-    (ncb, chunk, kbuf) int32 in-list slots), best-first; slots past k
-    carry (+inf, sentinel). L2 scores are the FULL estimator distance
-    (qconst = |q - center|^2 rides the qmeta operand); inner-product
-    scores are the negated estimator similarity — negate back at the
-    call site. `fault_key` = faults.trace_key() so chaos plans
-    retrace."""
-    del fault_key  # participates in the jit cache key only
-    ncb, chunk, pw = planes.shape
-    n_lists, words, L = codes_t.shape
-    if planes.dtype != jnp.uint32 or codes_t.dtype != jnp.uint32:
-        raise ValueError(
-            f"fused_bitplane_topk requires uint32 planes and codes, got "
-            f"{planes.dtype}/{codes_t.dtype}"
-        )
-    if meta.dtype != jnp.float32 or base.dtype != jnp.float32 \
-            or qmeta.dtype != jnp.float32:
-        # f32 meta/base/qmeta rows: the contract the envelope charges
-        # (trace-time only)
-        raise ValueError(
-            f"fused_bitplane_topk requires float32 meta/base/qmeta, got "
-            f"{meta.dtype}/{base.dtype}/{qmeta.dtype}"
-        )
-    if not (1 <= int(bits) <= BITPLANE_MAX_BITS):
-        raise ValueError(f"bits must be in [1, {BITPLANE_MAX_BITS}], got {bits}")
-    if pw != int(bits) * words:
-        raise ValueError(
-            f"planes width {pw} != bits*words = {int(bits) * words}"
-        )
-    if L % _LANES:
-        raise ValueError(f"list length {L} must be a multiple of {_LANES}")
-    kb = fused_kbuf(k) if kbuf is None else int(kbuf)
-    if kb < fused_kbuf(k):
-        raise ValueError(
-            f"candidate buffer width {kb} cannot hold k={k} "
-            f"(needs {fused_kbuf(k)})"
-        )
-
-    with_valid = chunk_valid is not None
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2 if with_valid else 1,
-        grid=(ncb,),
-        in_specs=[
-            pl.BlockSpec((1, chunk, pw), lambda i, *s: (i, 0, 0)),
-            pl.BlockSpec((1, words, L), lambda i, *s: (s[0][i], 0, 0)),
-            pl.BlockSpec((1, 3, L), lambda i, *s: (s[0][i], 0, 0)),
-            pl.BlockSpec((1, 1, L), lambda i, *s: (s[0][i], 0, 0)),
-            pl.BlockSpec((1, 4, chunk), lambda i, *s: (i, 0, 0)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, chunk, kb), lambda i, *s: (i, 0, 0)),
-            pl.BlockSpec((1, chunk, kb), lambda i, *s: (i, 0, 0)),
-        ),
-    )
-    scalars = (lof, chunk_valid.astype(jnp.int32)) if with_valid else (lof,)
-    vals, idx = pl.pallas_call(
-        _make_bitplane_kernel(words, int(bits), kb, int(k),
-                              bool(inner_product), int(rot_dim),
-                              with_valid),
-        out_shape=(
-            jax.ShapeDtypeStruct((ncb, chunk, kb), jnp.float32),
-            jax.ShapeDtypeStruct((ncb, chunk, kb), jnp.int32),
-        ),
-        grid_spec=grid_spec,
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)
-        ),
-    )(*scalars, planes, codes_t, meta, base, qmeta)
     return _maybe_corrupt(vals), idx

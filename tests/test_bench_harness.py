@@ -387,8 +387,9 @@ def test_tuned_registry_wellformed():
         if entry["bench"] is not None:
             assert os.path.exists(os.path.join(repo, entry["bench"])), key
     assert tuned.INT8_SCAN_KEY in tuned.TUNED_KEYS
-    assert tuned.BITPLANE_SCAN_KEY in tuned.TUNED_KEYS
     assert tuned.POLICY_KEY in tuned.TUNED_KEYS
+    # the RaBitQ scan engine is chosen by shape, never by a tuned key
+    assert not any("bitplane" in key for key in tuned.TUNED_KEYS)
     # the dispatch modules re-export, never respell (importlib: the
     # matrix package re-exports select_k the FUNCTION, which shadows
     # the module on attribute traversal)
@@ -398,7 +399,6 @@ def test_tuned_registry_wellformed():
     from raft_tpu.neighbors import probe_budget
 
     assert select_k_mod.INT8_SCAN_KEY is tuned.INT8_SCAN_KEY
-    assert select_k_mod.BITPLANE_SCAN_KEY is tuned.BITPLANE_SCAN_KEY
     assert probe_budget.POLICY_KEY is tuned.POLICY_KEY
 
 

@@ -65,11 +65,12 @@ def test_legacy_pq_golden_loads_budgets_only():
 
 
 def test_legacy_rabitq_golden_loads_runtime_defaults():
-    for f in ("fused_kb", "codes_t", "bp_meta"):
-        assert CKPT_SCHEMA["ivf_rabitq"]["fields"][f][0] == "runtime"
+    fields = CKPT_SCHEMA["ivf_rabitq"]["fields"]
+    for f in ("tombstones", "mut_cursor", "list_digests"):
+        assert fields[f][3] == "default"
     index = ivf_rabitq.load(_golden("legacy_ivf_rabitq_v1.ckpt"))
-    assert index.fused_kb is None
-    assert index.codes_t is None and index.bp_meta is None
+    assert index.tombstones is None and index.mut_cursor == 0
+    assert index.list_digests is None
     # rabitq centers live in the rotated space — query in data space
     q = np.random.default_rng(3).random((3, index.dim), dtype=np.float32)
     vals, ids = ivf_rabitq.search(

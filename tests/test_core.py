@@ -141,6 +141,23 @@ def test_crc32c_reference_vectors():
     assert crc32c(data) == ref ^ 0xFFFFFFFF
 
 
+@pytest.mark.parametrize("shape,dtype", [
+    ((7, 9), np.uint8),            # rows shorter than a block
+    ((5, 300, 24), np.uint32),     # whole blocks and a ragged tail
+    ((4, 1024), np.uint8),         # exactly one block a row
+    ((6, 77, 2), np.float32),
+    ((3, 0), np.uint8),            # empty rows: crc32c(b"") == 0
+])
+def test_crc32c_rows_equals_crc32c_of_each_row(shape, dtype):
+    from raft_tpu.core.serialize import crc32c, crc32c_rows
+
+    a = np.random.default_rng(4).integers(0, 255, shape).astype(dtype)
+    got = crc32c_rows(a)
+    assert got.dtype == np.uint32
+    np.testing.assert_array_equal(
+        got, [crc32c(np.ascontiguousarray(row)) for row in a])
+
+
 def test_serialize_truncated_and_torn_raise_typed(tmp_path):
     """Truncated/empty/garbage containers raise SerializationError
     naming the file and expected magic — not a raw struct.error or

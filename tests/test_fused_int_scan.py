@@ -1,18 +1,10 @@
 """Integer fused scans (ISSUE 11): exact-agreement + dispatch suite.
 
-Two kernel families on the TPU integer datapath:
-
-  - the int8 PQ-recon list scan (`fused_list_topk_int8`, dispatch
-    strategy "fused_int8"): int8 x int8 -> int32 on the MXU, per-row
-    dequant, exact partial top-k — its scores must be BIT-IDENTICAL f32
-    values to the pallas int8 bin trim's (same `_quantize_query_rows`
-    quantization, same op order);
-  - the RaBitQ bit-plane scan (`fused_bitplane_topk`, strategy
-    "fused_bitplane"): uint32 AND+popcount with the unbiased estimator
-    correction in-kernel — its per-(query, slot) scores must equal the
-    XLA reference (`_search_impl_rabitq` via `quantizer.binary_dot` /
-    `estimate_dot`) EXACTLY: the integer bit-plane sums are associative
-    and the f32 correction applies the identical expression.
+The int8 PQ-recon list scan (`fused_list_topk_int8`, dispatch strategy
+"fused_int8") on the TPU integer datapath: int8 x int8 -> int32 on the
+MXU, per-row dequant, exact partial top-k — its scores must be
+BIT-IDENTICAL f32 values to the pallas int8 bin trim's (same
+`_quantize_query_rows` quantization, same op order).
 
 Everything runs the kernels in interpret mode on CPU (the repo-wide
 Pallas testing convention). List geometries here keep L <= 512 so the
@@ -27,13 +19,11 @@ import jax
 import jax.numpy as jnp
 
 from raft_tpu.matrix.select_k import (
-    BITPLANE_SCAN_KEY,
     INT8_SCAN_KEY,
     list_scan_select_k,
-    resolve_bitplane_strategy,
     resolve_int8_trim_strategy,
 )
-from raft_tpu.neighbors import ivf_pq, ivf_rabitq
+from raft_tpu.neighbors import ivf_pq
 
 
 def _grid(rng, shape, lo=-8, hi=8):
@@ -179,179 +169,11 @@ def test_ivf_pq_fused_kb_monotonic_growth(pq_int8_setup):
     assert idx.fused_kb == 256
 
 
-# -- bit-plane kernel vs the quantizer reference ------------------------
-
-
-def test_fused_bitplane_kernel_matches_quantizer_reference(rng):
-    """Kernel-level exactness: per (chunk row, slot) the in-kernel
-    estimator score equals the reference computed with
-    quantizer.binary_dot / estimate_dot — same integer bit-plane sums,
-    same f32 correction, same deterministic smaller-slot ties."""
-    from raft_tpu.ops.fused_scan import fused_bitplane_topk
-    from raft_tpu.neighbors.quantizer import (
-        binary_dot, estimate_dot, pack_bits, quantize_queries,
-    )
-
-    n_lists, L, rot, chunk, k, bits = 4, 256, 64, 8, 16, 8
-    W = rot // 32
-    ncb = 9
-    resid = rng.standard_normal((n_lists, L, rot)).astype(np.float32)
-    codes = np.asarray(pack_bits((resid >= 0).astype(np.uint32)))
-    rnorm = np.sqrt((resid**2).sum(-1)).astype(np.float32)
-    o_dot = (np.abs(resid).sum(-1)
-             / (np.maximum(rnorm, 1e-30) * np.sqrt(rot))).astype(np.float32)
-    pop = np.asarray(jnp.sum(
-        jax.lax.population_count(jnp.asarray(codes)).astype(jnp.int32),
-        axis=-1)).astype(np.float32)
-    # tombstone a ragged tail per list
-    base = np.zeros((n_lists, 1, L), np.float32)
-    for l in range(n_lists):
-        base[l, 0, L - 1 - l * 17:] = np.inf
-
-    qres = rng.standard_normal((ncb, chunk, rot)).astype(np.float32)
-    planes, lo, delta = quantize_queries(jnp.asarray(qres), bits)
-    qsum = qres.sum(-1).astype(np.float32)
-    qcn = (qres**2).sum(-1).astype(np.float32)
-    qmeta = np.stack([np.asarray(lo)[..., 0], np.asarray(delta)[..., 0],
-                      qsum, qcn], axis=1)
-    codes_t = np.transpose(codes, (0, 2, 1))
-    meta = np.stack([pop, rnorm, o_dot], axis=1)
-    lof = rng.integers(0, n_lists, ncb).astype(np.int32)
-
-    vals, slots = fused_bitplane_topk(
-        jnp.asarray(lof),
-        jnp.asarray(planes).reshape(ncb, chunk, bits * W),
-        jnp.asarray(codes_t), jnp.asarray(meta), jnp.asarray(base),
-        jnp.asarray(qmeta), k, rot_dim=rot, bits=bits, interpret=True,
-    )
-    vals, slots = np.asarray(vals), np.asarray(slots)
-
-    # the oracle runs the quantizer reference helpers under jit — the
-    # SAME compiled op sequence the XLA engine uses (XLA CPU contracts
-    # mul+add into FMA, so a numpy re-derivation is a ulp off while the
-    # two compiled paths agree bitwise; tests/test_fused_int_scan pins
-    # exactly that compiled-vs-compiled equality)
-    @jax.jit
-    def oracle(cand, pl, lo_c, delta_c, qsum_c, qcn_c, pop_l, rn_l, od_l,
-               base_l):
-        s_u = binary_dot(cand[None, :, :], pl[:, None, :, :])
-        s = lo_c * pop_l[None, :] + delta_c * s_u
-        est = estimate_dot(s, None, qsum_c[:, None], od_l[None, :], rot)
-        return (qcn_c[:, None] + rn_l[None, :] ** 2
-                - 2.0 * rn_l[None, :] * est) + base_l[None, :]
-
-    for c in range(ncb):
-        l = lof[c]
-        d = np.asarray(oracle(
-            jnp.asarray(codes[l]), jnp.asarray(planes)[c],
-            jnp.asarray(lo)[c], jnp.asarray(delta)[c],
-            jnp.asarray(qsum[c]), jnp.asarray(qcn[c]),
-            jnp.asarray(pop[l]), jnp.asarray(rnorm[l]),
-            jnp.asarray(o_dot[l]), jnp.asarray(base[l, 0]),
-        ))
-        order = np.argsort(d, axis=1, kind="stable")[:, :k]
-        np.testing.assert_array_equal(slots[c][:, :k], order)
-        # the interpreted kernel and the jitted oracle are two compiled
-        # programs; whether XLA fuses a mul+add into an FMA differs
-        # between them, so an intermediate may round one ulp apart. The
-        # score subtracts terms up to twice its own size, which makes
-        # that up to 4 ulp of the result. Slots are exact, values within
-        # 8 ulp.
-        np.testing.assert_array_max_ulp(
-            vals[c][:, :k], np.take_along_axis(d, order, axis=1), maxulp=8)
-
-
-# -- RaBitQ fused engine end to end -------------------------------------
-
-
-@pytest.fixture(scope="module")
-def rabitq_setup():
-    rng = np.random.default_rng(3)
-    data = _grid(rng, (3000, 32))
-    q = _grid(rng, (16, 32))
-    return data, q
-
-
-def test_rabitq_fused_matches_xla_reference_exactly(rabitq_setup):
-    """Estimator-level pin: without rerank the fused scan returns the
-    SAME integer-derived scores and the same neighbors as
-    `_search_impl_rabitq` — exact, not approximate (acceptance: 'same
-    integer scores, same deterministic tie-break')."""
-    data, q = rabitq_setup
-    idx = ivf_rabitq.build(
-        ivf_rabitq.IndexParams(n_lists=16, kmeans_n_iters=4,
-                               store_dataset=False), data)
-    for k in (1, 10, 100):
-        d_x, i_x = ivf_rabitq.search(
-            ivf_rabitq.SearchParams(n_probes=16, scan_engine="xla"),
-            idx, q, k)
-        d_f, i_f = ivf_rabitq.search(
-            ivf_rabitq.SearchParams(n_probes=16, scan_engine="fused"),
-            idx, q, k)
-        np.testing.assert_array_equal(np.asarray(d_x), np.asarray(d_f))
-        i_x, i_f = np.asarray(i_x), np.asarray(i_f)
-        for r in range(len(q)):
-            assert set(i_x[r]) == set(i_f[r])
-
-
-def test_rabitq_fused_rerank_recall_parity(rabitq_setup):
-    """End-to-end with the exact rerank: the fused engine's recall vs
-    ground truth equals the XLA engine's (identical candidate scores ->
-    identical shortlists -> identical exact rerank)."""
-    from raft_tpu.neighbors import brute_force
-
-    data, q = rabitq_setup
-    idx = ivf_rabitq.build(
-        ivf_rabitq.IndexParams(n_lists=16, kmeans_n_iters=4), data)
-    _, gt = brute_force.knn(data, q, 10)
-    gt = np.asarray(gt)
-    d_x, i_x = ivf_rabitq.search(
-        ivf_rabitq.SearchParams(n_probes=16, scan_engine="xla"), idx, q, 10)
-    d_f, i_f = ivf_rabitq.search(
-        ivf_rabitq.SearchParams(n_probes=16, scan_engine="fused"), idx, q, 10)
-    np.testing.assert_array_equal(np.asarray(d_x), np.asarray(d_f))
-    rec_x = np.mean([len(set(np.asarray(i_x)[r]) & set(gt[r])) / 10
-                     for r in range(len(q))])
-    rec_f = np.mean([len(set(np.asarray(i_f)[r]) & set(gt[r])) / 10
-                     for r in range(len(q))])
-    assert rec_f == rec_x
-    assert rec_f >= 0.9  # probing every list: near-exact after rerank
-
-
-def test_rabitq_fused_prefilter_and_kb_growth(rabitq_setup, rng):
-    """Tombstone exclusion through the padded slot table, and the
-    monotonic fused_kb contract on the bit-plane store."""
-    data, q = rabitq_setup
-    idx = ivf_rabitq.build(
-        ivf_rabitq.IndexParams(n_lists=16, kmeans_n_iters=4,
-                               store_dataset=False), data)
-    keep = rng.random(3000) < 0.5
-    d_f, i_f = ivf_rabitq.search(
-        ivf_rabitq.SearchParams(n_probes=16, scan_engine="fused"),
-        idx, q, 10, prefilter=keep)
-    d_x, i_x = ivf_rabitq.search(
-        ivf_rabitq.SearchParams(n_probes=16, scan_engine="xla"),
-        idx, q, 10, prefilter=keep)
-    i_f = np.asarray(i_f)
-    assert not np.isin(i_f[i_f >= 0], np.where(~keep)[0]).any()
-    np.testing.assert_array_equal(np.asarray(d_f), np.asarray(d_x))
-    # kb growth: k=10 -> 128; k=200 -> 256; k=5 keeps 256
-    assert idx.fused_kb == 128
-    ivf_rabitq.search(
-        ivf_rabitq.SearchParams(n_probes=4, scan_engine="fused"),
-        idx, q, 200)
-    assert idx.fused_kb == 256
-    ivf_rabitq.search(
-        ivf_rabitq.SearchParams(n_probes=4, scan_engine="fused"),
-        idx, q, 5)
-    assert idx.fused_kb == 256
-
-
 # -- dispatch contract --------------------------------------------------
 
 
 def test_integer_dispatch_resolution(monkeypatch):
-    """The tuned integer keys promote the fused kernels ONLY on a TPU
+    """The int8 tuned key promotes its fused kernel ONLY on a TPU
     backend where the geometry fits; explicit strategies always win;
     out-of-envelope auto falls back silently."""
     from raft_tpu.core import config, tuned
@@ -359,26 +181,17 @@ def test_integer_dispatch_resolution(monkeypatch):
     # explicit wins regardless of backend/tuned state
     assert resolve_int8_trim_strategy(256, 32, 10,
                                       strategy="fused_int8") == "fused_int8"
-    assert resolve_bitplane_strategy(256, 3, 8, 10,
-                                     strategy="xla") == "xla"
     with pytest.raises(ValueError, match="strategy"):
         resolve_int8_trim_strategy(256, 32, 10, strategy="warpsort")
-    with pytest.raises(ValueError, match="strategy"):
-        resolve_bitplane_strategy(256, 3, 8, 10, strategy="warpsort")
     # no tuned winner -> no promotion
     assert resolve_int8_trim_strategy(256, 32, 10) is None
-    assert resolve_bitplane_strategy(256, 3, 8, 10) == "xla"
     monkeypatch.setitem(tuned._load(), INT8_SCAN_KEY, "fused_int8")
-    monkeypatch.setitem(tuned._load(), BITPLANE_SCAN_KEY, "fused_bitplane")
     # CPU backend: a chip-measured winner must not flip interpret mode
     assert resolve_int8_trim_strategy(256, 32, 10) is None
-    assert resolve_bitplane_strategy(256, 3, 8, 10) == "xla"
     monkeypatch.setattr(config, "is_tpu_backend", lambda: True)
     assert resolve_int8_trim_strategy(256, 32, 10) == "fused_int8"
-    assert resolve_bitplane_strategy(256, 3, 8, 10) == "fused_bitplane"
     # past the envelope: auto falls back, never crashes
     assert resolve_int8_trim_strategy(1 << 16, 4096, 10) is None
-    assert resolve_bitplane_strategy(1 << 16, 512, 8, 10) == "xla"
 
 
 def test_explicit_integer_engines_raise_past_envelope(rng):
@@ -395,19 +208,6 @@ def test_explicit_integer_engines_raise_past_envelope(rng):
             ivf_pq.SearchParams(n_probes=8, trim_engine="fused",
                                 score_dtype="int8"),
             idx, q, 300,
-        )
-    bidx = ivf_rabitq.build(
-        ivf_rabitq.IndexParams(n_lists=8, kmeans_n_iters=4,
-                               store_dataset=False), data)
-    with pytest.raises(ValueError, match="caps scan"):
-        ivf_rabitq.search(
-            ivf_rabitq.SearchParams(n_probes=8, scan_engine="fused"),
-            bidx, q, 300,
-        )
-    with pytest.raises(ValueError, match="scan_engine"):
-        ivf_rabitq.search(
-            ivf_rabitq.SearchParams(n_probes=8, scan_engine="warpsort"),
-            bidx, q, 10,
         )
 
 

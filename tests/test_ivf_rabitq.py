@@ -222,6 +222,87 @@ def test_build_has_no_codebook_stage(index):
     assert quant_meta == {}  # nothing trained, nothing to serialize
 
 
+# -- 96 and 768 dimensions (3 and 24 code words) against plain references
+
+def _unit_blobs(n, d, seed):
+    """Unit-norm Gaussian blobs, the benchmark generator's shape."""
+    rng = np.random.default_rng(seed)
+    centers = rng.uniform(-5.0, 5.0, (16, d))
+    x = centers[rng.integers(0, 16, n)] + rng.standard_normal((n, d))
+    return (x / np.linalg.norm(x, axis=1, keepdims=True)).astype(np.float32)
+
+
+@pytest.fixture(scope="module", params=[96, 768])
+def wide(request):
+    d = request.param
+    x = _unit_blobs(4032, d, seed=d)
+    data, queries = x[:4000], x[4000:]
+    index = ivf_rabitq.build(
+        ivf_rabitq.IndexParams(n_lists=64, kmeans_n_iters=4,
+                               store_dataset=False), data, seed=0)
+    assert index.words == d // 32
+    return data, queries, index
+
+
+def test_rerank_against_numpy_exact_knn(wide):
+    """Re-ranked search against exact float64 L2 k-NN in NumPy: recall
+    above 0.9, and every returned distance is its id's float64 squared
+    distance within 1e-5 relative."""
+    data, queries, index = wide
+    vals, ids = ivf_rabitq.search(
+        ivf_rabitq.SearchParams(n_probes=16, rerank_mult=16, query_bits=4),
+        index, queries, 10, refine_dataset=data)
+    vals, ids = np.asarray(vals), np.asarray(ids)
+    d64 = ((queries.astype(np.float64)[:, None, :]
+            - data.astype(np.float64)[None, :, :]) ** 2).sum(-1)
+    exact = np.argsort(d64, axis=1, kind="stable")[:, :10]
+    assert _recall(ids, exact) >= 0.9
+    assert (ids >= 0).all()
+    np.testing.assert_allclose(
+        vals, np.take_along_axis(d64, ids, axis=1), rtol=1e-5)
+
+
+def test_estimator_against_float64_formula(wide):
+    """One probed list, every row: the scan's estimated distance equals
+    a float64 transcription of the RaBitQ estimator. For residual r of
+    a row and q_r of the query against the list's center, x_bar =
+    sign(r) / sqrt(D), o = r / |r|, the query quantized per row to
+    2^bits - 1 even steps of delta from its minimum lo (round to
+    nearest); <q, o> ~ <q_hat, x_bar> / <o, x_bar> with <q_hat, x_bar>
+    = (2 * sum of q_hat over the set bits - sum(q_r)) / sqrt(D); the
+    distance is |q_r|^2 + |r|^2 - 2 |r| <q, o>."""
+    data, queries, index = wide
+    bits = 4
+    rot = np.asarray(index.rotation, np.float64)
+    centers = np.asarray(index.centers, np.float64)
+    slot_rows = np.asarray(index.slot_rows)
+    x_rot = data.astype(np.float64) @ rot.T
+    for q in queries[:4]:
+        q_rot = rot @ q.astype(np.float64)
+        lst = int(np.argmin(((centers - q_rot) ** 2).sum(1)))
+        pos = slot_rows[lst][slot_rows[lst] >= 0]
+        vals, ids = ivf_rabitq.search(
+            ivf_rabitq.SearchParams(n_probes=1, query_bits=bits),
+            index, q[None], len(pos))
+        got = dict(zip(np.asarray(ids)[0], np.asarray(vals)[0]))
+        assert sorted(got) == sorted(pos)
+
+        dim = rot.shape[0]
+        r = x_rot[pos] - centers[lst]
+        rnorm = np.linalg.norm(r, axis=1)
+        o_dot = np.abs(r).sum(1) / (rnorm * np.sqrt(dim))
+        qr = q_rot - centers[lst]
+        lo = qr.min()
+        delta = (qr.max() - lo) / (2 ** bits - 1)
+        q_hat = lo + delta * np.clip(np.round((qr - lo) / delta), 0,
+                                     2 ** bits - 1)
+        s = np.where(r >= 0, q_hat[None, :], 0.0).sum(1)
+        est = (2 * s - qr.sum()) / np.sqrt(dim) / o_dot
+        want = (qr ** 2).sum() + rnorm ** 2 - 2 * rnorm * est
+        np.testing.assert_allclose([got[p] for p in pos], want, rtol=1e-4,
+                                   atol=1e-5)
+
+
 # -- MNMG ---------------------------------------------------------------
 
 @pytest.fixture(scope="module")

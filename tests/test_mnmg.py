@@ -893,40 +893,6 @@ def test_distributed_int8_fused_trim_engine(comms, blobs):
                            trim_engine="fused", score_dtype="int8")
 
 
-def test_distributed_rabitq_fused_scan_engine(comms, blobs):
-    """ISSUE 11: scan_engine='fused' per rank (the fused bit-plane
-    scan) returns the SAME estimator scores and neighbors as the XLA
-    reference, with and without the exact refine; explicit requests
-    past the envelope raise."""
-    from raft_tpu.neighbors import ivf_rabitq
-
-    data, _ = blobs
-    q = data[:9]
-    dindex = mnmg.ivf_rabitq_build(
-        comms, ivf_rabitq.IndexParams(n_lists=16, kmeans_n_iters=6),
-        data[:2000])
-    xv, xi = mnmg.ivf_rabitq_search(dindex, q, 5, n_probes=16,
-                                    scan_engine="xla")
-    fv, fi = mnmg.ivf_rabitq_search(dindex, q, 5, n_probes=16,
-                                    scan_engine="fused")
-    np.testing.assert_array_equal(np.asarray(xv), np.asarray(fv))
-    np.testing.assert_array_equal(np.asarray(xi), np.asarray(fi))
-    assert dindex.fused_kb == 128
-    rv, ri = mnmg.ivf_rabitq_search(dindex, q, 5, n_probes=16,
-                                    scan_engine="fused",
-                                    refine_dataset=data[:2000])
-    rxv, rxi = mnmg.ivf_rabitq_search(dindex, q, 5, n_probes=16,
-                                      scan_engine="xla",
-                                      refine_dataset=data[:2000])
-    np.testing.assert_array_equal(np.asarray(rv), np.asarray(rxv))
-    np.testing.assert_array_equal(np.asarray(ri), np.asarray(rxi))
-    with pytest.raises(ValueError, match="scan_engine"):
-        mnmg.ivf_rabitq_search(dindex, q, 5, scan_engine="warpsort")
-    with pytest.raises(ValueError, match="caps scan"):
-        # k past FUSED_MAX_K: explicit fused must refuse loudly
-        mnmg.ivf_rabitq_search(dindex, q, 300, scan_engine="fused")
-
-
 def test_query_mode_auto_is_volume_aware(comms, monkeypatch, tmp_path):
     """The auto merge-topology policy consults BOTH thresholds: absolute
     batch size and queries-per-k (merge volume is nq*k*world; the round-3

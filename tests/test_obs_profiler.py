@@ -1,8 +1,9 @@
 """The program's instrumentation on the profiler's clock: `obs.span`
 written into a `jax.profiler` trace, the garbage-collector hook, the
-serve worker's per-batch spans, the IVF-PQ build's stage spans, and the
-stage scopes inside the compiled search programs — and, with obs
-disabled, none of it (no profiler annotation, no clock, no sync)."""
+serve worker's per-batch spans, the IVF-PQ and IVF-RaBitQ builds' stage
+spans, IVF-RaBitQ's scan counters, and the stage scopes inside the
+compiled search programs — and, with obs disabled, none of it (no
+profiler annotation, no clock, no sync)."""
 
 import gc
 import glob
@@ -16,7 +17,7 @@ import pytest
 
 from raft_tpu import obs, serve
 from raft_tpu.distance.distance_types import DistanceType
-from raft_tpu.neighbors import brute_force, ivf_pq
+from raft_tpu.neighbors import brute_force, ivf_pq, ivf_rabitq
 from raft_tpu.obs import spans
 
 refine_mod = importlib.import_module("raft_tpu.neighbors.refine")
@@ -93,24 +94,39 @@ def test_disabled_sites_touch_no_profiler_clock_or_sync(monkeypatch):
     assert calls == []
 
 
-def test_disabled_build_adds_no_sync(monkeypatch):
+BUILDS = {
+    "ivf_pq": (ivf_pq, ivf_pq.IndexParams(n_lists=4, pq_dim=4,
+                                          kmeans_n_iters=2),
+               ("ivf_pq.build.coarse", "ivf_pq.build.codebook"),
+               ("rotation", "centers", "pq_centers", "codes", "slot_rows",
+                "list_sizes", "source_ids")),
+    "ivf_rabitq": (ivf_rabitq, ivf_rabitq.IndexParams(n_lists=4,
+                                                      kmeans_n_iters=2),
+                   ("ivf_rabitq.build.coarse", "ivf_rabitq.build.encode"),
+                   ("rotation", "centers", "codes", "aux", "slot_rows",
+                    "list_sizes", "source_ids")),
+}
+
+
+@pytest.mark.parametrize("kind", ["ivf_pq", "ivf_rabitq"])
+def test_disabled_build_adds_no_sync(monkeypatch, kind):
+    mod, params, _, _ = BUILDS[kind]
     obs.disable()
     x = np.random.default_rng(0).standard_normal((600, 8)).astype(np.float32)
-    params = ivf_pq.IndexParams(n_lists=4, pq_dim=4, kmeans_n_iters=2)
-    ivf_pq.build(params, x)  # compiles outside the count
+    mod.build(params, x)  # compiles outside the count
     syncs = []
     real = jax.block_until_ready
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda v: syncs.append(1) or real(v))
-    ivf_pq.build(params, x)
+    mod.build(params, x)
     assert syncs == []
     obs.enable()
     try:
-        ivf_pq.build(params, x)
+        mod.build(params, x)
     finally:
         obs.disable()
         obs.reset()
-    assert len(syncs) == 3  # coarse, codebook, the whole build
+    assert len(syncs) == 3  # the two stages and the whole build
 
 
 def test_gc_hook_records_collections_until_disabled(tmp_path, obs_on):
@@ -158,24 +174,25 @@ def test_served_batch_spans_in_profiler_trace(tmp_path, obs_on):
     assert {f"raft_tpu.serve.{s}" for s in SERVE_STEPS} <= names
 
 
-def test_build_stage_spans_and_bit_identical_outputs(obs_on):
+@pytest.mark.parametrize("kind", sorted(BUILDS))
+def test_build_stage_spans_and_bit_identical_outputs(obs_on, kind):
+    mod, params, stages, fields = BUILDS[kind]
     x = np.random.default_rng(1).standard_normal((800, 8)).astype(np.float32)
-    params = ivf_pq.IndexParams(n_lists=4, pq_dim=4, kmeans_n_iters=2)
-    on = ivf_pq.build(params, x)
-    for name in ("neighbors.ivf_pq.build", "ivf_pq.build.coarse",
-                 "ivf_pq.build.codebook", "neighbors.ivf_pq.extend"):
+    on = mod.build(params, x)
+    for name in (f"neighbors.{kind}.build", f"neighbors.{kind}.extend",
+                 *stages):
         assert obs.histogram(f"span.{name}").count == 1, name
     obs.disable()
-    off = ivf_pq.build(params, x)
-    for field in ("rotation", "centers", "pq_centers", "codes", "slot_rows",
-                  "list_sizes", "source_ids"):
+    off = mod.build(params, x)
+    for field in fields:
         np.testing.assert_array_equal(np.asarray(getattr(on, field)),
                                       np.asarray(getattr(off, field)))
 
 
 def _stage_names(lowered):
     return {s for s in ("ivf_pq.coarse", "ivf_pq.invert", "ivf_pq.scan",
-                        "ivf_pq.trim", "ivf_pq.merge", "refine",
+                        "ivf_pq.trim", "ivf_pq.merge", "ivf_rabitq.coarse",
+                        "ivf_rabitq.scan", "ivf_rabitq.merge", "refine",
                         "brute_force.distance", "brute_force.select")
             if f"{s}/" in lowered.as_text(debug_info=True)}
 
@@ -191,6 +208,15 @@ def small_index():
     return x, q, index
 
 
+@pytest.fixture(scope="module")
+def small_rabitq():
+    rng = np.random.default_rng(3)
+    x = jnp.asarray(rng.standard_normal((2000, 16)), jnp.float32)
+    index = ivf_rabitq.build(ivf_rabitq.IndexParams(
+        n_lists=8, kmeans_n_iters=2, store_dataset=False), x)
+    return index
+
+
 def test_listmajor_program_names_its_stages(small_index):
     _, q, ix = small_index
     lowered = ivf_pq._search_impl_recon8_listmajor.lower(
@@ -201,20 +227,42 @@ def test_listmajor_program_names_its_stages(small_index):
                                      "ivf_pq.merge"}
 
 
-@pytest.mark.parametrize("engine", ["recon8", "lut"])
-def test_query_major_programs_name_their_stages(small_index, engine):
+@pytest.mark.parametrize("engine", ["recon8", "lut", "rabitq"])
+def test_query_major_programs_name_their_stages(small_index, small_rabitq,
+                                                engine):
     _, q, ix = small_index
     L2 = DistanceType.L2Expanded
+    kind = "ivf_pq"
     if engine == "recon8":
         lowered = ivf_pq._search_impl_recon8.lower(
             q, ix.rotation, ix.centers, ix.recon8, ix.recon_scale,
             ix.recon_norm, ix.slot_rows_pad, 10, 4, L2)
-    else:
+    elif engine == "lut":
         lowered = ivf_pq._search_impl.lower(
             q, ix.rotation, ix.centers, ix.pq_centers, ix.codes,
             ix.slot_rows, 10, 4, L2, False)
-    assert _stage_names(lowered) == {"ivf_pq.coarse", "ivf_pq.scan",
-                                     "ivf_pq.merge"}
+    else:
+        rb, kind = small_rabitq, "ivf_rabitq"
+        lowered = ivf_rabitq._search_impl_rabitq.lower(
+            q, rb.rotation, rb.centers, rb.codes, rb.aux, rb.slot_rows, 10,
+            4, L2, query_bits=4)
+    assert _stage_names(lowered) == {f"{kind}.coarse", f"{kind}.scan",
+                                     f"{kind}.merge"}
+
+
+@pytest.mark.parametrize("nq,rows", [(37, 3 * 16 * 4), (16, 16 * 4)])
+def test_rabitq_scan_counters_from_static_shapes(small_rabitq, obs_on,
+                                                 monkeypatch, nq, rows):
+    """One increment a call, from shapes alone: nq queries x 4 probes
+    live pairs; the rows scored for them, padding included — whole
+    16-query blocks x 4 probes."""
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda v: pytest.fail("device sync"))
+    q = np.random.default_rng(4).standard_normal((nq, 16)).astype(np.float32)
+    ivf_rabitq.search(ivf_rabitq.SearchParams(
+        n_probes=4, query_bits=4), small_rabitq, q, 10)
+    assert obs.counter("ivf_rabitq.scan.pairs").value == nq * 4
+    assert obs.counter("ivf_rabitq.scan.rows").value == rows
 
 
 def test_refine_program_names_its_stage(small_index):

@@ -146,14 +146,17 @@ def test_integer_peak_row_and_popcount_canon():
 
 
 def test_rabitq_fused_geometry_cost():
-    """The fused bit-plane span charges integer-ops flops with NO
-    score-matrix / intersection-tensor bytes — the dtype-correct MFU
-    attribution the banked smoke rows must show."""
+    """The bit-plane scan charges integer-ops flops and the blocked
+    intersection tensor it writes (bits x words per candidate) beside
+    the packed-code stream — the dtype-correct MFU attribution; the
+    int8 fused PQ scan splits its peaks by dtype."""
     kw = dict(nq=64, n_probes=8, n_lists=64, n_rows=64_000, dim=64, k=10)
-    xla = perf.rabitq_scan(**kw)
-    fused = perf.rabitq_scan(**kw, fused=True)
-    assert fused["flops_by_dtype"]["int"] == xla["flops_by_dtype"]["int"]
-    assert fused["bytes"] < xla["bytes"]  # the deleted HBM round-trips
+    four = perf.rabitq_scan(**kw, query_bits=4)
+    one = perf.rabitq_scan(**kw, query_bits=1)
+    assert four["flops_by_dtype"]["int"] == 4 * one["flops_by_dtype"]["int"]
+    rows = 8 * 64_000 / 64
+    assert four["bytes"] - one["bytes"] == pytest.approx(
+        64 * rows * 3 * 2 * 4.0)  # three more planes of two words
     # the int8 fused PQ scan splits coarse-f32 from the int8 MXU matmul
     pq = perf.ivf_pq_scan(nq=64, n_probes=8, n_lists=64, n_rows=64_000,
                           dim=32, pq_dim=16, k=10, dtype="int8",

@@ -198,14 +198,14 @@ def test_pq_saturated_bit_identical(pq16, clustered, cfg):
     np.testing.assert_array_equal(np.asarray(fixed[1]), np.asarray(sat[1]))
 
 
-@pytest.mark.parametrize("engine", ["xla", "fused"])
-def test_rabitq_saturated_bit_identical(rabitq16, clustered, engine):
+@pytest.mark.parametrize("query_bits", [4, 8])
+def test_rabitq_saturated_bit_identical(rabitq16, clustered, query_bits):
     q = clustered[:48]
     fixed = ivf_rabitq.search(
-        ivf_rabitq.SearchParams(n_probes=8, scan_engine=engine),
+        ivf_rabitq.SearchParams(n_probes=8, query_bits=query_bits),
         rabitq16, q, 10)
     sat = ivf_rabitq.search(
-        ivf_rabitq.SearchParams(n_probes=8, scan_engine=engine,
+        ivf_rabitq.SearchParams(n_probes=8, query_bits=query_bits,
                                 recall_target=1.0),
         rabitq16, q, 10)
     np.testing.assert_array_equal(np.asarray(fixed[0]), np.asarray(sat[0]))

@@ -23,7 +23,6 @@ from raft_tpu.ops.pq_list_scan import pq_list_scan
 from raft_tpu.ops.select_counting import counting_select_min
 
 D, N_LISTS, L, CHUNK, K, NCB = 96, 1024, 1024, 128, 10, 256
-WORDS, BITS = D // 32, 8
 
 
 @pytest.fixture(scope="module")
@@ -65,11 +64,6 @@ CASES = {
         lambda *a: fs.fused_list_topk_int8(*a, K),
         [LOF, ((NCB, CHUNK, D), jnp.int8), ((N_LISTS, L, D), jnp.int8),
          BASE, ((NCB, CHUNK, 1), f32)]),
-    "fused_bitplane_topk": (
-        lambda *a: fs.fused_bitplane_topk(*a, K, rot_dim=D, bits=BITS),
-        [LOF, ((NCB, CHUNK, BITS * WORDS), u32),
-         ((N_LISTS, WORDS, L), u32), ((N_LISTS, 3, L), f32), BASE,
-         ((NCB, 4, CHUNK), f32)]),
     "pq_list_scan_int8": (
         lambda lof, q, st, b, s: pq_list_scan(lof, q, st, b, q_scale=s),
         [LOF, ((NCB, CHUNK, D), jnp.int8), ((N_LISTS, L, D), jnp.int8),
@@ -119,3 +113,30 @@ def test_listmajor_trim_lowers_to_topk(one_chip):
     trims = [defs[o][1] for o in operands if defs[o][0] == str(slots)]
     assert trims and all(layout == "0,1" for layout in trims), trims
     assert not re.search(rf"\[[\d,]*\b{slots}\b[\d,]*\][^\n]* sort\(", txt)
+
+
+#: wiki1m-rabitq's widths: 768-d (24 code words), 4,096 lists, 4-bit
+#: query planes, 16 probes, a 320-row scan over a 10,000-query call;
+#: the longest list is what the coarse k-means gives the cell's data
+#: (the generator's 1,024 blobs: whole blobs a list): 2,016 slots on the
+#: cell's corpus, 3,008 on the longest of the five read on a v5e
+RQ_D, RQ_LISTS, PROBES, KK = 768, 4096, 16, 320
+
+
+@pytest.mark.parametrize("slots", [2016, 3008])
+def test_rabitq_search_compiles_at_cell_widths(one_chip, slots):
+    """The IVF-RaBitQ search program compiles at the benchmark cell's
+    widths: the XLA bit-plane scan over a 10,000-query call."""
+    from raft_tpu.distance.distance_types import DistanceType
+    from raft_tpu.neighbors import ivf_rabitq
+
+    words = RQ_D // 32
+    shapes = [((10_000, RQ_D), f32), ((RQ_D, RQ_D), f32),
+              ((RQ_LISTS, RQ_D), f32), ((RQ_LISTS, slots, words), u32),
+              ((RQ_LISTS, slots, 2), f32), ((RQ_LISTS, slots), i32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    txt = ivf_rabitq._search_impl_rabitq.lower(
+        *args, k=KK, n_probes=PROBES, metric=DistanceType.L2Expanded,
+        query_bits=4).compile().as_text()
+    assert "tpu_custom_call" not in txt

@@ -383,16 +383,14 @@ def check_dtype_flow(module: Module) -> Iterator[Finding]:
 #: direct entry points into the fused kernel family: the ops kernels and
 #: the matrix/select_k dispatch doors
 ROUTING_FUNCS = {"fused_topk", "fused_list_topk", "fused_list_topk_int8",
-                 "fused_bitplane_topk", "list_scan_select_k",
-                 "bitplane_scan_select_k"}
+                 "list_scan_select_k"}
 
 #: envelope validations (direct names; transitive callers found by
 #: summary fixpoint over the project call graph)
-CHECK_FUNCS = {"fits_fused", "fits_fused_list", "fits_fused_bitplane",
-               "check_fused_list_request", "check_bitplane_request"}
+CHECK_FUNCS = {"fits_fused", "fits_fused_list", "check_fused_list_request"}
 
 #: strategy literals that name a fused engine in a dispatch branch
-FUSED_LITERALS = {"fused", "fused_int8", "fused_bitplane"}
+FUSED_LITERALS = {"fused", "fused_int8"}
 
 
 def _guard_scope(path: str) -> bool:
@@ -496,7 +494,7 @@ def _strategy_test_name(facts: _FnFacts, test,
                         depth: int = 0) -> Optional[str]:
     """The strategy variable a branch tests: ``strat == "fused_..."``
     directly, or (one level) a boolean flag whose every assignment is
-    such a comparison (``use_fused = strat == "fused_bitplane"``)."""
+    such a comparison (``use_fused = strat == "fused_int8"``)."""
     if isinstance(test, ast.Name) and depth == 0:
         inner = {
             _strategy_test_name(facts, v, 1)
