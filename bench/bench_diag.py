@@ -334,20 +334,23 @@ def _run_stage_decomposition(smoke):
 
     try:
         vals0 = jax.random.normal(jax.random.PRNGKey(3), (ncb, chunk, kk))
-        rows0 = jax.random.randint(
-            jax.random.PRNGKey(4), (ncb, chunk, kk), 0, 1 << 20, jnp.int32
+        slots0 = jax.random.randint(
+            jax.random.PRNGKey(4), (ncb, chunk, kk), 0, L, jnp.int32
         )
-        jax.block_until_ready((vals0, rows0))
+        slot_rows0 = jax.random.randint(
+            jax.random.PRNGKey(5), (n_lists, L), 0, 1 << 20, jnp.int32
+        )
+        jax.block_until_ready((vals0, slots0, slot_rows0))
     except Exception as e:
         R["st_setup"] = {"error": str(e)[:160]}
         _bank()
         return
 
-    def stage_regroup(vals, rows):
+    def stage_regroup(vals, slots, slot_rows):
         from raft_tpu.neighbors.probe_invert import regroup_merge
 
         return regroup_merge(
-            tables, vals, rows, _select_k_impl, nq4, npb, kk, True
+            tables, vals, slots, slot_rows, _select_k_impl, nq4, npb, kk, True
         )
 
     stages = {
@@ -359,7 +362,9 @@ def _run_stage_decomposition(smoke):
         "st_score_trim_super": (
             jax.jit(stage_score_trim_super), (tables.lof, qs)
         ),
-        "st_regroup_merge": (jax.jit(stage_regroup), (vals0, rows0)),
+        "st_regroup_merge": (
+            jax.jit(stage_regroup), (vals0, slots0, slot_rows0)
+        ),
     }
     for name, (fn, args) in stages.items():
         try:

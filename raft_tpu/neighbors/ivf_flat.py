@@ -798,9 +798,7 @@ def _search_impl_listmajor_pallas(
     slot_idx = slot_idx[:, :, :k]
 
     invalid = ~jnp.isfinite(vals)
-    slot_idx = jnp.where(invalid, 0, slot_idx)  # sentinel -> safe gather
-    rows = jnp.take_along_axis(slot_rows[lof][:, None, :], slot_idx, axis=2)
-    rows = jnp.where(invalid, -1, rows)
+    slots = jnp.where(invalid, -1, slot_idx)
 
     if ip:
         # IP score = q.res + q.center; kernel returned -(q.res) on valid
@@ -811,7 +809,8 @@ def _search_impl_listmajor_pallas(
         vals = jnp.maximum(vals + qn[:, :, None], 0.0)
 
     v, rows_out = regroup_merge(
-        tables, vals, rows, _select_k_impl, nq, n_probes, int(k), select_min
+        tables, vals, slots, slot_rows, _select_k_impl, nq, n_probes, int(k),
+        select_min
     )
     v = v.astype(jnp.float32)
     if metric == DistanceType.L2SqrtExpanded:

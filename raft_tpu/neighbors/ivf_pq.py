@@ -1157,8 +1157,7 @@ def _search_impl_recon8_listmajor_pallas(
             )  # (ncb, chunk, 512) minimizing
 
         invalid = ~jnp.isfinite(vals)
-        rows = jnp.take_along_axis(slot_rows_pad[lof][:, None, :], slot_idx, axis=2)
-        rows = jnp.where(invalid, -1, rows)
+        slots = jnp.where(invalid, -1, slot_idx)
 
         # undo the kernel's minimization frame and add per-query constants
         if ip:
@@ -1176,13 +1175,15 @@ def _search_impl_recon8_listmajor_pallas(
         tv, tpos = _select_k_impl(
             vals.reshape(ncb * vals.shape[1], cands), kk, select_min
         )
-        tr = jnp.take_along_axis(rows.reshape(ncb * rows.shape[1], cands), tpos, axis=1)
+        ts = jnp.take_along_axis(slots.reshape(ncb * slots.shape[1], cands),
+                                 tpos, axis=1)
         tv = tv.reshape(ncb, -1, kk)
-        tr = tr.reshape(ncb, -1, kk)
+        ts = ts.reshape(ncb, -1, kk)
 
     with jax.named_scope("ivf_pq.merge"):
         v, rows_out = regroup_merge(
-            tables, tv, tr, _select_k_impl, nq, n_probes, int(k), select_min
+            tables, tv, ts, slot_rows_pad, _select_k_impl, nq, n_probes,
+            int(k), select_min
         )
         v = v.astype(jnp.float32)
         if metric == DistanceType.L2SqrtExpanded:
@@ -1288,9 +1289,7 @@ def _search_impl_recon8_listmajor_fused(
         slot_idx = slot_idx[:, :, :k]
 
         invalid = ~jnp.isfinite(vals)
-        slot_idx = jnp.where(invalid, 0, slot_idx)  # sentinel -> safe gather
-        rows = jnp.take_along_axis(slot_rows_pad[lof][:, None, :], slot_idx, axis=2)
-        rows = jnp.where(invalid, -1, rows)
+        slots = jnp.where(invalid, -1, slot_idx)
 
         if ip:
             qdotc = jnp.einsum("cqd,cd->cq", qs, cent)
@@ -1301,7 +1300,8 @@ def _search_impl_recon8_listmajor_fused(
 
     with jax.named_scope("ivf_pq.merge"):
         v, rows_out = regroup_merge(
-            tables, vals, rows, _select_k_impl, nq, n_probes, int(k), select_min
+            tables, vals, slots, slot_rows_pad, _select_k_impl, nq, n_probes,
+            int(k), select_min
         )
         v = v.astype(jnp.float32)
         if metric == DistanceType.L2SqrtExpanded:
@@ -1608,6 +1608,13 @@ def search(
         )
     else:
         raise ValueError(f"unknown score_mode {mode!r}")
+    if obs.enabled() and mode == "recon8_list":
+        # the list-major merge looks up one row id per winner of every
+        # padded slice row (probe_invert.regroup_merge), from shapes alone
+        from raft_tpu.neighbors.probe_invert import macro_batch_rows
+
+        obs.counter("ivf_pq.merge.id_lookups").inc(
+            macro_batch_rows(int(q.shape[0])) * int(k))
     ids = jnp.where(rows >= 0, index.source_ids[jnp.maximum(rows, 0)], -1)
     if resources is not None:
         resources.track(vals, ids)

@@ -351,8 +351,9 @@ def score_and_select(
     stage: str,
 ):
     """Shared back half of a list-major search (traced inside the engine's
-    jit): two-level blocked scoring, per-superblock exact trim,
-    gather-based regroup to query-major, exact final merge — the stage
+    jit): two-level blocked scoring, per-superblock exact trim to
+    in-list slots, gather-based regroup to query-major, exact final
+    merge, and the row-id lookup of the merged top-k — the stage
     scopes `<stage>.scan`, `<stage>.trim` and `<stage>.merge` in the
     program's op metadata (an op takes the innermost scope on its path).
 
@@ -417,10 +418,7 @@ def score_and_select(
             # rank 2: the TPU lowers a rank-3 top-k to a full sort
             v, si = select_k_fn(scores.reshape(sb * chunk, max_list), kk,
                                 select_min)
-            v, si = v.reshape(sb, chunk, kk), si.reshape(sb, chunk, kk)
-            rows_sb = jnp.take_along_axis(slot_rows[lofs][:, None, :], si,
-                                          axis=2)
-        return v, rows_sb
+            return v.reshape(sb, chunk, kk), si.reshape(sb, chunk, kk)
 
     with jax.named_scope(f"{stage}.scan"):
         lof_b = (jnp.pad(lof, (0, bpad)) if bpad else lof).reshape(nsuper, sb)
@@ -434,15 +432,16 @@ def score_and_select(
         outs = [super_block((lof_b[i], qid_b[i])) for i in range(nsuper)]
     with jax.named_scope(f"{stage}.merge"):
         vals = jnp.concatenate([v for v, _ in outs])[:ncb]
-        rows = jnp.concatenate([r for _, r in outs])[:ncb]
-        return regroup_merge(tables, vals, rows, select_k_fn, nq, n_probes,
-                             k, select_min)
+        slots = jnp.concatenate([s for _, s in outs])[:ncb]
+        return regroup_merge(tables, vals, slots, slot_rows, select_k_fn, nq,
+                             n_probes, k, select_min)
 
 
 def regroup_merge(
     tables: ChunkTables,
     vals: jax.Array,   # (ncb, chunk, kk) per-chunk trimmed candidate scores
-    rows: jax.Array,   # (ncb, chunk, kk) their source-row ids (-1 invalid)
+    slots: jax.Array,  # (ncb, chunk, kk) their in-list slots (-1 invalid)
+    slot_rows: jax.Array,  # (n_lists, max_list) slot -> row id (-1 empty)
     select_k_fn,
     nq: int,
     n_probes: int,
@@ -450,24 +449,32 @@ def regroup_merge(
     select_min: bool,
 ):
     """Regroup per-chunk candidates to query-major (pure gather through
-    the (g0, s0) pair addresses — no scatter) and merge exactly.
-    Budget-masked pairs (tables.pair_valid False) contribute the worst
-    value / row -1, exactly like a sub-k prefilter tail."""
+    the (g0, s0) pair addresses — no scatter), merge exactly, then turn
+    only the nq x k winners' (list, slot) into row ids: the trim keeps
+    kk slots for every chunk row, padding included, so looking ids up
+    before the merge costs ncb*chunk*kk one-element gathers for the
+    nq*k the merge keeps. A winner's list is its probe pair's chunk's
+    list, found from its merge position. Budget-masked pairs
+    (tables.pair_valid False) contribute the worst value / row -1,
+    exactly like a sub-k prefilter tail."""
     g0, s0 = tables.g0, tables.s0
     kk = vals.shape[-1]
     cand_v = vals[g0, s0]
-    cand_r = rows[g0, s0]
+    cand_s = slots[g0, s0]
     if tables.pair_valid is not None:
         worst = jnp.asarray(
             jnp.inf if select_min else -jnp.inf, cand_v.dtype)
         m = tables.pair_valid[:, None]
         cand_v = jnp.where(m, cand_v, worst)
-        cand_r = jnp.where(m, cand_r, -1)
+        cand_s = jnp.where(m, cand_s, -1)
     cand_v = cand_v.reshape(nq, n_probes * kk)
-    cand_r = cand_r.reshape(nq, n_probes * kk)
+    cand_s = cand_s.reshape(nq, n_probes * kk)
     v, pos2 = select_k_fn(cand_v, k, select_min)
-    ids = jnp.take_along_axis(cand_r, pos2, axis=1)
-    return v, ids
+    slot = jnp.take_along_axis(cand_s, pos2, axis=1)
+    lists = jnp.take_along_axis(tables.lof[g0].reshape(nq, n_probes),
+                                pos2 // kk, axis=1)
+    ids = slot_rows[lists, jnp.maximum(slot, 0)]
+    return v, jnp.where(slot >= 0, ids, -1)
 
 
 def chunk_validity(qid_tbl: jax.Array, nq: int) -> jax.Array:
@@ -521,6 +528,12 @@ def macro_batched(search_slice_fn, queries: jax.Array, k: int, mb: int = 4096,
         jnp.concatenate([v for v, _ in outs]),
         jnp.concatenate([r for _, r in outs]),
     )
+
+
+def macro_batch_rows(nq: int, mb: int = 4096) -> int:
+    """Query rows `macro_batched` runs for an nq-row call: every slice
+    padded up its ladder. From shapes alone, no device work."""
+    return sum(_ladder(min(mb, nq - s), mb) for s in range(0, nq, mb))
 
 
 def _ladder(n: int, cap: int) -> int:

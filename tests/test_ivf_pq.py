@@ -611,6 +611,7 @@ def _spy_trim(monkeypatch, jitted):
     tables, the slot table and every scored block. `jitted` is cleared so
     the next call traces through the spy."""
     import jax
+    import jax.numpy as jnp
 
     from raft_tpu.neighbors import probe_invert
 
@@ -625,10 +626,13 @@ def _spy_trim(monkeypatch, jitted):
                 inp[0], inp[1], s)
             return s
 
+        pv = (tables.pair_valid if tables.pair_valid is not None
+              else jnp.ones(tables.g0.shape, bool))
         jax.debug.callback(
-            lambda *a: rec.update(zip(("g0", "s0", "lof", "qid", "rows"),
+            lambda *a: rec.update(zip(("g0", "s0", "lof", "qid", "rows",
+                                       "pv"),
                                       [np.asarray(x) for x in a])),
-            tables.g0, tables.s0, tables.lof, tables.qid_tbl, slot_rows)
+            tables.g0, tables.s0, tables.lof, tables.qid_tbl, slot_rows, pv)
         return orig(tables, block, slot_rows, *args, **kw)
 
     monkeypatch.setattr(probe_invert, "score_and_select", spy)
@@ -639,6 +643,7 @@ def _spy_trim(monkeypatch, jitted):
 def _trim_reference(rec, nq, n_probes, k, source_ids):
     """Each (query, probed list)'s exact top-kk by (score, slot), merged
     per query by (score, probe rank): the list-major contract in NumPy.
+    A budget-masked pair gives kk candidates of score inf and id -1.
     Also returns how many live pairs had fewer than kk real candidates."""
     n_pad = len(rec["g0"]) // n_probes  # the pad id: the slice's size
     rows_of = {}
@@ -653,6 +658,10 @@ def _trim_reference(rec, nq, n_probes, k, source_ids):
     for q in range(nq):
         cv, cr = [], []
         for p in range(n_probes):
+            if not rec["pv"][q * n_probes + p]:
+                cv.append(np.full(kk, np.inf, np.float32))
+                cr.append(np.full(kk, -1))
+                continue
             c, r = rec["g0"][q * n_probes + p], rec["s0"][q * n_probes + p]
             lst = int(rec["lof"][c])
             assert rec["qid"][c, r] == q
@@ -670,15 +679,18 @@ def _trim_reference(rec, nq, n_probes, k, source_ids):
 
 
 TRIM_CASES = {
-    # name: (index, internal_distance_dtype, k, prefilter); "pq16" is the
-    # shared 20,000-row index, on which recall is checked besides
-    "pq-bf16-k40": ("pq", "bfloat16", 40, False),
-    "pq-bf16-k10-prefilter": ("pq", "bfloat16", 10, True),
-    "pq-f32-k40": ("pq", "float32", 40, False),
-    "pq-f32-k10-prefilter": ("pq", "float32", 10, True),
-    "pq16-recall": ("pq16", "float32", 10, False),
-    "flat-k40": ("flat", "float32", 40, False),
-    "flat-k10-prefilter": ("flat", "float32", 10, True),
+    # name: (index, internal_distance_dtype, k, prefilter or adaptive
+    # probe budgets); "pq16" is the shared 20,000-row index, on which
+    # recall is checked besides
+    "pq-bf16-k40": ("pq", "bfloat16", 40, None),
+    "pq-bf16-k10-prefilter": ("pq", "bfloat16", 10, "prefilter"),
+    "pq-f32-k40": ("pq", "float32", 40, None),
+    "pq-f32-k10-prefilter": ("pq", "float32", 10, "prefilter"),
+    "pq16-recall": ("pq16", "float32", 10, None),
+    "flat-k40": ("flat", "float32", 40, None),
+    "flat-k10-prefilter": ("flat", "float32", 10, "prefilter"),
+    "pq-f32-k40-budget": ("pq", "float32", 40, "budget"),
+    "flat-k40-budget": ("flat", "float32", 40, "budget"),
 }
 
 
@@ -688,13 +700,17 @@ def test_listmajor_trim_is_exact(case, trim_data, trim_pq, trim_flat,
     """The list-major trim keeps each (query, probed list)'s exact top-kk,
     ties to the lower slot, and the merge then takes the best k: the
     answers equal a NumPy reference built from the engine's own scores,
-    including lists with fewer than kk live rows. Every IVF-PQ
-    trim_engine name that runs the XLA trim returns the same bits."""
+    including lists with fewer than kk live rows and pairs dropped by
+    adaptive probe budgets. Every IVF-PQ trim_engine name that runs the
+    XLA trim returns the same bits."""
     from raft_tpu.neighbors import ivf_flat
 
-    which, idd, k, filtered = TRIM_CASES[case]
+    which, idd, k, extra = TRIM_CASES[case]
     _, queries, keep = trim_data
+    filtered = extra == "prefilter"
     prefilter = keep if filtered else None
+    budget = ({"adaptive": True, "budget_tau": 0.5} if extra == "budget"
+              else {})
     index = {"pq": trim_pq, "pq16": index16, "flat": trim_flat}[which]
     n_probes = 8
     if which == "pq16":
@@ -704,7 +720,8 @@ def test_listmajor_trim_is_exact(case, trim_data, trim_pq, trim_flat,
 
         def run(trim):
             return ivf_flat.search(
-                ivf_flat.SearchParams(n_probes=n_probes, engine="list"),
+                ivf_flat.SearchParams(n_probes=n_probes, engine="list",
+                                      **budget),
                 index, queries, k, prefilter=prefilter)
     else:
         jitted = ivf_pq._search_impl_recon8_listmajor
@@ -714,7 +731,7 @@ def test_listmajor_trim_is_exact(case, trim_data, trim_pq, trim_flat,
                 ivf_pq.SearchParams(n_probes=n_probes,
                                     score_mode="recon8_list",
                                     internal_distance_dtype=idd,
-                                    trim_engine=trim),
+                                    trim_engine=trim, **budget),
                 index, queries, k, prefilter=prefilter)
 
         d0, i0 = run("approx")
@@ -735,6 +752,7 @@ def test_listmajor_trim_is_exact(case, trim_data, trim_pq, trim_flat,
     np.testing.assert_array_equal(d, ref_d)
     if filtered:
         assert np.all(keep[i[i >= 0]])
+    assert rec["pv"].all() != bool(budget), "budgets masked no pair"
     if which != "pq16":
         assert short > 0, "no probed list shorter than kk"
         return

@@ -1,9 +1,9 @@
 """The program's instrumentation on the profiler's clock: `obs.span`
 written into a `jax.profiler` trace, the garbage-collector hook, the
 serve worker's per-batch spans, the IVF-PQ and IVF-RaBitQ builds' stage
-spans, IVF-RaBitQ's scan counters, and the stage scopes inside the
-compiled search programs — and, with obs disabled, none of it (no
-profiler annotation, no clock, no sync)."""
+spans, IVF-RaBitQ's scan counters, IVF-PQ's merge counter, and the stage
+scopes inside the compiled search programs — and, with obs disabled, none
+of it (no profiler annotation, no clock, no sync)."""
 
 import gc
 import glob
@@ -263,6 +263,28 @@ def test_rabitq_scan_counters_from_static_shapes(small_rabitq, obs_on,
         n_probes=4, query_bits=4), small_rabitq, q, 10)
     assert obs.counter("ivf_rabitq.scan.pairs").value == nq * 4
     assert obs.counter("ivf_rabitq.scan.rows").value == rows
+
+
+@pytest.mark.parametrize("enabled,nq,lookups", [
+    (True, 32, 256 * 10), (True, 300, 512 * 10), (False, 32, 0)])
+def test_ivf_pq_merge_id_lookups_from_static_shapes(small_index, monkeypatch,
+                                                    enabled, nq, lookups):
+    """One increment a list-major call, from shapes alone: the padded
+    slice's rows x k row ids the merge looks up; nothing with obs off."""
+    _, _, index = small_index
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda v: pytest.fail("device sync"))
+    q = np.random.default_rng(5).standard_normal((nq, 16)).astype(np.float32)
+    obs.reset()
+    obs.enable(enabled)
+    try:
+        ivf_pq.search(ivf_pq.SearchParams(n_probes=4,
+                                          score_mode="recon8_list"),
+                      index, q, 10)
+        assert obs.counter("ivf_pq.merge.id_lookups").value == lookups
+    finally:
+        obs.disable()
+        obs.reset()
 
 
 def test_refine_program_names_its_stage(small_index):

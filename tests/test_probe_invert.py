@@ -11,11 +11,15 @@ import numpy as np
 import jax.numpy as jnp
 import pytest
 
+from raft_tpu.matrix.select_k import _select_k_impl
 from raft_tpu.neighbors.probe_invert import (
     chunk_count,
     invert_probes,
     invert_probes_count,
     invert_probes_sort,
+    macro_batch_rows,
+    macro_batched,
+    regroup_merge,
 )
 
 
@@ -140,3 +144,76 @@ def test_invert_dispatch_honors_tuned_key(monkeypatch, rng):
             assert x is None and y is None
             continue
         assert np.array_equal(np.asarray(x), np.asarray(y))
+
+
+def _regroup_ids_first(tables, vals, slots, slot_rows, nq, n_probes, k,
+                       select_min):
+    """The gather-first regroup: every trimmed slot of every chunk row
+    turned into a row id, then the ids moved with the candidates."""
+    lof, g0, s0 = tables.lof, tables.g0, tables.s0
+    kk = vals.shape[-1]
+    rows = jnp.take_along_axis(slot_rows[lof][:, None, :],
+                               jnp.maximum(slots, 0), axis=2)
+    rows = jnp.where(slots >= 0, rows, -1)
+    cand_v, cand_r = vals[g0, s0], rows[g0, s0]
+    if tables.pair_valid is not None:
+        m = tables.pair_valid[:, None]
+        cand_v = jnp.where(m, cand_v, jnp.inf if select_min else -jnp.inf)
+        cand_r = jnp.where(m, cand_r, -1)
+    v, pos = _select_k_impl(cand_v.reshape(nq, n_probes * kk), k, select_min)
+    return v, jnp.take_along_axis(cand_r.reshape(nq, n_probes * kk), pos,
+                                  axis=1)
+
+
+@pytest.mark.parametrize("masked", [False, True])
+@pytest.mark.parametrize("select_min", [True, False])
+def test_regroup_merge_ids_after_merge(masked, select_min, rng):
+    """Looking ids up for the merged top-k only gives the values and ids
+    of the gather-first regroup bit for bit: tied scores, slots -1 (a
+    kernel's invalid candidate) and slots past a short list's end (row
+    -1 in the slot table), and budget-masked pairs."""
+    nq, n_probes, n_lists, chunk, max_list, kk, k = 40, 5, 12, 8, 20, 6, 24
+    raw = rng.integers(0, n_lists, size=(nq, n_probes)).astype(np.int32)
+    pv = None
+    if masked:
+        pv = rng.random((nq, n_probes)) < 0.6
+        pv[:, 0] = True
+        pv = jnp.asarray(pv)
+    tables = invert_probes_sort(jnp.asarray(raw), n_lists, chunk, pv)
+    ncb = int(tables.lof.shape[0])
+
+    # lists of 2..max_list rows; a few live slots filtered out (-1)
+    lens = rng.integers(2, max_list + 1, size=n_lists)
+    lens[-1] = 3  # shorter than kk
+    slot_rows = np.where(np.arange(max_list) < lens[:, None],
+                         rng.permutation(n_lists * max_list).reshape(
+                             n_lists, max_list), -1)
+    slot_rows[rng.random(slot_rows.shape) < 0.1] = -1
+    slots = np.argsort(rng.random((ncb, chunk, max_list)), axis=2)[..., :kk]
+    slots[rng.random(slots.shape) < 0.3] = -1
+    worst = np.inf if select_min else -np.inf
+    vals = rng.integers(0, 4, size=slots.shape).astype(np.float32)  # ties
+    vals = np.where(slots >= 0, vals, worst)
+    args = (tables, jnp.asarray(vals), jnp.asarray(slots.astype(np.int32)),
+            jnp.asarray(slot_rows.astype(np.int32)))
+
+    v, ids = regroup_merge(*args, _select_k_impl, nq, n_probes, k, select_min)
+    rv, rids = _regroup_ids_first(*args, nq, n_probes, k, select_min)
+    np.testing.assert_array_equal(np.asarray(v), np.asarray(rv))
+    np.testing.assert_array_equal(np.asarray(ids), np.asarray(rids))
+    ids = np.asarray(ids)
+    assert (ids == -1).any() and (ids >= 0).any()
+
+
+@pytest.mark.parametrize("nq", [0, 1, 256, 300, 4096, 4097, 10_000])
+def test_macro_batch_rows_counts_padded_slices(nq):
+    """`macro_batch_rows` is the row count `macro_batched` runs."""
+    seen = []
+
+    def fn(sl):
+        seen.append(sl.shape[0])
+        return jnp.zeros((sl.shape[0], 1)), jnp.zeros((sl.shape[0], 1),
+                                                       jnp.int32)
+
+    macro_batched(fn, jnp.zeros((nq, 2)), 1)
+    assert macro_batch_rows(nq) == sum(seen)

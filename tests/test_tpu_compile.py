@@ -86,33 +86,61 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_listmajor_trim_lowers_to_topk(one_chip):
-    """At the batch cell's widths (a 4,096-query slice, 1,024 lists x
-    2,944 slots, rot 96, int8 store, k = 40, 8 probes, bf16 trim) the
-    list-major trim compiles to the TPU's partial-select TopK over the
-    2,944-slot rows, each in the rows-on-lanes layout ({0,1}) it gets
-    outside a loop body, and no sort runs over that axis."""
-    import re
+#: the batch cell's list-major widths: a 4,096-query slice, 1,024 lists x
+#: 2,944 slots, 8 probes, k = 40, chunk 128: 356 chunks a superblock
+LM_NQ, LM_SLOTS, LM_PROBES, LM_K, LM_SB = 4096, 2944, 8, 40, 356
 
+
+@pytest.fixture(scope="module")
+def listmajor_hlo(one_chip):
+    """The IVF-PQ list-major search program at the batch cell's widths
+    (rot 96, int8 store, bf16 trim), compiled for one v5e."""
     from raft_tpu.distance.distance_types import DistanceType
     from raft_tpu.neighbors.ivf_pq import _search_impl_recon8_listmajor
 
-    slots = 2944
-    shapes = [((4096, D), f32), ((D, D), f32), ((N_LISTS, D), f32),
-              ((N_LISTS, slots, D), jnp.int8), ((D,), f32),
-              ((N_LISTS, slots), f32), ((N_LISTS, slots), i32)]
+    shapes = [((LM_NQ, D), f32), ((D, D), f32), ((N_LISTS, D), f32),
+              ((N_LISTS, LM_SLOTS, D), jnp.int8), ((D,), f32),
+              ((N_LISTS, LM_SLOTS), f32), ((N_LISTS, LM_SLOTS), i32)]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
-    txt = _search_impl_recon8_listmajor.lower(
-        *args, k=40, n_probes=8, metric=DistanceType.L2Expanded,
+    return _search_impl_recon8_listmajor.lower(
+        *args, k=LM_K, n_probes=LM_PROBES, metric=DistanceType.L2Expanded,
         trim_bf16=True).compile().as_text()
+
+
+def test_listmajor_trim_lowers_to_topk(listmajor_hlo):
+    """At the batch cell's widths the list-major trim compiles to the
+    TPU's partial-select TopK over the 2,944-slot rows, each in the
+    rows-on-lanes layout ({0,1}) it gets outside a loop body, and no
+    sort runs over that axis."""
+    import re
+
+    txt = listmajor_hlo
     defs = {name: (dims.split(",")[-1], layout) for name, dims, layout in
             re.findall(r"(%[\w.-]+) = \w+\[([\d,]*)\]\{([\d,]*)", txt)}
     operands = re.findall(
         r"custom-call\((%[\w.-]+)\), custom_call_target=\"TopK\"", txt)
-    trims = [defs[o][1] for o in operands if defs[o][0] == str(slots)]
+    trims = [defs[o][1] for o in operands if defs[o][0] == str(LM_SLOTS)]
     assert trims and all(layout == "0,1" for layout in trims), trims
-    assert not re.search(rf"\[[\d,]*\b{slots}\b[\d,]*\][^\n]* sort\(", txt)
+    assert not re.search(rf"\[[\d,]*\b{LM_SLOTS}\b[\d,]*\][^\n]* sort\(",
+                         txt)
+
+
+def test_listmajor_ids_looked_up_after_merge(listmajor_hlo):
+    """The row ids are looked up for the merged top-k only: no gather
+    makes an output of one entry per trimmed slot of every chunk row
+    (sb x chunk x k), and the widest int32 gather is the regroup of the
+    nq x n_probes x k candidate slots."""
+    import math
+    import re
+
+    sizes = [(dt, math.prod(int(d) for d in dims.split(",") if d))
+             for dt, dims in re.findall(
+                 r"= (\w+)\[([\d,]*)\]\{[^}]*\} gather\(", listmajor_hlo)]
+    assert sizes
+    assert LM_SB * CHUNK * LM_K not in {n for _, n in sizes}
+    assert max(n for dt, n in sizes if dt == "s32") <= \
+        LM_NQ * LM_PROBES * LM_K
 
 
 #: wiki1m-rabitq's widths: 768-d (24 code words), 4,096 lists, 4-bit
