@@ -5,10 +5,8 @@ lines, which engine/precision defaults the numbers support changing and
 which measurements are still missing. Decision rules mirror NOTES.md's
 on-chip queue:
 
-- trim_engine: pallas becomes the recon8_list default if it beats the
-  approx trim by >10% QPS at equal (±0.01) recall.
-- score_dtype: int8 likewise vs bf16.
-- internal_distance_dtype: bfloat16 likewise vs float32.
+- score_dtype: int8 becomes the recon8_list default if it beats bf16
+  by >10% QPS at equal (±0.01) recall.
 - IVF-Flat engine: the fastest of query/list/pallas at >= query-engine
   recall - 0.01.
 - trainer precision: bf16 trainer OK if its inertia is within 0.5% of
@@ -35,19 +33,13 @@ def hint(out, name, winner, detail):
 
 _EXPECTED_KEYS = (
     "search_recon8_list_bf16_float32_approx_np32",
-    "search_recon8_list_bf16_float32_pallas_np32",
     "search_recon8_list_int8_float32_approx_np32",
-    "search_recon8_list_int8_float32_pallas_np32",
     "search_recon8_list_bf16_bfloat16_approx_np32",
     "search_lut_bf16_float32_approx_np32",
-    "search_cb0_int8_bf16trim_np32",
-    "search_cb8_int8_bf16trim_np32",
     "search_recon8_list_int8_bfloat16_exact_np32",
     "search_unrefined_np8_approx",
     "search_unrefined_np8_exact",
-    "search_refined_np8_chunk128",
-    "search_refined_np8_chunk64",
-    "search_refined_np8_chunk32",
+    "search_refined_np8",
     "flat_search_query_np32",
     "flat_search_list_np32",
     "flat_search_pallas_np32",
@@ -93,17 +85,9 @@ def main(path: str):
                  f"recall gap {ra:.3f} vs {rb:.3f} — not a pure speed trade")
 
     base = "search_recon8_list_bf16_float32_approx_np32"
-    cmp("trim_engine_default", base,
-        "search_recon8_list_bf16_float32_pallas_np32", "approx", "pallas")
     cmp("bf_engine_default", "bf_tiled_1M", "bf_pallas_1M", "tiled", "pallas")
     cmp("score_dtype_default", base,
         "search_recon8_list_int8_float32_approx_np32", "bf16", "int8")
-    cmp("int8_trim_engine", "search_recon8_list_int8_float32_approx_np32",
-        "search_recon8_list_int8_float32_pallas_np32", "approx", "pallas")
-    cmp("internal_distance_dtype", base,
-        "search_recon8_list_bf16_bfloat16_approx_np32", "float32", "bfloat16")
-    cmp("pq_auto_engine", "search_lut_bf16_float32_approx_np32", base,
-        "lut", "recon8_list")
 
     def pick_best(records, baseline=None, ref_recall=None, margin=1.1):
         """Shared pick-best among measured records: drop entries more
@@ -139,46 +123,12 @@ def main(path: str):
     if w is not None:
         hint(out, "ivf_flat_engine_default", w, detail)
 
-    # listmajor chunk race (refined np8): best QPS at >= max-recall - 0.01;
-    # the 128 default keeps the win unless a smaller chunk beats it by
-    # >10%. The floor is the MAX measured recall (all three rows are the
-    # same engine, differing only in trim noise) — so a recall-degraded
-    # baseline cannot keep the win from outside the floor.
-    chunks = {c: R.get(f"search_refined_np8_chunk{c}") for c in (128, 64, 32)}
-    cmax = [(_recall(v) or 0.0) for v in chunks.values() if _qps(v)]
-    w, detail = pick_best(chunks, baseline=128,
-                          ref_recall=max(cmax) if cmax else None)
-    if w is not None:
-        hint(out, "listmajor_chunk", w, detail)
-
-    # chunk_block structure race: 0 (one einsum per superblock, the
-    # round-5 default) vs the inner-lax.map granularities; recall floor =
-    # max measured (same engine, trim noise only), the 0 baseline keeps
-    # the win unless a positive block beats it by >10%
-    cbs = {c: R.get(f"search_cb{c}_int8_bf16trim_np32") for c in (0, 8, 32)}
-    # (32 tolerated if an older record has it; the race now runs {0, 8})
-    cbmax = [(_recall(v) or 0.0) for v in cbs.values() if _qps(v)]
-    w, detail = pick_best(cbs, baseline=0,
-                          ref_recall=max(cbmax) if cbmax else None)
-    if w is not None:
-        hint(out, "listmajor_chunk_block", w, detail)
-
     ih, ib = R.get("inertia_highest"), R.get("inertia_bf16")
     if ih and ib:
         rel = (ib - ih) / abs(ih)
         hint(out, "trainer_precision",
              "bf16 (DEFAULT)" if rel <= 0.005 else "keep HIGHEST",
              f"bf16 inertia {rel:+.4%} vs HIGHEST")
-
-    # prefer the *_trueS re-run (measured at the built index's real slot
-    # count) over the early-banked S=1024 numbers when both are valid
-    mb, mi = R.get("micro_bf16_trueS"), R.get("micro_int8_trueS")
-    if not (isinstance(mb, dict) and isinstance(mi, dict)
-            and "tflops" in mb and "tflops" in mi):
-        mb, mi = R.get("micro_bf16"), R.get("micro_int8")
-    if isinstance(mb, dict) and isinstance(mi, dict) and "tflops" in mb and "tflops" in mi:
-        hint(out, "chunk_matmul", "int8" if mi["tflops"] > 1.1 * mb["tflops"] else "bf16",
-             f"int8 {mi['tflops']} vs bf16 {mb['tflops']} TFLOP/s")
 
     for h in out:
         print(json.dumps(h))
@@ -197,10 +147,7 @@ def main(path: str):
 # (raft_tpu/core/tuned.py); everything else stays informational.
 # value = (tuned key, caster applied to the recommend before writing)
 _TUNABLE = {
-    "pq_auto_engine": ("pq_auto_engine", str),
     "ivf_flat_engine_default": ("flat_auto_engine", str),
-    "listmajor_chunk": ("listmajor_chunk", int),
-    "listmajor_chunk_block": ("listmajor_chunk_block", int),
 }
 
 

@@ -308,7 +308,7 @@ def _run_stage_decomposition(smoke):
 
     def stage_score_trim_super(lof, qs):
         # round-5 structure: whole superblocks scored with ONE batched
-        # einsum each (chunk_block=0) — same math as stage_score_trim but
+        # einsum each — same math as stage_score_trim but
         # ~nsuper outer iterations instead of ncb/8 serialized inner scan
         # steps; the delta between the two rows IS the scan overhead the
         # 60x gap hypothesis blames

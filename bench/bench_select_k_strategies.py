@@ -3,8 +3,8 @@ the Pallas counting-select engine — plus the OPERAND-level race of the
 fused distance+select-k kernel vs the materializing two-phase scan
 (`matrix.scan_select_k`), the measurement behind the tuned
 `select_k_strategy` key, and (ISSUE 11) the INTEGER-scan races behind
-`select_k_strategy_int8` (exact fused int8 PQ-recon trim vs the pallas
-bin trim).
+`select_k_strategy_int8` (exact fused int8 PQ-recon trim vs the XLA
+int8 trim it would replace).
 
 Reference parity: matrix/detail/select_k.cuh:67-88 picks warpsort vs radix
 from an empirically-derived (batch, len, k) heuristic measured with
@@ -172,10 +172,11 @@ def main(smoke: bool = False):
 
     # -- integer-scan races (ISSUE 11) ---------------------------------
     # (a) int8 PQ-recon trim: the exact fused int8 scan (dispatch
-    #     strategy "fused_int8") vs the pallas bin trim — both score on
-    #     the int8 MXU path with identical quantization, so the race is
-    #     purely the trim geometry; a fused sweep flips the tuned
-    #     `select_k_strategy_int8` key.
+    #     strategy "fused_int8") vs the XLA int8 trim that
+    #     trim_engine="auto" runs otherwise — both score on the int8 MXU
+    #     path with identical quantization and both trims are exact, so
+    #     the race is purely the trim geometry; a fused win flips the
+    #     tuned `select_k_strategy_int8` key.
     from raft_tpu.neighbors import ivf_pq
 
     int_winners = {}
@@ -195,9 +196,9 @@ def main(smoke: bool = False):
     pq_entries = {
         "int8_fused": ivf_pq.SearchParams(
             n_probes=probes_i, trim_engine="fused", score_dtype="int8"),
-        "int8_pallas": ivf_pq.SearchParams(
+        "int8_xla": ivf_pq.SearchParams(
             n_probes=probes_i, score_mode="recon8_list",
-            trim_engine="pallas", score_dtype="int8"),
+            trim_engine="approx", score_dtype="int8"),
     }
     best = None
     timings = {}

@@ -3,7 +3,7 @@
 Ordered by decision value per minute of chip time: chunk-matmul + pairwise TFLOPS
 microbenches first, then the full 1M x 96 IVF-PQ build and the QPS +
 recall ladder over every PQ scoring engine (recon8_list bf16/int8 x
-approx/pallas trim, recon8, lut) and the refined low-probe config, a
+f32/bf16 trim scores, recon8) and the refined low-probe config, a
 second 1M-row IVF-Flat index laddering its three engines (query / list /
 fused residual scan), and LAST the stage-timed build breakdown + the
 bf16-vs-HIGHEST trainer-precision comparison (duplicate kmeans fits).
@@ -154,8 +154,6 @@ def main():
     from raft_tpu.neighbors import refine as refine_fn
     for mode, dt, idd, trim in () if not early else (
         ("recon8_list", "bf16", "float32", "approx"),
-        ("recon8_list", "bf16", "float32", "pallas"),  # fused list-scan kernel
-        ("recon8_list", "int8", "float32", "pallas"),  # in-kernel int8 MXU rate
         ("recon8_list", "int8", "float32", "approx"),
         ("recon8_list", "bf16", "bfloat16", "approx"),  # bf16 trim scores
         ("recon8_list", "int8", "bfloat16", "approx"),
@@ -175,33 +173,6 @@ def main():
             truth, nq, k, label=f"{mode}/{dt}/{idd}/{trim}",
         )
     _finish(R)  # the PQ engine ladder is the #1 default-flip input — bank it
-
-    # chunk_block structure race (round-5 restructure): 0 scores a whole
-    # superblock with ONE batched einsum (~nsuper scan iterations per
-    # batch); 8 restores the round-4 inner lax.map (~256 serialized scan
-    # iterations at this shape — the prime structural suspect for the
-    # measured 60x roofline gap, docs/perf.md). Raced on the round-4
-    # measured-best engine config; apply_profile_hints fits the
-    # listmajor_chunk_block tuned key from these rows.
-    from raft_tpu.core import tuned as _tuned0
-
-    p_cb = ivf_pq.SearchParams(
-        n_probes=32, score_mode="recon8_list", score_dtype="int8",
-        internal_distance_dtype="bfloat16",
-    )
-    # {0, 8} only: the decision is structural (superblock einsum vs the
-    # round-4 inner map); a third middle point costs a fresh ~30 s
-    # compile in a short chip window for no extra
-    # information
-    for cb in (0, 8) if early else ():
-        _tuned0._load()["listmajor_chunk_block"] = cb
-        measure_search(
-            f"search_cb{cb}_int8_bf16trim_np32",
-            lambda: ivf_pq.search(p_cb, index, queries, k),
-            truth, nq, k, label=f"chunk_block={cb}",
-        )
-    _tuned0.reload()  # drop the in-memory override, restoring disk state
-    _finish(R)
 
     # brute-force A/B at the same shape: tiled XLA path vs the fused
     # list-scan engine (dataset + truth already resident)
@@ -226,24 +197,16 @@ def main():
             truth, nq, k, label="bf fused-scan",
         )
 
-    # refined config (n_probes=8 + exact refine of 4k shortlist) raced
-    # over the listmajor chunk width: at np8 the P//chunk + n_lists
-    # fragmentation bound leaves 128-row chunks ~25% full (scan FLOPs/
-    # score bytes pull toward small chunks, store streams toward large —
-    # empirical). chunk=128 doubles as the plain refined-np8 record.
-    from raft_tpu.core import tuned as _tuned
-
+    # refined config (n_probes=8 + exact refine of 4k shortlist)
     p = ivf_pq.SearchParams(n_probes=8, score_mode="recon8_list")
 
     def run_refined():
         _, cand = ivf_pq.search(p, index, queries, 4 * k)
         return refine_fn(dataset, queries, cand, k)
 
-    for ch in (128, 64, 32) if early else ():
-        _tuned._load()["listmajor_chunk"] = ch
-        measure_search(f"search_refined_np8_chunk{ch}", run_refined,
-                       truth, nq, k, label=f"refined np8 chunk={ch}")
-    _tuned.reload()  # drop the in-memory override, restoring disk state
+    if early:
+        measure_search("search_refined_np8", run_refined, truth, nq, k,
+                       label="refined np8")
 
     # approx-vs-exact trim at unrefined np8 (the headline's PQ-scan shape;
     # pairs with the np32 exact row above for the VERDICT r4 #6 tax table)

@@ -34,7 +34,7 @@ def _build_distributed_recon(index: DistributedIvfPq,
     """Per-rank int8 reconstruction stores for the list-major engine,
     decoded from the packed codes inside shard_map (lazily, idempotent —
     the distributed build_reconstruction). With `pad_to_lanes` the slot
-    axis pads to the fused Pallas list-scan's 128-lane contract
+    axis pads to the fused list scan's lane contract
     (recon_norm +inf, slot gids -1 on pad slots — masked exactly like
     in-list padding); once padded, the store stays padded (monotone,
     same contract as the single-chip build_reconstruction)."""
@@ -71,9 +71,9 @@ def _build_distributed_recon(index: DistributedIvfPq,
 
 
 def _pad_distributed_recon(index: DistributedIvfPq, base: int) -> None:
-    """Pad the (sharded) recon store's slot axis to the Pallas lane
-    contract; no-op when already wide enough."""
-    from raft_tpu.ops.pq_list_scan import lane_padded
+    """Pad the (sharded) recon store's slot axis to the fused list
+    scan's lane contract; no-op when already wide enough."""
+    from raft_tpu.ops.fused_scan import lane_padded
 
     lpad = lane_padded(base)
     extra = lpad - int(index.recon8.shape[2])
@@ -228,8 +228,7 @@ def ivf_pq_search(index: DistributedIvfPq, queries, k: int, n_probes: int = 20,
     single-chip flagship uses — each rank streams each probed list once),
     "lut" (query-major, for tiny batches), or "auto" (same duplication
     heuristic as the single-chip `search`). With engine="recon8_list",
-    `trim_engine="pallas"` runs the bin-trimming fused list-scan per
-    rank, `trim_engine="fused"` the EXACT fused scan+select trim
+    `trim_engine="fused"` runs the EXACT fused scan+select trim
     (matrix/select_k list-scan dispatch; with score_dtype="int8" it is
     the dispatch layer's "fused_int8" int8-MXU strategy — ISSUE 11), and
     `score_dtype="int8"` scores with symmetric int8 queries — all
@@ -353,27 +352,20 @@ def ivf_pq_search(index: DistributedIvfPq, queries, k: int, n_probes: int = 20,
     out_spec = P(None, None) if mode == "replicated" else P(comms.axis, None)
 
     if engine == "auto":
-        if score_dtype == "int8" or trim_engine in ("pallas", "fused"):
-            # an explicit int8 / pallas-trim / fused-trim request pins
-            # the engine that honors it (same rule as the single-chip
-            # search: numerics must not depend on batch size or tuned
-            # state)
+        if score_dtype == "int8" or trim_engine == "fused":
+            # an explicit int8 / fused-trim request pins the engine that
+            # honors it (same rule as the single-chip search: numerics
+            # must not depend on batch size)
             engine = "recon8_list"
         else:
-            from raft_tpu.core import tuned
-
-            # same policy as ivf_pq._resolve_score_mode, restricted to
-            # the two distributed engines: on TPU the resolution NEVER
-            # lands on lut (its gather kernel-faults the device —
-            # docs/perf.md device-fault section), even from a
-            # CPU-rehearsal-fitted tuned key
-            on_tpu = jax.default_backend() == "tpu"
-            t = tuned.get("pq_auto_engine")
-            if t in ("recon8_list", "lut") and not (t == "lut" and on_tpu):
-                engine = t
-            else:
-                dup = q.shape[0] * n_probes / max(1, index.params.n_lists)
-                engine = "recon8_list" if (dup >= 4.0 or on_tpu) else "lut"
+            # ivf_pq._resolve_score_mode's duplication heuristic,
+            # restricted to the two distributed engines: on TPU the
+            # resolution NEVER lands on lut (its gather kernel-faults
+            # the device — docs/perf.md device-fault section)
+            dup = q.shape[0] * n_probes / max(1, index.params.n_lists)
+            engine = ("recon8_list"
+                      if dup >= 4.0 or jax.default_backend() == "tpu"
+                      else "lut")
     if engine not in ("recon8_list", "lut"):
         raise ValueError(f"unknown engine {engine!r}")
     if obs.enabled():
@@ -458,44 +450,18 @@ def ivf_pq_search(index: DistributedIvfPq, queries, k: int, n_probes: int = 20,
     def trim(out):
         return _pack_result(out[0], out[1], nq, coverage, repaired)
 
-    if trim_engine not in ("approx", "pallas", "fused"):
+    if trim_engine not in ("approx", "fused"):
         raise ValueError(f"unknown trim_engine {trim_engine!r}")
-    for eng_req in ("pallas", "fused"):
-        if trim_engine == eng_req and engine != "recon8_list":
-            raise ValueError(
-                f"trim_engine='{eng_req}' requires engine='recon8_list'"
-            )
+    if trim_engine == "fused" and engine != "recon8_list":
+        raise ValueError("trim_engine='fused' requires engine='recon8_list'")
     if score_dtype not in ("bf16", "int8"):
         raise ValueError(f"unknown score_dtype {score_dtype!r}")
     if score_dtype == "int8" and engine != "recon8_list":
         raise ValueError("score_dtype='int8' requires engine='recon8_list'")
     int8_q = score_dtype == "int8"
     if engine == "recon8_list":
-        use_pallas_trim = trim_engine == "pallas"
         use_fused_trim = trim_engine == "fused"
         fused_kb = None
-        if use_pallas_trim:
-            # the fused list-scan's shape contract, checked per rank
-            # (max_list is global across ranks, so this is static)
-            from raft_tpu.ops.pq_list_scan import (
-                _BINS, fits_pallas, lane_padded,
-            )
-
-            if kk > _BINS:
-                raise ValueError(
-                    f"trim_engine='pallas' caps per-list candidates at "
-                    f"{_BINS}; k={kk}"
-                )
-            # rotation is (rot_dim, dim); the scanned store axis is rot_dim
-            lpad = lane_padded(int(index.codes.shape[2]))
-            if not fits_pallas(128, lpad, int(index.rotation.shape[0])):
-                raise ValueError(
-                    f"trim_engine='pallas': list length {lpad} exceeds the "
-                    "kernel's VMEM envelope; use trim_engine='approx'"
-                )
-            from raft_tpu.neighbors.ivf_pq import (
-                _search_impl_recon8_listmajor_pallas,
-            )
         if use_fused_trim:
             # the EXACT fused trim per rank (ISSUE 11): bf16 or — with
             # score_dtype="int8" — the int8 MXU path, both through the
@@ -503,7 +469,7 @@ def ivf_pq_search(index: DistributedIvfPq, queries, k: int, n_probes: int = 20,
             # contract as the single-chip engine (the ONE shared
             # validation), checked per rank
             from raft_tpu.matrix.select_k import check_fused_list_request
-            from raft_tpu.ops.pq_list_scan import lane_padded
+            from raft_tpu.ops.fused_scan import lane_padded
 
             fused_kb = check_fused_list_request(
                 "trim_engine='fused'",
@@ -518,30 +484,14 @@ def ivf_pq_search(index: DistributedIvfPq, queries, k: int, n_probes: int = 20,
             # monotonic candidate-buffer bookkeeping, like the flat
             # engine's _build_distributed_resid
             index.fused_kb = fused_kb
-        _build_distributed_recon(
-            index, pad_to_lanes=use_pallas_trim or use_fused_trim)
+        _build_distributed_recon(index, pad_to_lanes=use_fused_trim)
         # ALWAYS the padded view: _build_distributed_recon keeps
         # slot_gids_pad width-matched to recon8 (== slot_gids until a
-        # pallas search pads the store in place — after which the approx
+        # fused search pads the store in place — after which the approx
         # engine must see the same padded width or its score/slot
         # broadcast shapes diverge)
         gid_source = index.slot_gids_pad
         interp = jax.default_backend() == "cpu"
-        from raft_tpu.ops.pq_list_scan import fold_variant
-
-        pfold = fold_variant()
-        # distributed list-major engines honor the same measured scoring
-        # granularity as the single-chip search (a chip race that rejects
-        # the superblock structure must flip the serving path too)
-        from raft_tpu.core import tuned as _tuned
-        from raft_tpu.neighbors.probe_invert import CHUNK_BLOCKS
-
-        cb = int(_tuned.get_choice("listmajor_chunk_block", CHUNK_BLOCKS, 0))
-        from raft_tpu.neighbors.probe_invert import resolve_setup_impls
-
-        # resolved OUTSIDE the jitted closure (and in the wrapper cache
-        # key below): a tuned flip mid-process must rebuild the wrapper
-        setup_impls = resolve_setup_impls(int(index.params.n_lists))
 
         def build_list():
             @functools.partial(jax.jit, static_argnames=("k", "use_pf"))
@@ -557,24 +507,13 @@ def ivf_pq_search(index: DistributedIvfPq, queries, k: int, n_probes: int = 20,
                             q, rotation, centers, recon8[0], scale,
                             rnorm[0], srows, kk, n_probes, metric,
                             interpret=interp, int8_queries=int8_q,
-                            kb=fused_kb, setup_impls=setup_impls,
-                            pvalid=pvk,
-                        )
-                    elif use_pallas_trim:
-                        v, gid = _search_impl_recon8_listmajor_pallas(
-                            q, rotation, centers, recon8[0], scale,
-                            rnorm[0], srows, kk, n_probes, metric,
-                            interpret=interp, int8_queries=int8_q,
-                            fold=pfold, setup_impls=setup_impls,
-                            pvalid=pvk,
+                            kb=fused_kb, pvalid=pvk,
                         )
                     else:
                         v, gid = _search_impl_recon8_listmajor(
                             q, rotation, centers, recon8[0], scale,
                             rnorm[0], srows, kk, n_probes, metric,
-                            chunk_block=cb, int8_queries=int8_q,
-                            setup_impls=setup_impls,
-                            pvalid=pvk,
+                            int8_queries=int8_q, pvalid=pvk,
                         )
                     return finish(v, gid, q, xs, base, valid, live)
 
@@ -596,8 +535,7 @@ def ivf_pq_search(index: DistributedIvfPq, queries, k: int, n_probes: int = 20,
             wrapper_key(
                 "pq_recon8_list", comms, mode, metric,
                 int(k), kk, n_probes, refine, refine_merged, pf_n, int8_q,
-                use_pallas_trim, use_fused_trim, fused_kb, interp, pfold,
-                cb, setup_impls, adaptive_on, qcfg),
+                use_fused_trim, fused_kb, interp, adaptive_on, qcfg),
             build_list,
         )
         return trim(run_list(
@@ -660,8 +598,7 @@ def _build_distributed_resid(index: DistributedIvfFlat, k: int) -> None:
     single-chip candidate-buffer bookkeeping: `index.fused_kb` records
     the compiled width and grows monotonically when `k` outruns it
     (never a silent per-list truncation)."""
-    from raft_tpu.ops.fused_scan import fused_kbuf
-    from raft_tpu.ops.pq_list_scan import lane_padded
+    from raft_tpu.ops.fused_scan import fused_kbuf, lane_padded
 
     base = int(index.list_data.shape[2])
     lpad = lane_padded(base)
@@ -783,22 +720,14 @@ def ivf_flat_search(index: DistributedIvfFlat, queries, k: int, n_probes: int = 
     # fixed path, unused and DCE'd) so each engine keeps one body/spec
     pv_rep = comms.replicate(
         keep if keep is not None else jnp.zeros((1, 1), bool))
-    from raft_tpu.neighbors.probe_invert import resolve_setup_impls
-
-    # resolved OUTSIDE the jitted closures and keyed in the wrapper cache
-    # (a tuned flip mid-process must rebuild the wrapper); n_lists engages
-    # the _COUNT_MAX_LISTS guard, engine="flat" keys the qs impl to the
-    # flat engines' f32-HIGHEST precision contract (ADVICE r5)
-    setup_impls = resolve_setup_impls(int(index.params.n_lists), engine="flat")
 
     def pack(v, gid):
         return _pack_result(v, gid, nq, coverage, repaired)
 
     if engine == "pallas":
         from raft_tpu.ops.fused_scan import (
-            FUSED_MAX_K, fits_fused_list, fused_kbuf,
+            FUSED_MAX_K, fits_fused_list, fused_kbuf, lane_padded,
         )
-        from raft_tpu.ops.pq_list_scan import lane_padded
 
         if int(k) > FUSED_MAX_K:
             raise ValueError(
@@ -832,7 +761,6 @@ def ivf_flat_search(index: DistributedIvfFlat, queries, k: int, n_probes: int = 
                         q, centers, resid[0], rnorm[0],
                         _shard_filtered(gid_tbl[0], bits, pf_n, use_pf),
                         k, n_probes, metric, kb=kb, interpret=interp,
-                        setup_impls=setup_impls,
                         fault_key=faults.trace_key(),
                         pvalid=pv if adaptive_on else None,
                     )
@@ -857,7 +785,7 @@ def ivf_flat_search(index: DistributedIvfFlat, queries, k: int, n_probes: int = 
         run_pallas = _cached_wrapper(
             wrapper_key(
                 "flat_pallas", comms, mode, metric,
-                n_probes, pf_n, interp, kb, setup_impls, adaptive_on, qcfg),
+                n_probes, pf_n, interp, kb, adaptive_on, qcfg),
             build_pallas,
         )
         v, gid = run_pallas(index.resid_bf16, index.resid_norm,
@@ -865,18 +793,7 @@ def ivf_flat_search(index: DistributedIvfFlat, queries, k: int, n_probes: int = 
                             live_rep, pv_rep, int(k), prefilter is not None)
         return pack(v, gid)
 
-    if engine == "query":
-        impl, cb = _search_impl, None
-    else:
-        from raft_tpu.core import tuned as _tuned
-        from raft_tpu.neighbors.probe_invert import CHUNK_BLOCKS
-
-        cb = int(_tuned.get_choice("listmajor_chunk_block", CHUNK_BLOCKS, 0))
-        # setup_impls forwarded (ADVICE r5): without it the tuned
-        # invert/qs flips were in the cache key but never reached the
-        # traced program — the wrapper rebuilt, then traced the default
-        impl = functools.partial(_search_impl_listmajor, chunk_block=cb,
-                                 setup_impls=setup_impls)
+    impl = _search_impl if engine == "query" else _search_impl_listmajor
 
     def build_flat():
         @functools.partial(jax.jit, static_argnames=("k", "use_pf"))
@@ -910,7 +827,7 @@ def ivf_flat_search(index: DistributedIvfFlat, queries, k: int, n_probes: int = 
     run = _cached_wrapper(
         wrapper_key(
             "flat", comms, mode, metric, n_probes, pf_n,
-            engine, cb, setup_impls, adaptive_on, qcfg),
+            engine, adaptive_on, qcfg),
         build_flat,
     )
     v, gid = run(index.list_data, index.slot_gids, index.centers, q, pf_bits,

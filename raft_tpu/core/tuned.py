@@ -58,23 +58,6 @@ TUNED_KEYS = {
         "bench": "bench/bench_comms.py"},
     "hints": {
         "kind": "hints", "choices": None, "bench": None},
-    "invert_impl": {
-        "kind": "choice", "choices": ("sort", "count"),
-        "bench": "bench/bench_invert_race.py"},
-    "listmajor_chunk": {
-        "kind": "int", "choices": None,
-        "bench": "bench/apply_profile_hints.py"},
-    "listmajor_chunk_block": {
-        "kind": "choice", "choices": (0, 8, 16, 32, 64),
-        "bench": "bench/apply_profile_hints.py"},
-    "listmajor_qs_impl": {
-        "kind": "choice", "choices": ("gather", "onehot_bf16",
-                                      "onehot_f32h"),
-        "bench": None},
-    "listmajor_qs_impl_flat": {
-        "kind": "choice", "choices": ("gather", "onehot_bf16",
-                                      "onehot_f32h"),
-        "bench": None},
     "mnmg_query_sharded_min_nq": {
         "kind": "int", "choices": None, "bench": "bench/bench_mnmg_merge.py"},
     "mnmg_query_sharded_min_nq_per_k": {
@@ -83,14 +66,6 @@ TUNED_KEYS = {
     "mnmg_replicated_merge_schedule": {
         "kind": "choice", "choices": ("tournament", "allgather"),
         "bench": "bench/bench_comms.py"},
-    "pallas_fold": {
-        "kind": "choice", "choices": ("exact", "packed"),
-        "bench": "bench/bench_pallas_scan.py"},
-    "pallas_rot_pad": {
-        "kind": "bool", "choices": None, "bench": None},
-    "pq_auto_engine": {
-        "kind": "choice", "choices": ("lut", "recon8", "recon8_list"),
-        "bench": "bench/apply_profile_hints.py"},
     "rabitq_query_bits": {
         "kind": "int", "choices": None, "bench": "bench/bench_ivf_rabitq.py"},
     "rabitq_rerank_mult": {
@@ -142,8 +117,7 @@ def get(key: str, default: Any = None) -> Any:
 def get_choice(key: str, allowed, default):
     """Tuned value for `key` validated against an allowed set; falls back
     to `default` on a missing or out-of-set value. Shared by dispatch
-    sites that must agree on the honored set (e.g. the two list-major
-    engines' `listmajor_chunk_block`)."""
+    sites that must agree on the honored set."""
     v = get(key, default)
     return v if v in allowed else default
 

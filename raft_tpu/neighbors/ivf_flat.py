@@ -598,7 +598,7 @@ def _search_impl(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "k", "n_probes", "metric", "chunk", "chunk_block", "setup_impls",
+        "k", "n_probes", "metric", "chunk",
     ),
 )
 def _search_impl_listmajor(
@@ -610,8 +610,6 @@ def _search_impl_listmajor(
     n_probes: int,
     metric: DistanceType,
     chunk: int = 128,
-    chunk_block: int = 0,
-    setup_impls: tuple = ("sort", "gather"),
     pvalid: jax.Array = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """List-major search: each list's vectors stream from HBM once per
@@ -624,12 +622,7 @@ def _search_impl_listmajor(
     `pvalid` (adaptive probe budgets): masked pairs are dropped before
     inversion and masked again at the regroup."""
     from raft_tpu.distance.pairwise import _MATMUL_PRECISION
-    from raft_tpu.neighbors.probe_invert import (
-        gather_query_rows,
-        invert_probes_count,
-        invert_probes_sort,
-        score_and_select,
-    )
+    from raft_tpu.neighbors.probe_invert import invert_probes, score_and_select
 
     nq, dim = queries.shape
     n_lists, max_list, _ = list_data.shape
@@ -638,10 +631,7 @@ def _search_impl_listmajor(
 
     cs, coarse_min = _coarse_scores(queries, centers, metric)
     _, probes = _select_k_impl(cs, n_probes, coarse_min)
-    # impls resolved by the caller OUTSIDE this jit (static args)
-    invert_impl, qs_impl = setup_impls
-    invert = invert_probes_count if invert_impl == "count" else invert_probes_sort
-    tables = invert(probes, n_lists, chunk, pvalid)
+    tables = invert_probes(probes, n_lists, chunk, pvalid)
 
     qf = queries.astype(jnp.float32)
     q_pad = jnp.concatenate([qf, jnp.zeros((1, dim), jnp.float32)])
@@ -650,7 +640,7 @@ def _search_impl_listmajor(
         lofb, qids = inp  # (CB,), (CB, chunk)
         v = list_data[lofb].astype(jnp.float32)  # only read of these vectors
         srows = slot_rows[lofb]
-        qs = gather_query_rows(q_pad, qids, qs_impl)  # (CB, chunk, dim)
+        qs = q_pad[qids]  # (CB, chunk, dim)
         dots = jnp.einsum("lqd,lsd->lqs", qs, v, precision=_MATMUL_PRECISION)
         if metric == DistanceType.InnerProduct:
             score = dots
@@ -662,7 +652,7 @@ def _search_impl_listmajor(
 
     v, ids = score_and_select(
         tables, block, slot_rows, _select_k_impl, nq, n_probes, k, select_min,
-        chunk, chunk_block, max_list, stage="ivf_flat",
+        chunk, max_list, stage="ivf_flat",
     )
     if metric == DistanceType.L2SqrtExpanded:
         v = jnp.sqrt(v)
@@ -671,7 +661,7 @@ def _search_impl_listmajor(
 
 def _pad_store_to_lanes(index: Index, k: int) -> None:
     """Monotone in-place pad of the list store to the fused Pallas scan's
-    lane contract (ops/pq_list_scan.lane_padded). Pad slots carry
+    lane contract (ops/fused_scan.lane_padded). Pad slots carry
     slot_rows=-1 and zero vectors, which every engine already masks; once
     padded the store stays padded (other engines recompile once for the
     wider shape and scan the masked pad slots).
@@ -690,8 +680,7 @@ def _pad_store_to_lanes(index: Index, k: int) -> None:
     (monotone, like the lane pad) — before this check existed, only a
     store-shape change triggered the rebuild and a k past the compiled
     width silently truncated the per-list candidates."""
-    from raft_tpu.ops.fused_scan import fused_kbuf
-    from raft_tpu.ops.pq_list_scan import lane_padded
+    from raft_tpu.ops.fused_scan import fused_kbuf, lane_padded
 
     max_list = index.list_data.shape[1]
     extra = lane_padded(max_list) - max_list
@@ -717,8 +706,7 @@ def _pad_store_to_lanes(index: Index, k: int) -> None:
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "k", "kb", "n_probes", "metric", "chunk", "interpret",
-        "setup_impls", "fault_key",
+        "k", "kb", "n_probes", "metric", "chunk", "interpret", "fault_key",
     ),
 )
 def _search_impl_listmajor_pallas(
@@ -733,7 +721,6 @@ def _search_impl_listmajor_pallas(
     chunk: int = 128,
     kb: int = None,
     interpret: bool = False,
-    setup_impls: tuple = ("sort", "gather"),
     fault_key=None,
     pvalid: jax.Array = None,
 ) -> Tuple[jax.Array, jax.Array]:
@@ -745,17 +732,14 @@ def _search_impl_listmajor_pallas(
     stream is half the bytes of raw f32). Scoring + an EXACT in-kernel
     partial top-k happen fused, so the (chunk, L) score tile never
     round-trips HBM — the TPU analogue of the reference's fused
-    interleaved scan (detail/ivf_flat_search.cuh:670), now without the
-    bin-trim recall tax the pq_list_scan engine paid. Probe inversion
+    interleaved scan (detail/ivf_flat_search.cuh:670). Probe inversion
     and the exact final merge are shared with the XLA trim engine.
     `kb` is the index's recorded candidate-buffer width (fused_kb);
     `fault_key` = faults.trace_key() so chaos plans retrace."""
     from raft_tpu.matrix.select_k import list_scan_select_k
     from raft_tpu.neighbors.probe_invert import (
         chunk_validity,
-        gather_query_rows,
-        invert_probes_count,
-        invert_probes_sort,
+        invert_probes,
         regroup_merge,
     )
 
@@ -766,9 +750,7 @@ def _search_impl_listmajor_pallas(
 
     cs, coarse_min = _coarse_scores(queries, centers, metric)
     _, probes = _select_k_impl(cs, n_probes, coarse_min)
-    invert_impl, qs_impl = setup_impls
-    invert = invert_probes_count if invert_impl == "count" else invert_probes_sort
-    tables = invert(probes, n_lists, chunk, pvalid)
+    tables = invert_probes(probes, n_lists, chunk, pvalid)
     lof, qid_tbl = tables.lof, tables.qid_tbl
     ncb = lof.shape[0]
     # empty chunks (trailing fragmentation + everything adaptive budgets
@@ -777,7 +759,7 @@ def _search_impl_listmajor_pallas(
 
     qf = queries.astype(jnp.float32)
     q_pad = jnp.concatenate([qf, jnp.zeros((1, dim), jnp.float32)])
-    qs = gather_query_rows(q_pad, qid_tbl, qs_impl)  # (ncb, chunk, dim)
+    qs = q_pad[qid_tbl]  # (ncb, chunk, dim)
     cent = centers[lof]  # (ncb, dim)
     qres = qs if ip else qs - cent[:, None, :]
 
@@ -829,9 +811,8 @@ def _pallas_fits(index, k: int) -> bool:
     machine-checks that every route into the fused kernel stays under
     this validation (docs/linting.md, kernelcheck catalog)."""
     from raft_tpu.ops.fused_scan import (
-        FUSED_MAX_K, fits_fused_list, fused_kbuf,
+        FUSED_MAX_K, fits_fused_list, fused_kbuf, lane_padded,
     )
-    from raft_tpu.ops.pq_list_scan import lane_padded
 
     if not 0 < k <= FUSED_MAX_K:
         return False
@@ -950,35 +931,26 @@ def search(
         _pad_store_to_lanes(index, k)
         srows = maybe_filter(index.slot_rows)
         from raft_tpu.core import faults
-        from raft_tpu.neighbors.probe_invert import resolve_setup_impls
 
-        setup = resolve_setup_impls(index.n_lists, engine="flat")
         vals, rows = macro_batched(
             lambda sl, pv=None: _search_impl_listmajor_pallas(
                 sl, index.centers, index.resid_bf16, index.resid_norm,
                 srows, k, n_probes, index.metric, kb=index.fused_kb,
                 interpret=jax.default_backend() == "cpu",
-                setup_impls=setup, fault_key=faults.trace_key(),
-                pvalid=pv,
+                fault_key=faults.trace_key(), pvalid=pv,
             ),
             jnp.asarray(q),
             int(k),
             extra=pvalid,
         )
     elif engine == "list":
-        from raft_tpu.core import tuned
-        from raft_tpu.neighbors.probe_invert import CHUNK_BLOCKS, macro_batched
+        from raft_tpu.neighbors.probe_invert import macro_batched
 
         srows = maybe_filter(index.slot_rows)
-        cb = int(tuned.get_choice("listmajor_chunk_block", CHUNK_BLOCKS, 0))
-        from raft_tpu.neighbors.probe_invert import resolve_setup_impls
-
-        setup = resolve_setup_impls(index.n_lists, engine="flat")
         vals, rows = macro_batched(
             lambda sl, pv=None: _search_impl_listmajor(
                 sl, index.centers, index.list_data, srows, k, n_probes,
-                index.metric, chunk_block=cb, setup_impls=setup,
-                pvalid=pv,
+                index.metric, pvalid=pv,
             ),
             jnp.asarray(q),
             int(k),

@@ -81,13 +81,6 @@ class IndexParams:
             raise ValueError(f"bad codebook_kind {self.codebook_kind}")
 
 
-# duplication (nq * n_probes / n_lists) at or below which the tuned
-# listmajor_chunk key applies: the profiler races chunk widths at the
-# refined np8 shape (dup = 32 at bench geometry); the np32 ladder
-# (dup = 128) is measured at the 128 default and must stay there
-_LOW_DUP_CHUNK_BOUND = 48
-
-
 @dataclasses.dataclass
 class SearchParams:
     """Mirrors ivf_pq::search_params (ivf_pq_types.hpp:112-150).
@@ -106,9 +99,9 @@ class SearchParams:
     # scores, halving that engine's dominant HBM stream (~1e-3 relative
     # ranking noise). Other engines keep f32 scores (the lut engine's LUT
     # dtype is `lut_dtype`). "float32" = exact f32 everywhere. "auto"
-    # (default) resolves from the measured tuned hint on TPU (bf16 trim
-    # won the 2026-08-01 chip ladder by 11% at equal recall) and to
-    # "float32" on every other backend, so CPU test numerics are stable.
+    # (default) is "bfloat16" when jax.default_backend() is "tpu" (bf16
+    # trim won the 2026-08-01 chip ladder by 11% at equal recall) and
+    # "float32" on every other backend.
     internal_distance_dtype: str = "auto"
     # Scoring engine (TPU design choice, no reference analogue):
     #   "lut"    — classic PQ LUT scoring (embedding-style gathers from the
@@ -122,11 +115,12 @@ class SearchParams:
     #              query buckets so each list's codes are streamed from HBM
     #              exactly once per batch (vs ~nq*n_probes/n_lists times in
     #              the query-major engines). Best for large query batches.
-    #   "auto"   — the measured tuned engine when a chip profile wrote
-    #              one; else recon8_list when the batch re-reads lists
-    #              >=4x, recon8 on TPU below that (lut's big flattened
-    #              gather kernel-faults TPU devices — docs/perf.md
-    #              device-fault section), lut on other backends.
+    #   "auto"   — recon8_list when score_dtype="int8" or trim_engine is
+    #              "exact"/"fused" (the only engine honoring them), or
+    #              when the batch re-reads lists >=4x (nq * n_probes /
+    #              n_lists >= 4); below that recon8 on TPU (lut's big
+    #              flattened gather kernel-faults TPU devices —
+    #              docs/perf.md device-fault section), lut elsewhere.
     # Default "auto" (VERDICT r4 #5): a default-constructed SearchParams
     # must land on the measured winner, never the faulting lut engine.
     score_mode: str = "auto"  # "lut" | "recon8" | "recon8_list" | "auto"
@@ -145,30 +139,23 @@ class SearchParams:
     #              partial-select TopK (no sort). Zero candidate loss,
     #              ties to the lower slot; an exact answer meets the
     #              "approx" recall floor, so both names run it.
-    #   "pallas" — fused Pallas list-scan (ops/pq_list_scan.py): scoring
-    #              and the best+second-best bin reduction stay in VMEM;
-    #              codes are read by scalar-prefetch indexing with no
-    #              gather copy. Experimental on-chip; incompatible with
-    #              score_dtype="int8", ignores internal_distance_dtype,
-    #              and caps per-list candidates at 256 (k <= 256).
-    #   "fused"  — fused distance + EXACT partial select-k
+    #   "fused"  — fused distance + exact partial select-k
     #              (matrix/select_k.list_scan_select_k, the select_k
-    #              dispatch layer's fused list kernel): same fused
-    #              geometry as "pallas" (score tile never in HBM,
-    #              scalar-prefetch code reads) but the in-kernel top-k
-    #              is exact, so the only loss left is the PQ
-    #              quantization itself. Caps per-list candidates at 256
-    #              (k <= 256). With score_dtype="int8" the scoring
-    #              matmul runs on the MXU's int8 datapath
-    #              (ISSUE 11: dispatch strategy "fused_int8" — int8
-    #              dot, int32 accumulate, per-row dequant on the VPU)
-    #              and bit-agrees with the "pallas" int8 trim's scores.
+    #              dispatch layer's fused list kernel): the score tile
+    #              never reaches HBM and codes are read from the int8
+    #              store by scalar-prefetch indexing, with the same
+    #              per-(query, list) candidates as the XLA trim up to
+    #              bf16 operand rounding. Caps per-list candidates at
+    #              256 (k <= 256). With score_dtype="int8" the scoring
+    #              matmul runs on the MXU's int8 datapath (dispatch
+    #              strategy "fused_int8": int8 dot, int32 accumulate,
+    #              per-row dequant on the VPU).
     #   "auto"   — "approx" unless the measured integer tuned key
     #              (matrix/select_k.INT8_SCAN_KEY, written by
     #              bench_select_k_strategies --apply on chip data)
     #              promotes the fused int8 trim for an int8-scored
     #              list-major search whose geometry fits the kernel.
-    trim_engine: str = "auto"  # "auto"|"approx"|"exact"|"pallas"|"fused"
+    trim_engine: str = "auto"  # "auto" | "approx" | "exact" | "fused"
     # -- adaptive probing (neighbors/probe_budget, ROADMAP item 2) --
     # per-query probe budgets from the coarse gap profile (+ radius
     # bounds for L2 when the index carries them); recall_target >= 1.0
@@ -607,13 +594,13 @@ def build_reconstruction(index: Index, pad_to_lanes: bool = False) -> Index:
     """Populate the int8 reconstruction store used by score_mode="recon8"
     (idempotent; called lazily from `search`).
 
-    With `pad_to_lanes` the store's slot axis is padded to a multiple of
-    128 lanes (>= 256) — the shape contract of the fused Pallas list-scan
-    (ops/pq_list_scan.py) — with `slot_rows_pad` marking pad slots
-    invalid and `recon_norm` +inf there, so every recon8 engine masks
-    them exactly like in-list padding. Only the pallas trim asks for the
-    padding (the default engines keep the tight store); once padded, the
-    store stays padded (monotone, still idempotent)."""
+    With `pad_to_lanes` the store's slot axis is padded to the fused
+    list scan's lane contract (ops/fused_scan.lane_padded) — with
+    `slot_rows_pad` marking pad slots invalid and `recon_norm` +inf
+    there, so every recon8 engine masks them exactly like in-list
+    padding. Only the fused trim asks for the padding (the default
+    engines keep the tight store); once padded, the store stays padded
+    (monotone, still idempotent)."""
     if index.recon8 is None:
         r8, scale, rnorm = _decode_quantize(
             index.codes, index.pq_centers, index.params.codebook_kind == PER_CLUSTER
@@ -621,7 +608,7 @@ def build_reconstruction(index: Index, pad_to_lanes: bool = False) -> Index:
         index.recon8, index.recon_scale, index.recon_norm = r8, scale, rnorm
         index.slot_rows_pad = index.slot_rows
     if pad_to_lanes:
-        from raft_tpu.ops.pq_list_scan import lane_padded
+        from raft_tpu.ops.fused_scan import lane_padded
 
         max_list = index.recon8.shape[1]
         extra = lane_padded(max_list) - max_list
@@ -644,30 +631,20 @@ def build_reconstruction(index: Index, pad_to_lanes: bool = False) -> Index:
 def _resolve_score_mode(params: SearchParams, nq: int, n_probes: int, n_lists: int) -> str:
     """Resolve score_mode="auto" to a concrete engine.
 
-    Order: an explicit int8/pallas request pins recon8_list (the only
-    engine honoring it); else a measured tuned key (`pq_auto_engine`,
-    written by bench/apply_profile_hints.py from chip data) wins; else
-    the duplication heuristic. On TPU the resolution NEVER lands on lut
-    (even from a tuned key): its flattened gather kernel-faulted the
-    device and a fault poisons the process backend — small batches get
-    the gather-free recon8 engine instead."""
+    Order: an explicit int8/exact/fused request pins recon8_list (the
+    only engine honoring it); else the duplication heuristic. On TPU
+    the resolution NEVER lands on lut: its flattened gather
+    kernel-faulted the device and a fault poisons the process backend —
+    small batches get the gather-free recon8 engine instead."""
     mode = params.score_mode
     if mode != "auto":
         return mode
-    if params.score_dtype == "int8" or params.trim_engine in (
-        "pallas", "exact", "fused"
-    ):
+    if params.score_dtype == "int8" or params.trim_engine in ("exact", "fused"):
         return "recon8_list"
-    from raft_tpu.core import tuned
-
-    on_tpu = jax.default_backend() == "tpu"
-    t = tuned.get("pq_auto_engine")
-    if t in ("lut", "recon8", "recon8_list") and not (t == "lut" and on_tpu):
-        return t
     dup = nq * n_probes / max(1, n_lists)
     if dup >= 4.0:
         return "recon8_list"
-    return "recon8" if on_tpu else "lut"
+    return "recon8" if jax.default_backend() == "tpu" else "lut"
 
 
 _LUT_TPU_OVERRIDE = "RAFT_TPU_ALLOW_LUT_TPU"
@@ -693,7 +670,7 @@ def _check_lut_allowed() -> None:
 def _quantize_query_rows(u):
     """Symmetric per-row int8 quantization for ScaNN-style scoring:
     returns (q8, row_scale) with u ~= q8 * row_scale. Shared by the XLA
-    and Pallas list-major engines — their parity depends on identical
+    and fused list-major trims — their parity depends on identical
     quantization."""
     ua = jnp.max(jnp.abs(u), axis=-1, keepdims=True) + 1e-12
     q8 = jnp.clip(jnp.round(u / ua * 127.0), -127, 127).astype(jnp.int8)
@@ -939,8 +916,7 @@ def _search_impl_recon8(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "k", "n_probes", "metric", "chunk", "chunk_block", "int8_queries",
-        "trim_bf16", "setup_impls",
+        "k", "n_probes", "metric", "chunk", "int8_queries", "trim_bf16",
     ),
 )
 def _search_impl_recon8_listmajor(
@@ -955,10 +931,8 @@ def _search_impl_recon8_listmajor(
     n_probes: int,
     metric: DistanceType,
     chunk: int = 128,
-    chunk_block: int = 0,
     int8_queries: bool = False,
     trim_bf16: bool = False,
-    setup_impls: tuple = ("sort", "gather"),
     pvalid: jax.Array = None,
 ):
     """List-major scoring: each list's codes are streamed from HBM once per
@@ -985,12 +959,7 @@ def _search_impl_recon8_listmajor(
 
     The coarse probe selection runs inside this same jit (one program,
     one dispatch)."""
-    from raft_tpu.neighbors.probe_invert import (
-        gather_query_rows,
-        invert_probes_count,
-        invert_probes_sort,
-        score_and_select,
-    )
+    from raft_tpu.neighbors.probe_invert import invert_probes, score_and_select
 
     nq = queries.shape[0]
     n_lists, max_list, rot_dim = recon8.shape
@@ -999,12 +968,8 @@ def _search_impl_recon8_listmajor(
     with jax.named_scope("ivf_pq.coarse"):
         q_rot, probes = _coarse_select(queries, rotation, centers, n_probes,
                                        metric)
-    # impls resolved by the caller OUTSIDE this jit (static args), so a
-    # tuned flip retraces instead of serving the stale program
-    invert_impl, qs_impl = setup_impls
-    invert = invert_probes_count if invert_impl == "count" else invert_probes_sort
     with jax.named_scope("ivf_pq.invert"):
-        tables = invert(probes, n_lists, chunk, pvalid)
+        tables = invert_probes(probes, n_lists, chunk, pvalid)
 
     with jax.named_scope("ivf_pq.scan"):
         q_pad = jnp.concatenate([q_rot, jnp.zeros((1, rot_dim), q_rot.dtype)])
@@ -1016,7 +981,7 @@ def _search_impl_recon8_listmajor(
         rn = recon_norm[lofb]
         srows = slot_rows[lofb]
         cent = centers[lofb]
-        qs = gather_query_rows(q_pad, qids, qs_impl)  # (CB, chunk, rot)
+        qs = q_pad[qids]  # (CB, chunk, rot)
         if metric == DistanceType.InnerProduct:
             qres = qs
         else:
@@ -1059,132 +1024,9 @@ def _search_impl_recon8_listmajor(
 
     v, rows_out = score_and_select(
         tables, block, slot_rows, _select_k_impl, nq, n_probes, k, select_min,
-        chunk, chunk_block, max_list, stage="ivf_pq",
+        chunk, max_list, stage="ivf_pq",
     )
     with jax.named_scope("ivf_pq.merge"):
-        v = v.astype(jnp.float32)
-        if metric == DistanceType.L2SqrtExpanded:
-            v = jnp.sqrt(jnp.maximum(v, 0.0))
-    return v, rows_out
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "k", "n_probes", "metric", "chunk", "interpret", "int8_queries", "fold",
-        "setup_impls",
-    ),
-)
-def _search_impl_recon8_listmajor_pallas(
-    queries,
-    rotation,
-    centers,
-    recon8,
-    recon_scale,
-    recon_norm,
-    slot_rows_pad,
-    k: int,
-    n_probes: int,
-    metric: DistanceType,
-    chunk: int = 128,
-    interpret: bool = False,
-    int8_queries: bool = False,
-    fold: str = "exact",
-    setup_impls: tuple = ("sort", "gather"),
-    pvalid: jax.Array = None,
-):
-    """List-major search with the fused Pallas list-scan trim
-    (ops/pq_list_scan.py): per chunk, scoring and the best+second-best
-    bin reduction happen inside one kernel, so the (chunk, L) score tile
-    never round-trips HBM and the codes are read straight from the index
-    by scalar-prefetch indexing (no gather copy). Everything around the
-    kernel — probe inversion, exact final merge — is shared with the XLA
-    trim engine."""
-    from raft_tpu.neighbors.probe_invert import (
-        gather_query_rows,
-        invert_probes_count,
-        invert_probes_sort,
-        regroup_merge,
-    )
-    from raft_tpu.ops.pq_list_scan import pq_list_scan, _BINS
-
-    nq = queries.shape[0]
-    n_lists, lpad, rot_dim = recon8.shape
-    select_min = metric != DistanceType.InnerProduct
-    ip = metric == DistanceType.InnerProduct
-
-    with jax.named_scope("ivf_pq.coarse"):
-        q_rot, probes = _coarse_select(queries, rotation, centers, n_probes,
-                                       metric)
-    invert_impl, qs_impl = setup_impls
-    invert = invert_probes_count if invert_impl == "count" else invert_probes_sort
-    with jax.named_scope("ivf_pq.invert"):
-        tables = invert(probes, n_lists, chunk, pvalid)
-    lof, qid_tbl = tables.lof, tables.qid_tbl
-    ncb = lof.shape[0]
-
-    with jax.named_scope("ivf_pq.scan"):
-        # per-chunk query residuals with the int8 store's scale folded in
-        # (the kernel then consumes raw int8 codes with no dequant multiply)
-        q_pad = jnp.concatenate([q_rot, jnp.zeros((1, rot_dim), q_rot.dtype)])
-        qs = gather_query_rows(q_pad, qid_tbl, qs_impl)  # (ncb, chunk, rot)
-        cent = centers[lof]  # (ncb, rot)
-        qres = qs if ip else qs - cent[:, None, :]
-        qres_s = qres * recon_scale[None, None, :]
-
-        # additive per-slot base: L2 -> recon norm; IP -> 0; invalid -> +inf
-        valid = slot_rows_pad >= 0
-        if ip:
-            # kernel minimizes base - dots = -dots on valid slots
-            base = jnp.where(valid, 0.0, jnp.inf)[:, None, :]
-        else:
-            base = jnp.where(valid, recon_norm, jnp.inf)[:, None, :]
-
-        if int8_queries:
-            # symmetric int8 scoring in-kernel (the XLA engine's int8 path,
-            # moved inside the fused scan): quantize each scale-folded query
-            # residual row to int8 and let the kernel dequant by the per-row
-            # scale after its int8 x int8 -> int32 matmul
-            q8, row_scale = _quantize_query_rows(qres_s)
-            vals, slot_idx = pq_list_scan(
-                lof, q8, recon8, base, inner_product=ip, interpret=interpret,
-                q_scale=row_scale, fold=fold,
-            )
-        else:
-            vals, slot_idx = pq_list_scan(
-                lof, qres_s, recon8, base, inner_product=ip, interpret=interpret,
-                fold=fold,
-            )  # (ncb, chunk, 512) minimizing
-
-        invalid = ~jnp.isfinite(vals)
-        slots = jnp.where(invalid, -1, slot_idx)
-
-        # undo the kernel's minimization frame and add per-query constants
-        if ip:
-            # IP score = dots + q.center; kernel returned -dots on valid slots
-            qdotc = jnp.einsum("cqd,cd->cq", qs, cent)
-            vals = jnp.where(invalid, -jnp.inf, -vals + qdotc[:, :, None])
-        else:
-            qcn = jnp.sum(qres**2, axis=2)  # (ncb, chunk)
-            vals = vals + qcn[:, :, None]
-
-    with jax.named_scope("ivf_pq.trim"):
-        # trim the bin candidates to the merge width kk (tiny exact top-k)
-        cands = vals.shape[-1]
-        kk = min(k, _BINS)
-        tv, tpos = _select_k_impl(
-            vals.reshape(ncb * vals.shape[1], cands), kk, select_min
-        )
-        ts = jnp.take_along_axis(slots.reshape(ncb * slots.shape[1], cands),
-                                 tpos, axis=1)
-        tv = tv.reshape(ncb, -1, kk)
-        ts = ts.reshape(ncb, -1, kk)
-
-    with jax.named_scope("ivf_pq.merge"):
-        v, rows_out = regroup_merge(
-            tables, tv, ts, slot_rows_pad, _select_k_impl, nq, n_probes,
-            int(k), select_min
-        )
         v = v.astype(jnp.float32)
         if metric == DistanceType.L2SqrtExpanded:
             v = jnp.sqrt(jnp.maximum(v, 0.0))
@@ -1195,7 +1037,7 @@ def _search_impl_recon8_listmajor_pallas(
     jax.jit,
     static_argnames=(
         "k", "n_probes", "metric", "chunk", "interpret", "int8_queries",
-        "kb", "setup_impls", "fault_key",
+        "kb", "fault_key",
     ),
 )
 def _search_impl_recon8_listmajor_fused(
@@ -1213,31 +1055,27 @@ def _search_impl_recon8_listmajor_fused(
     interpret: bool = False,
     int8_queries: bool = False,
     kb: int = None,
-    setup_impls: tuple = ("sort", "gather"),
     fault_key=None,
     pvalid: jax.Array = None,
 ):
     """List-major search with the fused distance + EXACT select-k trim
     (matrix/select_k.list_scan_select_k — the select_k dispatch layer's
-    fused list kernel): same fused geometry as the `pallas` trim (one
-    kernel per chunk scores the whole list straight out of the int8
-    store and the (chunk, L) score tile never round-trips HBM), but the
-    in-kernel partial top-k is exact with ties to the smaller slot, so
-    there is no bin-trim recall term — the per-(query, list) candidates
-    are exactly what trim_engine='exact' computes, without
-    materializing the scores. With `int8_queries` the scoring matmul
-    runs int8 x int8 -> int32 on the MXU's doubled int8 rate (dispatch
-    strategy "fused_int8"): rows quantize through the SAME
-    `_quantize_query_rows` as the pallas int8 trim, so the two engines'
-    scores are bit-identical f32 values. `kb` is the index's recorded
-    monotonic candidate-buffer width (`fused_kb`); `fault_key` =
-    faults.trace_key() so chaos plans retrace."""
+    fused list kernel): one kernel per chunk scores the whole list
+    straight out of the int8 store, so the (chunk, L) score tile never
+    round-trips HBM, and the in-kernel partial top-k is exact with ties
+    to the smaller slot — the per-(query, list) candidates are what
+    trim_engine='exact' computes, without materializing the scores.
+    With `int8_queries` the scoring matmul runs int8 x int8 -> int32 on
+    the MXU's doubled int8 rate (dispatch strategy "fused_int8"): rows
+    quantize through the SAME `_quantize_query_rows` as the XLA int8
+    trim, so the two trims return the same candidates, their scores
+    apart only by the order of one f32 add. `kb` is the index's
+    recorded monotonic candidate-buffer width (`fused_kb`); `fault_key`
+    = faults.trace_key() so chaos plans retrace."""
     from raft_tpu.matrix.select_k import list_scan_select_k
     from raft_tpu.neighbors.probe_invert import (
         chunk_validity,
-        gather_query_rows,
-        invert_probes_count,
-        invert_probes_sort,
+        invert_probes,
         regroup_merge,
     )
 
@@ -1249,16 +1087,14 @@ def _search_impl_recon8_listmajor_fused(
     with jax.named_scope("ivf_pq.coarse"):
         q_rot, probes = _coarse_select(queries, rotation, centers, n_probes,
                                        metric)
-    invert_impl, qs_impl = setup_impls
-    invert = invert_probes_count if invert_impl == "count" else invert_probes_sort
     with jax.named_scope("ivf_pq.invert"):
-        tables = invert(probes, n_lists, chunk, pvalid)
+        tables = invert_probes(probes, n_lists, chunk, pvalid)
     lof, qid_tbl = tables.lof, tables.qid_tbl
     with jax.named_scope("ivf_pq.scan"):
         cvalid = chunk_validity(qid_tbl, nq)  # empty chunks skip in-kernel
 
         q_pad = jnp.concatenate([q_rot, jnp.zeros((1, rot_dim), q_rot.dtype)])
-        qs = gather_query_rows(q_pad, qid_tbl, qs_impl)  # (ncb, chunk, rot)
+        qs = q_pad[qid_tbl]  # (ncb, chunk, rot)
         cent = centers[lof]
         qres = qs if ip else qs - cent[:, None, :]
         qres_s = qres * recon_scale[None, None, :]
@@ -1271,8 +1107,8 @@ def _search_impl_recon8_listmajor_fused(
 
         if int8_queries:
             # symmetric int8 scoring fused end to end: quantize the
-            # scale-folded residual rows exactly like the pallas trim and
-            # hand the int8 operands to the dispatch layer's int8 kernel
+            # scale-folded residual rows and hand the int8 operands to
+            # the dispatch layer's int8 kernel
             q8, row_scale = _quantize_query_rows(qres_s)
             vals, slot_idx = list_scan_select_k(
                 lof, q8, recon8, base, k, strategy="fused_int8",
@@ -1324,10 +1160,10 @@ def search(
     `search_with_filtering`). When fewer than k samples pass, the tail
     holds the worst distance with id -1.
 
-    Note: trim_engine='pallas' (experimental until validated on-chip) pads
-    the index's reconstruction store to lane multiples IN PLACE on first
-    use; later searches on the same index with other engines recompile for
-    the padded shape and scan the (masked) pad slots."""
+    Note: trim_engine='fused' pads the index's reconstruction store to
+    lane multiples IN PLACE on first use; later searches on the same
+    index with other engines recompile for the padded shape and scan the
+    (masked) pad slots."""
     from raft_tpu.core.validation import check_matrix
 
     q = check_matrix(queries, name="queries")
@@ -1338,7 +1174,7 @@ def search(
     # every engine masks candidate scores to the worst value wherever its
     # slot table reads -1 (before trim/selection), so a filtered view is
     # the entire filtering mechanism; applied per branch because the
-    # recon8/pallas engines use the padded table from build_reconstruction
+    # recon8 engines use the padded table from build_reconstruction
     from raft_tpu.core.bitset import make_slot_filter
 
     maybe_filter = make_slot_filter(prefilter, index.id_bound,
@@ -1350,15 +1186,9 @@ def search(
         raise ValueError(f"unknown score_dtype {params.score_dtype!r}")
     idd = params.internal_distance_dtype
     if idd == "auto":
-        # resolve from the measured tuned hint, TPU only (the hint was
-        # measured on chip; CPU tests keep exact f32 trim numerics)
-        idd = "float32"
-        if jax.default_backend() == "tpu":
-            from raft_tpu.core import tuned
-
-            hinted = tuned.hints().get("internal_distance_dtype")
-            if hinted in ("float32", "float16", "bfloat16"):
-                idd = hinted
+        # bf16 trim scores on the chip, where they won the 2026-08-01
+        # ladder; exact f32 trim numerics everywhere else
+        idd = "bfloat16" if jax.default_backend() == "tpu" else "float32"
     if idd not in ("float32", "float16", "bfloat16"):
         raise ValueError(
             f"unknown internal_distance_dtype {params.internal_distance_dtype!r}"
@@ -1374,14 +1204,15 @@ def search(
     # for this geometry (chip-measured, envelope-gated — the single
     # chooser of ISSUE 11)
     trim = params.trim_engine
-    if trim not in ("auto", "approx", "exact", "pallas", "fused"):
+    if trim not in ("auto", "approx", "exact", "fused"):
         raise ValueError(f"unknown trim_engine {params.trim_engine!r}")
     if trim == "auto":
         trim = "approx"
         if mode == "recon8_list" and params.score_dtype == "int8":
             from raft_tpu.matrix.select_k import resolve_int8_trim_strategy
-            from raft_tpu.ops.fused_scan import FUSED_MAX_K, fused_kbuf
-            from raft_tpu.ops.pq_list_scan import lane_padded
+            from raft_tpu.ops.fused_scan import (
+                FUSED_MAX_K, fused_kbuf, lane_padded,
+            )
 
             if 0 < int(k) <= FUSED_MAX_K:
                 kb_probe = max(fused_kbuf(int(k)), index.fused_kb or 0)
@@ -1416,7 +1247,7 @@ def search(
     if obs.enabled():
         # list-major modes stream every padded list per query batch;
         # query-major modes touch the probed lists only (the ACTUAL
-        # adaptive mean when budgets are on); the fused/pallas trims
+        # adaptive mean when budgets are on); the fused trim
         # never materialize the score tile
         obs.span_cost(**obs.perf.cost_for(
             "neighbors.ivf_pq.search", nq=int(q.shape[0]),
@@ -1429,9 +1260,8 @@ def search(
                            if (mode.endswith("_list") and trim != "fused")
                            else (scanned_mean if scanned_mean is not None
                                  else n_probes)),
-            fused=(mode == "recon8_list"
-                   and trim in ("pallas", "fused"))))
-    for eng in ("pallas", "exact", "fused"):
+            fused=mode == "recon8_list" and trim == "fused"))
+    for eng in ("exact", "fused"):
         if trim == eng and mode != "recon8_list":
             raise ValueError(
                 f"trim_engine='{eng}' requires score_mode 'recon8_list'"
@@ -1439,7 +1269,7 @@ def search(
     if mode == "recon8_list" and trim == "fused":
         from raft_tpu.matrix.select_k import check_fused_list_request
         from raft_tpu.neighbors.probe_invert import macro_batched
-        from raft_tpu.ops.pq_list_scan import lane_padded
+        from raft_tpu.ops.fused_scan import lane_padded
 
         # caps/envelope checked BEFORE padding the index's store (a
         # rejected request must not leave the index mutated), at the
@@ -1454,9 +1284,7 @@ def search(
         index.fused_kb = kb  # monotonic: kb >= the recorded width
         srows_pad = maybe_filter(index.slot_rows_pad)
         from raft_tpu.core import faults
-        from raft_tpu.neighbors.probe_invert import resolve_setup_impls
 
-        setup = resolve_setup_impls(index.n_lists)
         vals, rows = macro_batched(
             lambda sl, pv=None: _search_impl_recon8_listmajor_fused(
                 sl,
@@ -1472,7 +1300,6 @@ def search(
                 interpret=jax.default_backend() == "cpu",
                 int8_queries=params.score_dtype == "int8",
                 kb=kb,
-                setup_impls=setup,
                 fault_key=faults.trace_key(),
                 pvalid=pv,
             ),
@@ -1480,78 +1307,11 @@ def search(
             int(k),
             extra=pvalid,
         )
-    elif mode == "recon8_list" and trim == "pallas":
-        from raft_tpu.neighbors.probe_invert import macro_batched
-        from raft_tpu.ops.pq_list_scan import _BINS, fits_pallas, lane_padded
-
-        if int(k) > _BINS:
-            raise ValueError(
-                f"trim_engine='pallas' caps per-list candidates at {_BINS}; k={k}"
-            )
-        # check the VMEM envelope BEFORE padding the index's store: a
-        # rejected request must not leave the index mutated
-        lpad = lane_padded(int(index.codes.shape[1]))
-        if not fits_pallas(128, lpad, index.rot_dim):
-            raise ValueError(
-                f"trim_engine='pallas': list length {lpad} exceeds the kernel's "
-                "VMEM envelope; use the default trim_engine='approx'"
-            )
-        build_reconstruction(index, pad_to_lanes=True)
-        srows_pad = maybe_filter(index.slot_rows_pad)
-        from raft_tpu.ops.pq_list_scan import fold_variant
-        from raft_tpu.neighbors.probe_invert import resolve_setup_impls
-
-        fold = fold_variant()
-        setup = resolve_setup_impls(index.n_lists)
-        vals, rows = macro_batched(
-            lambda sl, pv=None: _search_impl_recon8_listmajor_pallas(
-                sl,
-                index.rotation,
-                index.centers,
-                index.recon8,
-                index.recon_scale,
-                index.recon_norm,
-                srows_pad,
-                int(k),
-                n_probes,
-                index.metric,
-                interpret=jax.default_backend() == "cpu",
-                int8_queries=params.score_dtype == "int8",
-                fold=fold,
-                setup_impls=setup,
-                pvalid=pv,
-            ),
-            jnp.asarray(q),
-            int(k),
-            extra=pvalid,
-        )
     elif mode == "recon8_list":
-        from raft_tpu.core import tuned
         from raft_tpu.neighbors.probe_invert import macro_batched
 
         build_reconstruction(index)
         srows_pad = maybe_filter(index.slot_rows_pad)
-        # chunk rows per virtual list: the measured tuned key when valid,
-        # applied ONLY at low-duplication shapes (where the race that
-        # produced it ran: the P//chunk + n_lists fragmentation bound
-        # leaves 128-row chunks mostly empty). High-dup batches keep the
-        # 128 default the np32 engine ladder was measured under — a key
-        # tuned at np8 must not regress the np32 path.
-        chunk = 128
-        dup = q.shape[0] * n_probes / max(1, index.n_lists)
-        if dup <= _LOW_DUP_CHUNK_BOUND:
-            t_chunk = tuned.get("listmajor_chunk", 128)
-            if t_chunk in (32, 64, 128):
-                chunk = int(t_chunk)
-        # scoring granularity: 0 = one einsum per superblock (~nsuper
-        # scan iterations/batch); a positive tuned value restores the
-        # round-1..4 inner lax.map structure (see probe_invert)
-        from raft_tpu.neighbors.probe_invert import CHUNK_BLOCKS
-
-        cb = int(tuned.get_choice("listmajor_chunk_block", CHUNK_BLOCKS, 0))
-        from raft_tpu.neighbors.probe_invert import resolve_setup_impls
-
-        setup = resolve_setup_impls(index.n_lists)
         vals, rows = macro_batched(
             lambda sl, pv=None: _search_impl_recon8_listmajor(
                 sl,
@@ -1564,11 +1324,8 @@ def search(
                 int(k),
                 n_probes,
                 index.metric,
-                chunk=chunk,
-                chunk_block=cb,
                 int8_queries=params.score_dtype == "int8",
                 trim_bf16=idd in ("bfloat16", "float16"),
-                setup_impls=setup,
                 pvalid=pv,
             ),
             jnp.asarray(q),

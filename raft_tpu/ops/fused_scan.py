@@ -17,10 +17,11 @@ Four geometries share one epilogue:
                       analogue of the paper's per-core partial top-k
                       state. Only (m, kbuf) values+ids reach HBM.
   `fused_list_topk` — list scan: grid (ncb,) with scalar-prefetched
-                      chunk->list ids indexing the store directly (the
-                      `pq_list_scan` addressing scheme); per step the
+                      chunk->list ids indexing the store directly (no
+                      gather copy of the scanned lists); per step the
                       (chunk, L) scores fold to an exact (chunk, kbuf)
-                      top-k in-kernel. Backs the IVF-Flat/IVF-PQ fused
+                      top-k in-kernel. Stores follow the lane contract
+                      of `lane_padded`. Backs the IVF-Flat/IVF-PQ fused
                       trims and the per-query fused rerank (chunk=1,
                       one "list" of gathered candidates per query).
   `fused_list_topk_int8`
@@ -31,19 +32,19 @@ Four geometries share one epilogue:
                       TFLOP/s), per-row dequant scale applied on the
                       VPU, then the same exact epilogue — only the
                       (chunk, kbuf) survivors are ever dequantized to
-                      f32 in HBM. Scoring numerics are IDENTICAL to
-                      `pq_list_scan`'s q_int8 path (same quantization,
-                      same op order), which is what the bit-agreement
-                      tests pin.
+                      f32 in HBM. Scores are `base - coef * dots`, dots
+                      the int32 dot times the per-row scale, over the
+                      same quantized rows as the XLA list-major
+                      engine's int8 path.
 
-The epilogue is an EXACT partial selection, unlike `pq_list_scan`'s
-lane-bin trim: `k` extraction passes over the merged candidate window
-(the running kbuf buffer + the fresh tile — the "2k candidates" the
-merge sorts), each pass taking the lexicographic (score, id) minimum so
-ties break deterministically to the smaller id — the same stable-tie
-order `lax.top_k` produces, which is what makes the fused path
-bit-agree with the two-phase reference select-k. Exhausted slots carry
-(+inf, _ID_SENTINEL); callers map non-finite winners to id -1.
+The epilogue is an EXACT partial selection: `k` extraction passes over
+the merged candidate window (the running kbuf buffer + the fresh tile —
+the "2k candidates" the merge sorts), each pass taking the
+lexicographic (score, id) minimum so ties break deterministically to the
+smaller id — the same stable-tie order `lax.top_k` produces, which is
+what makes the fused path bit-agree with the two-phase reference
+select-k. Exhausted slots carry (+inf, _ID_SENTINEL); callers map
+non-finite winners to id -1.
 
 Scores are canonical-minimizing: `base - 2<q,v>` for L2 (the per-query
 |q|^2 constant cannot change any ranking, so it is added OUTSIDE the
@@ -96,6 +97,13 @@ KERNEL_ENVELOPES = {
     "fused_list_topk": ("fits_fused_list", {}),
     "fused_list_topk_int8": ("fits_fused_list", {"store_itemsize": 1}),
 }
+
+
+def lane_padded(width: int) -> int:
+    """The slot-axis width the list scans' lane contract requires: a
+    multiple of the 128-lane register width, at least 256 slots. Shared
+    by every caller that pads a store for them."""
+    return max(2 * _LANES, -(-width // _LANES) * _LANES)
 
 
 def fused_kbuf(k: int) -> int:
@@ -332,11 +340,11 @@ def _make_list_kernel(kbuf: int, k: int, inner_product: bool,
 def fits_fused_list(chunk: int, L: int, rot: int, k: int,
                     store_itemsize: int = 2,
                     kbuf: Optional[int] = None) -> bool:
-    """VMEM envelope for one list-scan grid step (mirrors
-    `pq_list_scan.fits_pallas`, plus the extraction window, the base
-    row, and the int8 kernel's dequant-scale column — the mirroring is
-    machine-checked against the kernels' actual block bytes by
-    raftlint's `kernel-vmem-envelope` via KERNEL_ENVELOPES). `kbuf`:
+    """VMEM envelope for one list-scan grid step: the score tile, the
+    slot-id plane, the scanned list block, the query rows, the output
+    buffers, the base row, and the int8 kernel's dequant-scale column
+    — machine-checked against the kernels' actual block bytes by
+    raftlint's `kernel-vmem-envelope` via KERNEL_ENVELOPES. `kbuf`:
     the buffer width the kernel will ACTUALLY run with — callers that
     cache a monotonically-grown width (ivf_flat's `fused_kb`) must pass
     it, or a small-k search on a grown store is gated against a
@@ -460,7 +468,7 @@ def _make_list_kernel_int8(kbuf: int, k: int, inner_product: bool,
         def compute():
             # int8 x int8 -> int32 at the MXU's doubled int8 rate; the
             # per-row dequant scale is the ONLY float multiply before the
-            # epilogue — numerics match pq_list_scan's q_int8 path exactly
+            # epilogue
             idots = lax.dot_general(
                 q8_ref[0], store_ref[0],
                 dimension_numbers=(((1,), (1,)), ((), ())),

@@ -258,15 +258,15 @@ def main():
                  for a, b in zip(zf_, zl_))
     check(f"mp_flat_pallas_engine ({hits_f / zl_.size:.2f})",
           hits_f / zl_.size >= 0.85)
-    _, ids_pallas_trim = mnmg.ivf_pq_search(
+    _, ids_fused_trim = mnmg.ivf_pq_search(
         dpq, fdata[:16], 5, n_probes=16,
-        engine="recon8_list", trim_engine="pallas")
+        engine="recon8_list", trim_engine="fused")
     _, ids_approx_trim = mnmg.ivf_pq_search(dpq, fdata[:16], 5, n_probes=16,
                                             engine="recon8_list")
-    pal_, apx_ = fetch(ids_pallas_trim)[:16], fetch(ids_approx_trim)[:16]
+    fus_, apx_ = fetch(ids_fused_trim)[:16], fetch(ids_approx_trim)[:16]
     hits_t = sum(len(set(a.tolist()) & set(b.tolist()))
-                 for a, b in zip(pal_, apx_))
-    check(f"mp_pq_pallas_trim ({hits_t / apx_.size:.2f})",
+                 for a, b in zip(fus_, apx_))
+    check(f"mp_pq_fused_trim ({hits_t / apx_.size:.2f})",
           hits_t / apx_.size >= 0.8)
     try:
         mnmg.ivf_pq_save("/tmp/should_not_exist.rtpq", dpq)

@@ -363,7 +363,7 @@ def tuned_file(monkeypatch, tmp_path):
 def test_tuned_defaults_absent_is_none(tuned_file):
     from raft_tpu.core import tuned
 
-    assert tuned.get("pq_auto_engine") is None
+    assert tuned.get("flat_auto_engine") is None
     assert tuned.get("anything", "fallback") == "fallback"
 
 
@@ -432,17 +432,20 @@ def test_apply_hints_skips_unregistered_keys(tuned_file, monkeypatch):
     import apply_profile_hints as aph
 
     monkeypatch.setitem(aph._TUNABLE, "bogus_hint", ("bogus_key", str))
+    monkeypatch.setitem(aph._TUNABLE, "merge_schedule",
+                        ("mnmg_replicated_merge_schedule", str))
     aph.apply_hints([
         {"hint": "bogus_hint", "recommend": "x", "detail": "drifted"},
-        {"hint": "listmajor_chunk", "recommend": "256", "detail": "ok"},
+        {"hint": "ivf_flat_engine_default", "recommend": "list",
+         "detail": "ok"},
         # registered choice key, value outside its registered set: the
         # lint rule cannot see computed values, so the belt must
-        {"hint": "pq_auto_engine", "recommend": "fused", "detail": "bad"},
+        {"hint": "merge_schedule", "recommend": "ring", "detail": "bad"},
     ])
     rec = json.load(open(tuned_file))
     assert "bogus_key" not in rec
-    assert "pq_auto_engine" not in rec
-    assert rec["listmajor_chunk"] == 256
+    assert "mnmg_replicated_merge_schedule" not in rec
+    assert rec["flat_auto_engine"] == "list"
 
 
 @pytest.mark.slow
@@ -483,14 +486,15 @@ def test_apply_hints_writes_tuned_file(tuned_file):
     from raft_tpu.core import tuned
 
     hints = [
-        {"hint": "pq_auto_engine", "recommend": "recon8_list", "detail": "x"},
-        {"hint": "trim_engine_default", "recommend": "inspect", "detail": "y"},
+        {"hint": "ivf_flat_engine_default", "recommend": "list",
+         "detail": "x"},
+        {"hint": "bf_engine_default", "recommend": "inspect", "detail": "y"},
     ]
     aph.apply_hints(hints)
     rec = json.load(open(tuned_file))
-    assert rec["pq_auto_engine"] == "recon8_list"
-    assert "trim_engine_default" in rec["hints"]
-    assert tuned.get("pq_auto_engine") == "recon8_list"
+    assert rec["flat_auto_engine"] == "list"
+    assert "bf_engine_default" in rec["hints"]
+    assert tuned.get("flat_auto_engine") == "list"
 
 
 @pytest.mark.slow

@@ -193,12 +193,13 @@ class TestFilteredSearch:
 
     @pytest.mark.parametrize(
         "mode,trim", [
-            # one engine combo smokes the filter invariant in the quick
-            # tier; the full matrix (compile-heavy on 1 core) is slow-tier
+            # the recon8 engines (the list-major one is every batch
+            # cell's) smoke the filter invariant in the quick tier; the
+            # rest of the matrix (compile-heavy on 1 core) is slow-tier
             pytest.param("lut", "approx", marks=pytest.mark.slow),
             ("recon8", "approx"),
-            pytest.param("recon8_list", "approx", marks=pytest.mark.slow),
-            pytest.param("recon8_list", "pallas", marks=pytest.mark.slow),
+            ("recon8_list", "approx"),
+            pytest.param("recon8_list", "fused", marks=pytest.mark.slow),
         ]
     )
     def test_ivf_pq_engines(self, blobs, pq_index, mode, trim):

@@ -2,14 +2,13 @@
 
 The int8 PQ-recon list scan (`fused_list_topk_int8`, dispatch strategy
 "fused_int8") on the TPU integer datapath: int8 x int8 -> int32 on the
-MXU, per-row dequant, exact partial top-k — its scores must be
-BIT-IDENTICAL f32 values to the pallas int8 bin trim's (same
-`_quantize_query_rows` quantization, same op order).
+MXU, per-row dequant, exact partial top-k — it must return the same
+neighbors as the XLA int8 trim (same `_quantize_query_rows`
+quantization; both trims exact), with scores equal to within the f32
+rounding of the one add the two trims order differently.
 
 Everything runs the kernels in interpret mode on CPU (the repo-wide
-Pallas testing convention). List geometries here keep L <= 512 so the
-pallas bin trim is lossless and the int8 comparison is exact end to
-end, not just per-score.
+Pallas testing convention).
 """
 
 import numpy as np
@@ -84,7 +83,7 @@ def test_list_scan_dispatch_validation():
                            q_scale=scale, interpret=True)
 
 
-# -- int8 fused trim vs the pallas bin trim -----------------------------
+# -- int8 fused trim vs the XLA int8 trim -------------------------------
 
 
 @pytest.fixture(scope="module")
@@ -100,15 +99,17 @@ def pq_int8_setup():
 
 @pytest.mark.parametrize("k", [1, 10, 100])
 def test_ivf_pq_int8_fused_bit_agrees_with_pallas_trim(pq_int8_setup, k):
-    """The acceptance pin: fused int8 recon scan bit-agrees with the
-    existing pallas int8 trim — identical f32 distance VALUES (same
-    quantization, same op order) and the same neighbor sets, across
-    the k ladder. L <= 512 here, so the pallas bin trim is lossless and
-    any disagreement is a kernel bug, not trim loss."""
+    """The acceptance pin: the fused int8 recon scan agrees with the XLA
+    int8 trim across the k ladder — the same neighbors in the same order
+    (same quantization, both trims exact), and f32 distance values equal
+    to the last bit but for the one re-associated add: the XLA trim sums
+    (|q'|^2 - 2 q'.r) + |r|^2, the fused kernel (|r|^2 - 2 q'.r) + |q'|^2
+    (measured: 12 of 1,600 values at k=100 one ulp apart, none at
+    k=1, 10)."""
     _, q, idx = pq_int8_setup
-    d_p, i_p = ivf_pq.search(
+    d_x, i_x = ivf_pq.search(
         ivf_pq.SearchParams(n_probes=16, score_mode="recon8_list",
-                            trim_engine="pallas", score_dtype="int8"),
+                            trim_engine="approx", score_dtype="int8"),
         idx, q, k,
     )
     d_f, i_f = ivf_pq.search(
@@ -116,10 +117,10 @@ def test_ivf_pq_int8_fused_bit_agrees_with_pallas_trim(pq_int8_setup, k):
                             score_dtype="int8"),
         idx, q, k,
     )
-    np.testing.assert_array_equal(np.asarray(d_p), np.asarray(d_f))
-    i_p, i_f = np.asarray(i_p), np.asarray(i_f)
-    for r in range(len(q)):
-        assert set(i_p[r]) == set(i_f[r])
+    np.testing.assert_array_max_ulp(np.asarray(d_x), np.asarray(d_f),
+                                    maxulp=1)
+    i_f = np.asarray(i_f)
+    np.testing.assert_array_equal(np.asarray(i_x), i_f)
     # determinism: bit-identical across calls
     d_f2, i_f2 = ivf_pq.search(
         ivf_pq.SearchParams(n_probes=16, trim_engine="fused",
@@ -133,7 +134,7 @@ def test_ivf_pq_int8_fused_bit_agrees_with_pallas_trim(pq_int8_setup, k):
 def test_ivf_pq_int8_fused_prefilter_excludes(pq_int8_setup, rng):
     """valid-mask/tombstone exclusion: a prefilter must be invisible to
     the int8 fused trim's selection — no filtered id ever returns, and
-    the surviving results match the pallas trim's."""
+    the surviving results match the XLA int8 trim's."""
     _, q, idx = pq_int8_setup
     keep = rng.random(4000) < 0.5
     d_f, i_f = ivf_pq.search(
@@ -143,7 +144,7 @@ def test_ivf_pq_int8_fused_prefilter_excludes(pq_int8_setup, rng):
     )
     d_p, i_p = ivf_pq.search(
         ivf_pq.SearchParams(n_probes=16, score_mode="recon8_list",
-                            trim_engine="pallas", score_dtype="int8"),
+                            trim_engine="approx", score_dtype="int8"),
         idx, q, 10, prefilter=keep,
     )
     i_f = np.asarray(i_f)

@@ -140,70 +140,6 @@ def test_list_major_engine(dataset):
         ivf_flat.search(ivf_flat.SearchParams(engine="nope"), index, queries, 5)
 
 
-def test_listmajor_setup_impl_equivalence_flat(dataset, monkeypatch):
-    """Flat-engine mirror of the PQ setup-impl equivalence gate (ADVICE
-    r5): invert_impl=count and qs_impl=onehot_f32h are bit-preserving on
-    the IVF-Flat list-major engine; a SHARED tuned onehot_bf16 winner is
-    gated back to gather for flat (this engine scores at f32
-    Precision.HIGHEST — bf16-rounded query rows would silently degrade
-    it); the flat-specific key `listmajor_qs_impl_flat` opts bf16 in
-    explicitly (overlap gate, near-ties only)."""
-    from raft_tpu.core import tuned
-    from raft_tpu.neighbors.probe_invert import resolve_qs_impl
-
-    data, queries = dataset
-    index = ivf_flat.build(
-        ivf_flat.IndexParams(n_lists=16, kmeans_n_iters=8), data[:5000])
-    p = ivf_flat.SearchParams(n_probes=8, engine="list")
-    d_ref, i_ref = ivf_flat.search(p, index, queries, 10)
-    i_ref = np.asarray(i_ref)
-
-    real = tuned.get_choice
-
-    def force(**keys):
-        def fake(key, allowed, default):
-            return keys[key] if key in keys else real(key, allowed, default)
-
-        monkeypatch.setattr(tuned, "get_choice", fake)
-        out = ivf_flat.search(p, index, queries, 10)
-        monkeypatch.setattr(tuned, "get_choice", real)
-        return out
-
-    # counting inversion + f32-highest one-hot: bit-preserving
-    d_c, i_c = force(invert_impl="count", listmajor_qs_impl="onehot_f32h")
-    assert np.array_equal(np.asarray(i_c), i_ref)
-    np.testing.assert_allclose(np.asarray(d_c), np.asarray(d_ref), rtol=1e-6)
-
-    # the SHARED bf16 winner resolves to gather on flat -> bit-equal
-    def fake_shared_bf16(key, allowed, default):
-        if key == "listmajor_qs_impl":
-            return "onehot_bf16"
-        return real(key, allowed, default)
-
-    monkeypatch.setattr(tuned, "get_choice", fake_shared_bf16)
-    assert resolve_qs_impl("flat") == "gather"
-    assert resolve_qs_impl("pq") == "onehot_bf16"
-    _, i_g = ivf_flat.search(p, index, queries, 10)
-    monkeypatch.setattr(tuned, "get_choice", real)
-    assert np.array_equal(np.asarray(i_g), i_ref)
-
-    # the flat-specific key opts bf16 in explicitly: near-ties only
-    def fake_flat_bf16(key, allowed, default):
-        if key == "listmajor_qs_impl_flat":
-            return "onehot_bf16"
-        return real(key, allowed, default)
-
-    monkeypatch.setattr(tuned, "get_choice", fake_flat_bf16)
-    assert resolve_qs_impl("flat") == "onehot_bf16"
-    _, i_b = ivf_flat.search(p, index, queries, 10)
-    monkeypatch.setattr(tuned, "get_choice", real)
-    i_b = np.asarray(i_b)
-    overlap = np.mean(
-        [len(set(i_b[r]) & set(i_ref[r])) / 10 for r in range(len(i_ref))]
-    )
-    assert overlap >= 0.95, f"bf16 one-hot moved results: overlap {overlap}"
-
-
 def test_pallas_fused_engine(dataset):
     """The fused Pallas list-scan engine (interpret mode on CPU) must agree
     with the exact query-major engine, pad the store monotonically, and
@@ -318,39 +254,6 @@ def test_pallas_fused_kb_grows_with_k(dataset):
     # a smaller k afterwards keeps the wider compiled width (monotone)
     ivf_flat.search(p, index, queries, 5)
     assert index.fused_kb == 256
-
-
-def test_pallas_packed_fold_engine(monkeypatch):
-    """pallas_fold="packed" routes the IVF-PQ pallas trim through the
-    bf16-coarse fold (fold_variant() wiring): results must track the
-    exact-fold engine at trim-noise level. (The IVF-Flat fused engine
-    no longer consults the fold knob — its in-kernel select is exact by
-    construction, tests/test_fused_scan.py.)"""
-    from raft_tpu.core import tuned
-    from raft_tpu.neighbors import ivf_pq
-
-    rng = np.random.default_rng(5)
-    data = rng.random((6000, 32), dtype=np.float32)
-    queries = data[:40]
-    index = ivf_pq.build(
-        ivf_pq.IndexParams(n_lists=16, kmeans_n_iters=4, pq_dim=16), data
-    )
-    p = ivf_pq.SearchParams(n_probes=16, trim_engine="pallas")
-    # pin the baseline: a committed pallas_fold="packed" tuned key must
-    # not silently turn this into packed-vs-packed
-    monkeypatch.setitem(tuned._load(), "pallas_fold", "exact")
-    i_exact = np.asarray(ivf_pq.search(p, index, queries, 10)[1])
-    monkeypatch.setitem(tuned._load(), "pallas_fold", "packed")
-    try:
-        d_p, i_p = ivf_pq.search(p, index, queries, 10)
-    finally:
-        tuned.reload()
-    i_p = np.asarray(i_p)
-    overlap = np.mean(
-        [len(set(i_exact[r]) & set(i_p[r])) / 10 for r in range(len(i_exact))]
-    )
-    assert overlap >= 0.9, f"packed fold diverged: overlap {overlap}"
-    assert np.all(np.diff(np.asarray(d_p), axis=1) >= -1e-4)
 
 
 def test_build_trainset_subsample_unbiased_on_sorted_data():

@@ -107,241 +107,3 @@ def test_fused_l2_argmin_exact_duplicate(rng):
     dist, idx = fused_l2_argmin_pallas(x, y, bm=16, bn=128, interpret=True)
     np.testing.assert_array_equal(np.asarray(idx), [3, 42, 99])
     assert np.asarray(dist).max() < 1e-5
-
-
-def test_pq_list_scan_bins_match_oracle(rng):
-    """Fused list-scan kernel (interpret mode) vs a bf16-faithful numpy
-    oracle: every (chunk, bin) running-best value and index must equal the
-    per-bin minimum over that bin's lane-column class."""
-    import ml_dtypes
-    import jax.numpy as jnp
-
-    from raft_tpu.ops.pq_list_scan import pq_list_scan, _BINS
-
-    n_lists, L, rot, ncb, chunk = 5, 384, 32, 8, 16
-    r8 = rng.integers(-127, 128, (n_lists, L, rot)).astype(np.int8)
-    rn = (rng.random((n_lists, 1, L)) * 10).astype(np.float32)
-    invalid = rng.random((n_lists, 1, L)) < 0.3
-    base = np.where(invalid, np.inf, rn).astype(np.float32)
-    lof = rng.integers(0, n_lists, (ncb,)).astype(np.int32)
-    qres = rng.normal(size=(ncb, chunk, rot)).astype(np.float32)
-
-    vals, idx = pq_list_scan(
-        jnp.asarray(lof), jnp.asarray(qres), jnp.asarray(r8), jnp.asarray(base),
-        interpret=True,
-    )
-    vals, idx = np.asarray(vals), np.asarray(idx)
-
-    assert vals.shape[-1] == 2 * _BINS  # best + second-best per bin
-    bins = (np.arange(L) % 128) + 128 * ((np.arange(L) // 128) % 2)
-    for b in range(ncb):
-        qb = qres[b].astype(ml_dtypes.bfloat16).astype(np.float32)
-        rb = r8[lof[b]].astype(ml_dtypes.bfloat16).astype(np.float32)
-        scores = base[lof[b]][0][None, :] - 2.0 * (qb @ rb.T)
-        for bin_ in range(0, _BINS, 17):  # stride keeps runtime modest
-            cols = np.nonzero(bins == bin_)[0]
-            srt = np.sort(scores[:, cols], axis=1)
-            for rank_, off in ((0, 0), (1, _BINS)):  # best, second-best
-                want = srt[:, rank_] if srt.shape[1] > rank_ else np.full(
-                    (chunk,), np.inf, np.float32
-                )
-                got = vals[b, :, bin_ + off]
-                finite = np.isfinite(want)
-                np.testing.assert_allclose(
-                    got[finite], want[finite], rtol=1e-5, atol=1e-3
-                )
-                assert not np.isfinite(got[~finite]).any()
-                # idx only meaningful where the slot held a finite candidate
-                assert (bins[idx[b, finite, bin_ + off]] == bin_).all()
-
-
-def test_pq_list_scan_packed_fold_matches_oracle(rng):
-    """fold="packed" (interpret mode) vs a numpy oracle that applies the
-    SAME int32 packing (bf16-coarse score image | fold id): per (lane,
-    bank) the kernel must return exactly the two packed-smallest
-    candidates, values equal to the coarse band bound, indices exact."""
-    import jax.numpy as jnp
-
-    from raft_tpu.ops.pq_list_scan import pq_list_scan, _BINS, _LANES
-
-    n_lists, L, rot, ncb, chunk = 5, 384, 32, 8, 16
-    r8 = rng.integers(-127, 128, (n_lists, L, rot)).astype(np.int8)
-    rn = (rng.random((n_lists, 1, L)) * 10).astype(np.float32)
-    invalid = rng.random((n_lists, 1, L)) < 0.3
-    base = np.where(invalid, np.inf, rn).astype(np.float32)
-    lof = rng.integers(0, n_lists, (ncb,)).astype(np.int32)
-    qres = rng.normal(size=(ncb, chunk, rot)).astype(np.float32)
-
-    vals, idx = pq_list_scan(
-        jnp.asarray(lof), jnp.asarray(qres), jnp.asarray(r8), jnp.asarray(base),
-        interpret=True, fold="packed",
-    )
-    vals, idx = np.asarray(vals), np.asarray(idx)
-
-    def pack_np(scores, folds):
-        i = scores.view(np.int32)
-        u = np.where(i < 0, ~i, i | np.int32(-2147483648))
-        return ((u & np.int32(-65536)) | folds) ^ np.int32(-2147483648)
-
-    import ml_dtypes
-
-    n_folds = L // _LANES
-    for b in range(ncb):
-        qb = qres[b].astype(ml_dtypes.bfloat16).astype(np.float32)
-        rb = r8[lof[b]].astype(ml_dtypes.bfloat16).astype(np.float32)
-        scores = (base[lof[b]][0][None, :] - 2.0 * (qb @ rb.T)).astype(np.float32)
-        folds = (np.arange(L, dtype=np.int32) // _LANES)[None, :]
-        packed = pack_np(scores, np.broadcast_to(folds, scores.shape))
-        for lane in range(0, _LANES, 13):
-            for bank, off in ((0, 0), (1, _LANES)):
-                cols = [
-                    c * _LANES + lane
-                    for c in range(bank, n_folds, 2)
-                ]
-                srt = np.sort(packed[:, cols], axis=1)
-                for rank_, roff in ((0, 0), (1, _BINS)):
-                    slot = lane + off + roff
-                    got_v, got_i = vals[b, :, slot], idx[b, :, slot]
-                    if srt.shape[1] > rank_:
-                        want_p = srt[:, rank_]
-                    else:
-                        want_p = np.full((chunk,), np.int32(2147483647))
-                    # decode expected value/index from the packed oracle
-                    p = want_p ^ np.int32(-2147483648)
-                    want_fold = p & np.int32(0xFFFF)
-                    u = p & np.int32(-65536)
-                    i32 = np.where(u < 0, u & np.int32(2147483647), ~u)
-                    want_v = i32.view(np.float32)
-                    sentinel = want_fold >= n_folds
-                    np.testing.assert_array_equal(
-                        got_v[~sentinel], want_v[~sentinel]
-                    )
-                    assert not np.isfinite(got_v[sentinel]).any()
-                    np.testing.assert_array_equal(
-                        got_i[~sentinel],
-                        want_fold[~sentinel] * _LANES + lane,
-                    )
-
-
-def test_pq_list_scan_int8_queries_match_oracle(rng):
-    """The q_scale (int8 x int8) kernel branch against an exact integer
-    oracle: int32 dots * per-row scale, then the same bin reduction."""
-    import jax.numpy as jnp
-
-    from raft_tpu.ops.pq_list_scan import pq_list_scan, _BINS
-
-    n_lists, L, rot, ncb, chunk = 4, 384, 16, 6, 8
-    r8 = rng.integers(-127, 128, (n_lists, L, rot)).astype(np.int8)
-    rn = (rng.random((n_lists, 1, L)) * 10).astype(np.float32)
-    invalid = rng.random((n_lists, 1, L)) < 0.25
-    base = np.where(invalid, np.inf, rn).astype(np.float32)
-    lof = rng.integers(0, n_lists, (ncb,)).astype(np.int32)
-    q8 = rng.integers(-127, 128, (ncb, chunk, rot)).astype(np.int8)
-    rs = (rng.random((ncb, chunk, 1)) * 0.01 + 0.001).astype(np.float32)
-
-    vals, idx = pq_list_scan(
-        jnp.asarray(lof), jnp.asarray(q8), jnp.asarray(r8), jnp.asarray(base),
-        interpret=True, q_scale=jnp.asarray(rs),
-    )
-    vals, idx = np.asarray(vals), np.asarray(idx)
-    assert vals.shape[-1] == 2 * _BINS
-
-    bins = (np.arange(L) % 128) + 128 * ((np.arange(L) // 128) % 2)
-    for b in range(ncb):
-        dots = q8[b].astype(np.int64) @ r8[lof[b]].astype(np.int64).T  # exact
-        scores = base[lof[b]][0][None, :] - 2.0 * dots.astype(np.float32) * rs[b]
-        for bin_ in range(0, _BINS, 37):
-            cols = np.nonzero(bins == bin_)[0]
-            srt = np.sort(scores[:, cols], axis=1)
-            for rank_, off in ((0, 0), (1, _BINS)):
-                want = srt[:, rank_] if srt.shape[1] > rank_ else np.full(
-                    (chunk,), np.inf, np.float32
-                )
-                got = vals[b, :, bin_ + off]
-                finite = np.isfinite(want)
-                np.testing.assert_allclose(got[finite], want[finite],
-                                           rtol=1e-5, atol=1e-4)
-                assert not np.isfinite(got[~finite]).any()
-                assert (bins[idx[b, finite, bin_ + off]] == bin_).all()
-
-    # dtype validation: q_scale demands int8 operands
-    import pytest
-
-    with pytest.raises(ValueError, match="int8"):
-        pq_list_scan(
-            jnp.asarray(lof), jnp.asarray(q8, jnp.float32), jnp.asarray(r8),
-            jnp.asarray(base), interpret=True, q_scale=jnp.asarray(rs),
-        )
-
-
-def test_pq_list_scan_rot_pad_bit_identical(rng, monkeypatch):
-    """RAFT_TPU_PALLAS_ROT_PAD: the lane-padded contracting dim (the
-    one-flag fallback if the first Mosaic compile rejects rot % 128 != 0)
-    must be BIT-identical to the unpadded kernel — zero lanes contribute
-    zero to every dot — on both the bf16 and the int8-MXU paths."""
-    import jax
-    import jax.numpy as jnp
-
-    from raft_tpu.ops.pq_list_scan import pq_list_scan
-
-    n_lists, L, rot, ncb, chunk = 4, 256, 96, 6, 8  # rot = bench geometry
-    r8 = rng.integers(-127, 128, (n_lists, L, rot)).astype(np.int8)
-    base = (rng.random((n_lists, 1, L)) * 10).astype(np.float32)
-    lof = rng.integers(0, n_lists, (ncb,)).astype(np.int32)
-    qres = rng.normal(size=(ncb, chunk, rot)).astype(np.float32)
-    q8 = rng.integers(-127, 128, (ncb, chunk, rot)).astype(np.int8)
-    qs = (rng.random((ncb, chunk, 1)) + 0.5).astype(np.float32)
-
-    args = (jnp.asarray(lof), jnp.asarray(qres), jnp.asarray(r8),
-            jnp.asarray(base))
-    v0, i0 = pq_list_scan(*args, interpret=True)
-    vi0, ii0 = pq_list_scan(jnp.asarray(lof), jnp.asarray(q8),
-                            jnp.asarray(r8), jnp.asarray(base),
-                            interpret=True, q_scale=jnp.asarray(qs))
-
-    monkeypatch.setenv("RAFT_TPU_PALLAS_ROT_PAD", "1")
-    jax.clear_caches()  # the flag is read at trace time
-    try:
-        v1, i1 = pq_list_scan(*args, interpret=True)
-        vi1, ii1 = pq_list_scan(jnp.asarray(lof), jnp.asarray(q8),
-                                jnp.asarray(r8), jnp.asarray(base),
-                                interpret=True, q_scale=jnp.asarray(qs))
-    finally:
-        monkeypatch.delenv("RAFT_TPU_PALLAS_ROT_PAD")
-        jax.clear_caches()
-    np.testing.assert_array_equal(np.asarray(v1), np.asarray(v0))
-    np.testing.assert_array_equal(np.asarray(i1), np.asarray(i0))
-    np.testing.assert_array_equal(np.asarray(vi1), np.asarray(vi0))
-    np.testing.assert_array_equal(np.asarray(ii1), np.asarray(ii0))
-
-
-def test_rot_pad_flag_semantics(monkeypatch, tmp_path):
-    """Env wins in both directions over the tuned key; fits_pallas sizes
-    the envelope against the rot the kernel will actually run with."""
-    import json
-    from raft_tpu.ops import pq_list_scan as mod
-    from raft_tpu.core import tuned
-
-    p = str(tmp_path / "tuned_defaults.json")
-    with open(p, "w") as f:
-        json.dump({"pallas_rot_pad": True}, f)
-    monkeypatch.setattr(tuned, "_PATH", p)
-    tuned.reload()
-    try:
-        assert mod.rot_pad_enabled() is True          # tuned on
-        monkeypatch.setenv("RAFT_TPU_PALLAS_ROT_PAD", "0")
-        assert mod.rot_pad_enabled() is False         # env force-off wins
-        monkeypatch.setenv("RAFT_TPU_PALLAS_ROT_PAD", "True")
-        assert mod.rot_pad_enabled() is True          # case-insensitive
-        # envelope accounts for the padded rot: pick L so rot=96 fits but
-        # rot->128 does not (store_itemsize=2, chunk=128)
-        chunk, si = 128, 2
-        L = 40960
-        assert mod.fits_pallas(chunk, L, 96, si) == mod.fits_pallas(
-            chunk, L, 128, si), "padded-rot envelope must match rot=128"
-        monkeypatch.setenv("RAFT_TPU_PALLAS_ROT_PAD", "0")
-        bytes96 = 4 * chunk * L + si * L * 96 + 4 * chunk * 96 + 8 * chunk * mod._CANDS
-        if bytes96 <= 10 * 1024 * 1024:
-            assert mod.fits_pallas(chunk, L, 96, si)  # unpadded fits
-    finally:
-        tuned.reload()

@@ -15,15 +15,12 @@ from raft_tpu.matrix.select_k import _select_k_impl
 from raft_tpu.neighbors.probe_invert import (
     chunk_count,
     invert_probes,
-    invert_probes_count,
-    invert_probes_sort,
     macro_batch_rows,
     macro_batched,
     regroup_merge,
 )
 
 
-@pytest.mark.parametrize("impl", [invert_probes_sort, invert_probes_count])
 @pytest.mark.parametrize(
     "nq,n_probes,n_lists,chunk,skew",
     [
@@ -33,14 +30,14 @@ from raft_tpu.neighbors.probe_invert import (
         (16, 3, 4, 64, False),   # chunk larger than any bucket
     ],
 )
-def test_invert_probes_invariants(nq, n_probes, n_lists, chunk, skew, impl, rng):
+def test_invert_probes_invariants(nq, n_probes, n_lists, chunk, skew, rng):
     if skew:
         # zipf-ish skew: low-id lists drawn far more often
         raw = rng.zipf(1.5, size=(nq, n_probes)) % n_lists
     else:
         raw = rng.integers(0, n_lists, size=(nq, n_probes))
     probes = jnp.asarray(raw.astype(np.int32))
-    t = impl(probes, n_lists, chunk)
+    t = invert_probes(probes, n_lists, chunk)
     assert t.pair_valid is None  # fixed path: every pair live
     lof, qid_tbl, g0, s0 = (np.asarray(t.lof), np.asarray(t.qid_tbl),
                             np.asarray(t.g0), np.asarray(t.s0))
@@ -72,42 +69,16 @@ def test_invert_probes_invariants(nq, n_probes, n_lists, chunk, skew, impl, rng)
         got = int((qid_tbl[lof == l] < nq).sum())
         assert got == want, f"list {l}: {got} != {want}"
 
-@pytest.mark.parametrize(
-    "nq,n_probes,n_lists,chunk",
-    [
-        (64, 8, 16, 16),
-        (33, 7, 64, 8),
-        (100, 5, 300, 32),  # block smaller than 8192, wide list table
-        (16, 1, 4, 64),     # n_probes=1
-    ],
-)
-def test_invert_impls_bit_identical(nq, n_probes, n_lists, chunk, rng):
-    """The counting construction must reproduce the sort-based tables
-    BIT-IDENTICALLY (stable in-bucket order), so the `invert_impl` tuned
-    key can flip between them without any behavioral difference."""
-    raw = rng.integers(0, n_lists, size=(nq, n_probes)).astype(np.int32)
-    # skew one list hot to force multi-chunk splits
-    raw[: nq // 2, 0] = 0
-    a = invert_probes_sort(jnp.asarray(raw), n_lists, chunk)
-    b = invert_probes_count(jnp.asarray(raw), n_lists, chunk)
-    for x, y in zip(tuple(a), tuple(b)):
-        if x is None or y is None:
-            assert x is None and y is None
-            continue
-        assert np.array_equal(np.asarray(x), np.asarray(y))
-
-
-@pytest.mark.parametrize("impl", [invert_probes_sort, invert_probes_count])
-def test_invert_probes_masked_pairs(impl, rng):
+def test_invert_probes_masked_pairs(rng):
     """Adaptive probe budgets: masked pairs occupy NO chunk slot (the
-    populated-chunk count shrinks), live pairs keep exactly the
+    populated-chunk count shrinks), and live pairs keep exactly the
     addresses/invariants of the unmasked construction restricted to
-    them, and the two constructions stay bit-identical under a mask."""
+    them."""
     nq, n_probes, n_lists, chunk = 48, 8, 16, 16
     raw = rng.integers(0, n_lists, size=(nq, n_probes)).astype(np.int32)
     pv = rng.random((nq, n_probes)) < 0.5
     pv[:, 0] = True  # budget floor: first probe always live
-    t = impl(jnp.asarray(raw), n_lists, chunk, jnp.asarray(pv))
+    t = invert_probes(jnp.asarray(raw), n_lists, chunk, jnp.asarray(pv))
     lof, qid_tbl, g0, s0, pvalid = map(np.asarray, t)
     flat = raw.reshape(-1)
     qidx = np.arange(nq * n_probes) // n_probes
@@ -122,28 +93,6 @@ def test_invert_probes_masked_pairs(impl, rng):
     assert np.all(g0[~live] == 0) and np.all(s0[~live] == 0)
     # populated entries == live pair count (masked pairs dropped)
     assert int((qid_tbl < nq).sum()) == int(live.sum())
-    # masked construction is bit-identical across impls
-    other = (invert_probes_count if impl is invert_probes_sort
-             else invert_probes_sort)
-    t2 = other(jnp.asarray(raw), n_lists, chunk, jnp.asarray(pv))
-    for x, y in zip(tuple(t), tuple(t2)):
-        assert np.array_equal(np.asarray(x), np.asarray(y))
-
-
-def test_invert_dispatch_honors_tuned_key(monkeypatch, rng):
-    from raft_tpu.core import tuned
-
-    raw = rng.integers(0, 16, size=(32, 4)).astype(np.int32)
-    monkeypatch.setattr(tuned, "get_choice",
-                        lambda key, allowed, default: "count"
-                        if key == "invert_impl" else default)
-    t = invert_probes(jnp.asarray(raw), 16, 8)
-    ref = invert_probes_count(jnp.asarray(raw), 16, 8)
-    for x, y in zip(tuple(t), tuple(ref)):
-        if x is None or y is None:
-            assert x is None and y is None
-            continue
-        assert np.array_equal(np.asarray(x), np.asarray(y))
 
 
 def _regroup_ids_first(tables, vals, slots, slot_rows, nq, n_probes, k,
@@ -179,7 +128,7 @@ def test_regroup_merge_ids_after_merge(masked, select_min, rng):
         pv = rng.random((nq, n_probes)) < 0.6
         pv[:, 0] = True
         pv = jnp.asarray(pv)
-    tables = invert_probes_sort(jnp.asarray(raw), n_lists, chunk, pv)
+    tables = invert_probes(jnp.asarray(raw), n_lists, chunk, pv)
     ncb = int(tables.lof.shape[0])
 
     # lists of 2..max_list rows; a few live slots filtered out (-1)

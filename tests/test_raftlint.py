@@ -2055,11 +2055,11 @@ _MUTATIONS = [
      "if False:",
      "dispatch-envelope-guard", "list_scan_select_k"),
     ("tuned-key-literal",
-     ["raft_tpu/core/tuned.py", "bench/bench_pallas_scan.py"],
-     "bench/bench_pallas_scan.py",
-     'tuned.merge({"pallas_fold": winner})',
-     'tuned.merge({"palas_fold": winner})',
-     "tuned-key-registry", "palas_fold"),
+     ["raft_tpu/core/tuned.py", "bench/bench_ivf_rabitq.py"],
+     "bench/bench_ivf_rabitq.py",
+     'tuned.merge({"rabitq_rerank_mult": int(rb_best[1]),',
+     'tuned.merge({"rabitq_rerank_mlt": int(rb_best[1]),',
+     "tuned-key-registry", "rabitq_rerank_mlt"),
     # raftlint 4.0 statecheck: delete one field from a real
     # _cached_wrapper key tuple -> the PR-1/4/12 stale-program class
     ("cache-key-field-deleted",

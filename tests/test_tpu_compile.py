@@ -19,7 +19,6 @@ import pytest
 
 from raft_tpu.ops import fused_scan as fs
 from raft_tpu.ops.pairwise_pallas import pairwise_tiled
-from raft_tpu.ops.pq_list_scan import pq_list_scan
 from raft_tpu.ops.select_counting import counting_select_min
 
 D, N_LISTS, L, CHUNK, K, NCB = 96, 1024, 1024, 128, 10, 256
@@ -62,10 +61,6 @@ CASES = {
          BASE]),
     "fused_list_topk_int8": (
         lambda *a: fs.fused_list_topk_int8(*a, K),
-        [LOF, ((NCB, CHUNK, D), jnp.int8), ((N_LISTS, L, D), jnp.int8),
-         BASE, ((NCB, CHUNK, 1), f32)]),
-    "pq_list_scan_int8": (
-        lambda lof, q, st, b, s: pq_list_scan(lof, q, st, b, q_scale=s),
         [LOF, ((NCB, CHUNK, D), jnp.int8), ((N_LISTS, L, D), jnp.int8),
          BASE, ((NCB, CHUNK, 1), f32)]),
     "pairwise_tiled_l1": (

@@ -18,8 +18,8 @@ class). This module answers, per site:
     derivation fixpoint). Derivations through a **tuned read**
     (``tuned.get``/``get_choice``/``hints``, directly or via a resolved
     callee's summary) never count as covered — tuned state is
-    process-global but NOT process-stable, exactly why
-    ``resolve_setup_impls`` results are keyed at every site.
+    process-global but NOT process-stable, exactly why every site keys
+    the *resolved* value.
 
 Checkpoint sites (``serialize_arrays``/``_write_ckpt`` callers, the
 ``load``/``ivf_*_load`` dispatchers) are matched against the
