@@ -930,7 +930,7 @@ def _search_impl_recon8_listmajor(
     k: int,
     n_probes: int,
     metric: DistanceType,
-    chunk: int = 128,
+    chunk: int = None,
     int8_queries: bool = False,
     trim_bf16: bool = False,
     pvalid: jax.Array = None,
@@ -939,15 +939,19 @@ def _search_impl_recon8_listmajor(
     ~chunk queries probing it and scored with one bf16 MXU matmul.
 
     The query-major engines gather `codes[probes]` per query, so each list
-    is re-read ~nq*n_probes/n_lists times; at bench shape (nq=4096,
-    n_probes=32, n_lists=1024) that is a 128x duplication of the dominant
-    HBM stream. Here the (query, list) probe pairs are sorted by list and
-    split into fixed-size chunks of `chunk` pairs ("virtual lists" — hot
+    is re-read dup = nq*n_probes/n_lists times; at the batch cell's slice
+    shape (nq=4096, n_probes=8, n_lists=1024) that is a 32x duplication of
+    the dominant HBM stream. Here the (query, list) probe pairs are sorted
+    by list and split into chunks of `chunk` pairs ("virtual lists" — hot
     lists get several chunks, so query skew costs padding only inside one
-    chunk, never globally). Each chunk does one (chunk, rot) x (rot,
-    max_list) matmul plus a per-row top-k, and the per-pair candidates are
-    regrouped to query-major by an inverse-permutation *gather* for the
-    final select_k.
+    chunk, never globally). `chunk` defaults to
+    `probe_invert.list_chunk`'s rule, the largest power of two at most
+    dup in [32, 128], fixed at trace time from the static shapes: each
+    list then pads at most one chunk of that width, and where dup >= 32
+    the trim's `TopK` works on at most about 2P rows for P pairs. Each
+    chunk does one (chunk, rot) x (rot, max_list) matmul plus a per-row
+    top-k, and the per-pair candidates are regrouped to query-major by an
+    inverse-permutation *gather* for the final select_k.
 
     TPU notes: the whole pipeline is sorts + searchsorted + gathers — no
     XLA scatters (TPU lowers scatters to a serialized per-index loop, which
@@ -959,10 +963,16 @@ def _search_impl_recon8_listmajor(
 
     The coarse probe selection runs inside this same jit (one program,
     one dispatch)."""
-    from raft_tpu.neighbors.probe_invert import invert_probes, score_and_select
+    from raft_tpu.neighbors.probe_invert import (
+        invert_probes,
+        list_chunk,
+        score_and_select,
+    )
 
     nq = queries.shape[0]
     n_lists, max_list, rot_dim = recon8.shape
+    if chunk is None:
+        chunk = list_chunk(nq, n_probes, n_lists)
     select_min = metric != DistanceType.InnerProduct
     worst = jnp.inf if select_min else -jnp.inf
     with jax.named_scope("ivf_pq.coarse"):
@@ -974,12 +984,14 @@ def _search_impl_recon8_listmajor(
     with jax.named_scope("ivf_pq.scan"):
         q_pad = jnp.concatenate([q_rot, jnp.zeros((1, rot_dim), q_rot.dtype)])
         scale_bf = recon_scale.astype(jnp.bfloat16)
+        # gathered per chunk as 1-byte flags, not the 4-byte slot table
+        live = slot_rows >= 0
 
     def block(inp):
         lofb, qids = inp  # (CB,), (CB, chunk)
         r8 = recon8[lofb]  # (CB, max_list, rot) — the only read of these codes
         rn = recon_norm[lofb]
-        srows = slot_rows[lofb]
+        lv = live[lofb]
         cent = centers[lofb]
         qs = q_pad[qids]  # (CB, chunk, rot)
         if metric == DistanceType.InnerProduct:
@@ -1010,7 +1022,7 @@ def _search_impl_recon8_listmajor(
         else:
             qcn = jnp.sum(qres**2, axis=2)
             scores = qcn[:, :, None] - 2.0 * dots + rn[:, None, :]
-        scores = jnp.where(srows[:, None, :] >= 0, scores, worst)
+        scores = jnp.where(lv[:, None, :], scores, worst)
         if trim_bf16:
             # bf16 trim (internal_distance_dtype parity with the
             # reference's half-precision internal distances,
@@ -1367,11 +1379,23 @@ def search(
         raise ValueError(f"unknown score_mode {mode!r}")
     if obs.enabled() and mode == "recon8_list":
         # the list-major merge looks up one row id per winner of every
-        # padded slice row (probe_invert.regroup_merge), from shapes alone
-        from raft_tpu.neighbors.probe_invert import macro_batch_rows
+        # padded slice row (probe_invert.regroup_merge); the XLA trim
+        # selects over its chunk rows, superblock padding included. Both
+        # from shapes alone
+        from raft_tpu.neighbors.probe_invert import (
+            list_chunk,
+            macro_batch_slices,
+            trim_rows,
+        )
 
-        obs.counter("ivf_pq.merge.id_lookups").inc(
-            macro_batch_rows(int(q.shape[0])) * int(k))
+        slices = macro_batch_slices(int(q.shape[0]))
+        obs.counter("ivf_pq.merge.id_lookups").inc(sum(slices) * int(k))
+        if trim != "fused":
+            n_lists, max_list = (int(d) for d in index.recon8.shape[:2])
+            obs.counter("ivf_pq.trim.rows").inc(sum(
+                trim_rows(s, n_probes, n_lists, max_list,
+                          list_chunk(s, n_probes, n_lists))
+                for s in slices))
     ids = jnp.where(rows >= 0, index.source_ids[jnp.maximum(rows, 0)], -1)
     if resources is not None:
         resources.track(vals, ids)

@@ -6,8 +6,8 @@ probed list's storage once per query, so a batch re-reads every list
 ~nq*n_probes/n_lists times from HBM. The list-major engines instead invert
 the (query, list) probe pairs into per-list buckets and stream each list
 once. This module holds the shared inversion: sort pairs by list, split
-each list's bucket into fixed-size chunks of `chunk` pairs ("virtual
-lists", so hot-list skew costs padding only inside one chunk), and emit
+each list's bucket into chunks of `chunk` pairs ("virtual lists", so
+hot-list skew costs padding only inside one chunk), and emit
   - per-chunk tables (which list, which queries) for the scoring loop, and
   - a per-pair (chunk, slot) address for regrouping candidates back to
     query-major order with a pure gather.
@@ -16,6 +16,12 @@ Everything is sorts + searchsorted + gathers — no XLA scatters (TPU lowers
 scatter to a serialized per-index loop) — and every shape is static: the
 chunk budget uses the bound sum(ceil(c_i/chunk)) <= P//chunk + n_lists, so
 equal-shaped batches never recompile and no host sync is needed.
+
+The bound's n_lists term is one chunk of padding per list, so a chunk
+much wider than the batch's duplication (P / n_lists pairs a list) pads
+far more rows than it fills. `list_chunk` sizes the chunk to that
+duplication from the batch's static shapes, so IVF-PQ's XLA trim works
+on chunk tables of at most 2P rows where dup >= 32.
 """
 
 from __future__ import annotations
@@ -53,6 +59,42 @@ class ChunkTables(NamedTuple):
 def chunk_count(nq: int, n_probes: int, n_lists: int, chunk: int) -> int:
     """Static upper bound on the number of chunks for a batch."""
     return (nq * n_probes) // chunk + n_lists
+
+
+def list_chunk(nq: int, n_probes: int, n_lists: int) -> int:
+    """Chunk width from a batch's static shapes: the largest power of two
+    at most its duplication dup = nq * n_probes / n_lists, clamped to
+    [32, 128]. The chunk tables then hold P + chunk * n_lists <= 2P rows
+    (P = nq * n_probes pairs) where dup >= 32, against P + 128 * n_lists
+    at a fixed 128. Below 32 the TPU compiler gives the trim's `TopK`
+    the slots-on-lanes layout, about 3x slower a row on a v5e: there a
+    2,048-query slice at 8 probes over 1,024 lists (dup 16) runs its
+    search program in 37 ms at chunk 32 and 90 ms at 16."""
+    dup = (nq * n_probes) // max(1, n_lists)
+    return min(128, max(32, 1 << max(0, dup.bit_length() - 1)))
+
+
+#: elements of one superblock's score buffer (sb * chunk * max_list), at
+#: most, unless one chunk alone holds more
+SUPERBLOCK_ELEMS = 1 << 27
+
+
+def superblocks(ncb: int, chunk: int, max_list: int) -> tuple:
+    """(nsuper, sb): `score_and_select` splits `ncb` chunks into the
+    fewest superblocks within SUPERBLOCK_ELEMS, nsuper equal ones of sb
+    chunks, so the split pads at most nsuper - 1 chunks."""
+    sb_max = max(1, SUPERBLOCK_ELEMS // max(1, chunk * max_list))
+    nsuper = -(-ncb // sb_max)
+    return nsuper, -(-ncb // nsuper)
+
+
+def trim_rows(nq: int, n_probes: int, n_lists: int, max_list: int,
+              chunk: int) -> int:
+    """(query, list) rows the trim selects over for an nq-query batch,
+    superblock padding included, from shapes alone."""
+    nsuper, sb = superblocks(chunk_count(nq, n_probes, n_lists, chunk),
+                             chunk, max_list)
+    return nsuper * sb * chunk
 
 
 def invert_probes(probes: jax.Array, n_lists: int, chunk: int,
@@ -133,8 +175,11 @@ def score_and_select(
     exact 2-D top-k (the select-k dispatch) used for the trim and the
     final merge.
 
-    Superblocks of `sb` chunks bound the materialized score buffer to
-    ~2^27 elements regardless of max_list skew. Each superblock's
+    `chunk` is the engine's chunk width (`list_chunk` for IVF-PQ's XLA
+    trim, 128 for the other engines). The `ncb` chunks split evenly into
+    the fewest superblocks whose score buffer stays within 2^27 elements
+    regardless of max_list skew (`superblocks`), so the split pads at
+    most nsuper - 1 chunks. Each superblock's
     (chunk, max_list) rows are trimmed to their exact top-kk, ties to the
     lower slot, through `select_k_fn` on a rank-2 (sb * chunk, max_list)
     view: on the TPU that lowers to the partial-select `TopK` custom call,
@@ -150,8 +195,7 @@ def score_and_select(
     ncb = lof.shape[0]
     kk = min(k, max_list)
 
-    sb = min(max(1, (1 << 27) // max(1, chunk * max_list)), ncb)
-    nsuper = -(-ncb // sb)
+    nsuper, sb = superblocks(ncb, chunk, max_list)
     bpad = nsuper * sb - ncb
 
     def super_block(lofs, qids):  # (sb,), (sb, chunk)
@@ -272,10 +316,10 @@ def macro_batched(search_slice_fn, queries: jax.Array, k: int, mb: int = 4096,
     )
 
 
-def macro_batch_rows(nq: int, mb: int = 4096) -> int:
-    """Query rows `macro_batched` runs for an nq-row call: every slice
-    padded up its ladder. From shapes alone, no device work."""
-    return sum(_ladder(min(mb, nq - s), mb) for s in range(0, nq, mb))
+def macro_batch_slices(nq: int, mb: int = 4096) -> list:
+    """The padded slice sizes `macro_batched` runs for an nq-row call,
+    each slice padded up its ladder. From shapes alone, no device work."""
+    return [_ladder(min(mb, nq - s), mb) for s in range(0, nq, mb)]
 
 
 def _ladder(n: int, cap: int) -> int:

@@ -285,19 +285,30 @@ def _trim_reference(rec, nq, n_probes, k, source_ids):
     return np.stack(vals), np.stack(ids), short
 
 
+#: the IVF-PQ trim scores: bf16 or f32 trim over bf16 MXU dots, or int8
+#: query rows against the int8 codes (an f32 trim)
+SCORES = {
+    "bf16": {"internal_distance_dtype": "bfloat16"},
+    "f32": {"internal_distance_dtype": "float32"},
+    "int8": {"internal_distance_dtype": "float32", "score_dtype": "int8"},
+}
+
 TRIM_CASES = {
-    # name: (index, internal_distance_dtype, k, prefilter or adaptive
-    # probe budgets); "pq16" is the shared 20,000-row index, on which
-    # recall is checked besides
-    "pq-bf16-k40": ("pq", "bfloat16", 40, None),
-    "pq-bf16-k10-prefilter": ("pq", "bfloat16", 10, "prefilter"),
-    "pq-f32-k40": ("pq", "float32", 40, None),
-    "pq-f32-k10-prefilter": ("pq", "float32", 10, "prefilter"),
-    "pq16-recall": ("pq16", "float32", 10, None),
-    "flat-k40": ("flat", "float32", 40, None),
-    "flat-k10-prefilter": ("flat", "float32", 10, "prefilter"),
-    "pq-f32-k40-budget": ("pq", "float32", 40, "budget"),
-    "flat-k40-budget": ("flat", "float32", 40, "budget"),
+    # name: (index, trim scores, k, prefilter or adaptive probe
+    # budgets); "pq16" is the shared 20,000-row index, on which recall
+    # is checked besides
+    "pq-bf16-k40": ("pq", "bf16", 40, None),
+    "pq-bf16-k10-prefilter": ("pq", "bf16", 10, "prefilter"),
+    "pq-f32-k40": ("pq", "f32", 40, None),
+    "pq-f32-k10-prefilter": ("pq", "f32", 10, "prefilter"),
+    "pq16-recall": ("pq16", "f32", 10, None),
+    "flat-k40": ("flat", "f32", 40, None),
+    "flat-k10-prefilter": ("flat", "f32", 10, "prefilter"),
+    "pq-f32-k40-budget": ("pq", "f32", 40, "budget"),
+    "flat-k40-budget": ("flat", "f32", 40, "budget"),
+    "pq-int8-k40": ("pq", "int8", 40, None),
+    "pq-int8-k10-prefilter": ("pq", "int8", 10, "prefilter"),
+    "pq-int8-k40-budget": ("pq", "int8", 40, "budget"),
 }
 
 
@@ -309,10 +320,11 @@ def test_listmajor_trim_is_exact(case, trim_data, trim_pq, trim_flat,
     answers equal a NumPy reference built from the engine's own scores,
     including lists with fewer than kk live rows and pairs dropped by
     adaptive probe budgets. Every IVF-PQ trim_engine name that runs the
-    XLA trim returns the same bits."""
-    from raft_tpu.neighbors import ivf_flat
+    XLA trim returns the same bits, and so does a fixed 128-pair chunk
+    in place of the engine's `list_chunk` width."""
+    from raft_tpu.neighbors import ivf_flat, probe_invert
 
-    which, idd, k, extra = TRIM_CASES[case]
+    which, scores, k, extra = TRIM_CASES[case]
     _, queries, keep = trim_data
     filtered = extra == "prefilter"
     prefilter = keep if filtered else None
@@ -337,8 +349,8 @@ def test_listmajor_trim_is_exact(case, trim_data, trim_pq, trim_flat,
             return ivf_pq.search(
                 ivf_pq.SearchParams(n_probes=n_probes,
                                     score_mode="recon8_list",
-                                    internal_distance_dtype=idd,
-                                    trim_engine=trim, **budget),
+                                    trim_engine=trim, **SCORES[scores],
+                                    **budget),
                 index, queries, k, prefilter=prefilter)
 
         d0, i0 = run("approx")
@@ -346,6 +358,15 @@ def test_listmajor_trim_is_exact(case, trim_data, trim_pq, trim_flat,
             d, i = run(trim)
             np.testing.assert_array_equal(np.asarray(d), np.asarray(d0))
             np.testing.assert_array_equal(np.asarray(i), np.asarray(i0))
+        monkeypatch.setattr(probe_invert, "list_chunk", lambda *a: 128)
+        jitted.clear_cache()
+        try:
+            d, i = run("approx")
+        finally:
+            monkeypatch.undo()
+            jitted.clear_cache()
+        np.testing.assert_array_equal(np.asarray(d), np.asarray(d0))
+        np.testing.assert_array_equal(np.asarray(i), np.asarray(i0))
 
     rec = _spy_trim(monkeypatch, jitted)
     try:
@@ -353,6 +374,12 @@ def test_listmajor_trim_is_exact(case, trim_data, trim_pq, trim_flat,
     finally:
         monkeypatch.undo()
         jitted.clear_cache()
+    # IVF-PQ's XLA trim takes the rule's chunk (the 256-query slice:
+    # 32 pairs at 8 probes over 64 lists, 128 for "pq16"); IVF-Flat 128
+    n_slice, n_lists = len(rec["g0"]) // n_probes, int(index.n_lists)
+    assert rec["qid"].shape[1] == (
+        128 if which == "flat"
+        else probe_invert.list_chunk(n_slice, n_probes, n_lists))
     ref_d, ref_i, short = _trim_reference(rec, len(queries), n_probes, k,
                                           np.asarray(index.source_ids))
     np.testing.assert_array_equal(i, ref_i)
@@ -374,3 +401,38 @@ def test_listmajor_trim_is_exact(case, trim_data, trim_pq, trim_flat,
         )
 
 
+def test_distributed_list_path_chunk_parity(trim_data, monkeypatch):
+    """The distributed XLA list path (`mnmg.ivf_pq_search` on a 4-device
+    mesh) runs the engine at the rule's chunk (its floor of 32 pairs for
+    50 queries x 8 probes over 64 lists) and returns the bits a fixed 128
+    gives."""
+    from raft_tpu.comms import Comms, mnmg, mnmg_common
+    from raft_tpu.neighbors import probe_invert
+
+    data, queries, _ = trim_data
+    index = mnmg.ivf_pq_build(Comms(n_devices=4),
+                              ivf_pq.IndexParams(n_lists=64, pq_dim=16), data)
+    rule = probe_invert.list_chunk
+    engine = ivf_pq._search_impl_recon8_listmajor
+
+    def run(chunk_fn):
+        seen = []
+        monkeypatch.setattr(probe_invert, "list_chunk",
+                            lambda *a: seen.append(chunk_fn(*a)) or seen[-1])
+        monkeypatch.setattr(mnmg_common, "_JIT_WRAPPER_CACHE", {})
+        engine.clear_cache()
+        try:
+            d, i = mnmg.ivf_pq_search(index, queries, 40, n_probes=8,
+                                      engine="recon8_list")
+            return np.asarray(d), np.asarray(i), seen
+        finally:
+            monkeypatch.undo()
+            engine.clear_cache()
+
+    d0, i0, seen = run(rule)
+    assert seen == [32]
+    d, i, seen = run(lambda *a: 128)
+    assert seen == [128]
+    np.testing.assert_array_equal(d, d0)
+    np.testing.assert_array_equal(i, i0)
+    assert i0.min() >= 0

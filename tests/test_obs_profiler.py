@@ -287,6 +287,32 @@ def test_ivf_pq_merge_id_lookups_from_static_shapes(small_index, monkeypatch,
         obs.reset()
 
 
+@pytest.mark.parametrize("enabled,nq,n_probes,rows", [
+    (True, 32, 4, 16 * 128),  # 256-row slice, dup 128: 8 + 8 chunks of 128
+    (True, 32, 1, 16 * 32),   # dup 32: 8 + 8 chunks of 32
+    (True, 300, 4, 24 * 128),  # 512-row slice: 16 + 8 chunks of 128
+    (False, 32, 4, 0)])
+def test_ivf_pq_trim_rows_from_static_shapes(small_index, monkeypatch,
+                                             enabled, nq, n_probes, rows):
+    """One increment a list-major call, from shapes alone: the rows the
+    XLA trim selects over at the rule's chunk (one superblock here);
+    nothing with obs off."""
+    _, _, index = small_index
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda v: pytest.fail("device sync"))
+    q = np.random.default_rng(6).standard_normal((nq, 16)).astype(np.float32)
+    obs.reset()
+    obs.enable(enabled)
+    try:
+        ivf_pq.search(ivf_pq.SearchParams(n_probes=n_probes,
+                                          score_mode="recon8_list"),
+                      index, q, 10)
+        assert obs.counter("ivf_pq.trim.rows").value == rows
+    finally:
+        obs.disable()
+        obs.reset()
+
+
 def test_refine_program_names_its_stage(small_index):
     x, q, _ = small_index
     lowered = refine_mod._refine_impl.lower(

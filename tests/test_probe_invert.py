@@ -15,9 +15,12 @@ from raft_tpu.matrix.select_k import _select_k_impl
 from raft_tpu.neighbors.probe_invert import (
     chunk_count,
     invert_probes,
-    macro_batch_rows,
+    list_chunk,
+    macro_batch_slices,
     macro_batched,
     regroup_merge,
+    superblocks,
+    trim_rows,
 )
 
 
@@ -28,9 +31,13 @@ from raft_tpu.neighbors.probe_invert import (
         (128, 4, 8, 32, True),   # hot lists get several chunks
         (33, 7, 64, 8, True),    # non-divisible everything
         (16, 3, 4, 64, False),   # chunk larger than any bucket
+        (512, 8, 64, None, True),   # the rule's chunk: dup 64
+        (40, 3, 64, None, False),   # the rule's floor of 32 at dup 1
     ],
 )
 def test_invert_probes_invariants(nq, n_probes, n_lists, chunk, skew, rng):
+    if chunk is None:
+        chunk = list_chunk(nq, n_probes, n_lists)
     if skew:
         # zipf-ish skew: low-id lists drawn far more often
         raw = rng.zipf(1.5, size=(nq, n_probes)) % n_lists
@@ -156,7 +163,7 @@ def test_regroup_merge_ids_after_merge(masked, select_min, rng):
 
 @pytest.mark.parametrize("nq", [0, 1, 256, 300, 4096, 4097, 10_000])
 def test_macro_batch_rows_counts_padded_slices(nq):
-    """`macro_batch_rows` is the row count `macro_batched` runs."""
+    """`macro_batch_slices` are the padded slices `macro_batched` runs."""
     seen = []
 
     def fn(sl):
@@ -165,4 +172,99 @@ def test_macro_batch_rows_counts_padded_slices(nq):
                                                        jnp.int32)
 
     macro_batched(fn, jnp.zeros((nq, 2)), 1)
-    assert macro_batch_rows(nq) == sum(seen)
+    assert macro_batch_slices(nq) == seen
+
+
+@pytest.mark.parametrize("nq,n_probes,n_lists,chunk", [
+    (4096, 8, 1024, 32),   # the batch cell's full slices: dup 32
+    (2048, 8, 1024, 32),   # its last slice: dup 16, the floor
+    (8192, 8, 1024, 64),   # dup 64
+    (6000, 8, 1024, 32),   # dup 46: the power of two below it
+    (256, 1, 1024, 32),    # dup 0: the floor
+    (4096, 32, 64, 128),   # dup 2,048: the ceiling
+    (0, 8, 1024, 32),
+])
+def test_list_chunk_rule(nq, n_probes, n_lists, chunk):
+    """The largest power of two at most the duplication, in [32, 128]."""
+    assert list_chunk(nq, n_probes, n_lists) == chunk
+
+
+def test_trim_rows_of_the_batch_cell():
+    """Rows the trim selects over in one 10,000-query `batch-10k` call
+    (slices 4,096, 4,096 and 2,048; 8 probes, 1,024 lists, 2,944 slots),
+    from shapes alone: 180,224 at the rule's chunk of 32, 475,136 at a
+    fixed 128 with even superblocks (546,816 with 356-chunk ones)."""
+    slices = macro_batch_slices(10_000)
+    assert slices == [4096, 4096, 2048]
+
+    def rows(chunk_of):
+        return sum(trim_rows(s, 8, 1024, 2944, chunk_of(s)) for s in slices)
+
+    assert [trim_rows(s, 8, 1024, 2944, list_chunk(s, 8, 1024))
+            for s in slices] == [65_536, 65_536, 49_152]
+    assert rows(lambda s: list_chunk(s, 8, 1024)) == 180_224
+    assert rows(lambda s: 128) == 475_136
+
+
+@pytest.mark.parametrize("chunk,max_list", [
+    (128, 2944), (32, 2944), (16, 2944), (8, 2944), (128, 47), (8, 1 << 24),
+    (128, 1 << 22)])  # a chunk row alone past 2^27 elements: sb = 1
+def test_superblocks_split_evenly(chunk, max_list):
+    """The fewest superblocks whose score buffer stays within 2^27
+    elements, split evenly: at most nsuper - 1 chunks of padding."""
+    cap = max(1, (1 << 27) // (chunk * max_list))
+    for ncb in [1, 2, 7, 355, 356, 357, 1152, 1280, 2048, 3072, 5000]:
+        nsuper, sb = superblocks(ncb, chunk, max_list)
+        assert nsuper == -(-ncb // cap)
+        assert ncb <= nsuper * sb <= ncb + nsuper - 1
+        assert sb <= cap
+        if cap > 1:
+            assert sb * chunk * max_list <= 1 << 27
+    # the cell's slices at the parent's chunk: 4 x 320, not 4 x 356
+    assert superblocks(1280, 128, 2944) == (4, 320)
+    assert superblocks(2048, 32, 2944) == (2, 1024)
+    assert superblocks(1536, 32, 2944) == (2, 768)
+
+
+def test_score_and_select_scores_even_superblocks(rng, monkeypatch):
+    """`score_and_select` scores `superblocks`' split: every block_fn call
+    sees sb chunks, the padding chunks are dropped, and the answers equal
+    those of one superblock."""
+    import jax
+
+    from raft_tpu.neighbors import probe_invert
+
+    nq, n_probes, n_lists, chunk, max_list, k = 64, 4, 8, 8, 32, 5
+    raw = rng.integers(0, n_lists, size=(nq, n_probes)).astype(np.int32)
+    tables = invert_probes(jnp.asarray(raw), n_lists, chunk)
+    ncb = int(tables.lof.shape[0])
+    assert ncb == 40
+    slot_rows = jnp.arange(n_lists * max_list, dtype=jnp.int32).reshape(
+        n_lists, max_list)
+    table = jax.random.normal(jax.random.PRNGKey(0),
+                              (n_lists, nq + 1, max_list))
+
+    def run():
+        seen = []
+
+        def block(inp):
+            lofs, qids = inp
+            seen.append(lofs.shape[0])
+            return table[lofs[:, None], qids]
+
+        out = probe_invert.score_and_select(
+            tables, block, slot_rows, _select_k_impl, nq, n_probes, k, True,
+            chunk, max_list, stage="t")
+        return [np.asarray(x) for x in out], seen
+
+    (v1, i1), seen = run()
+    assert seen == [ncb]
+    # room for 14 chunks: 3 superblocks of 14 (2 chunks of padding), not
+    # 14 + 14 + 12
+    monkeypatch.setattr(probe_invert, "SUPERBLOCK_ELEMS",
+                        14 * chunk * max_list)
+    assert superblocks(ncb, chunk, max_list) == (3, 14)
+    (v3, i3), seen = run()
+    assert seen == [14] * 3
+    np.testing.assert_array_equal(v3, v1)
+    np.testing.assert_array_equal(i3, i1)

@@ -81,61 +81,82 @@ def test_kernel_compiles_for_v5e(one_chip, name):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-#: the batch cell's list-major widths: a 4,096-query slice, 1,024 lists x
-#: 2,944 slots, 8 probes, k = 40, chunk 128: 356 chunks a superblock
-LM_NQ, LM_SLOTS, LM_PROBES, LM_K, LM_SB = 4096, 2944, 8, 40, 356
+#: the batch cell's list-major widths: 1,024 lists x 2,944 slots, 8
+#: probes, k = 40, over its 4,096- and 2,048-query slices
+LM_SLOTS, LM_PROBES, LM_K = 2944, 8, 40
+#: slice -> the rule's chunk (dup 32 and 16, both 32), superblocks, and
+#: chunks a superblock: 2,048 and 1,536 chunks
+LM_SLICES = {4096: (32, 2, 1024), 2048: (32, 2, 768)}
 
 
-@pytest.fixture(scope="module")
-def listmajor_hlo(one_chip):
-    """The IVF-PQ list-major search program at the batch cell's widths
-    (rot 96, int8 store, bf16 trim), compiled for one v5e."""
+@pytest.fixture(scope="module", params=sorted(LM_SLICES, reverse=True))
+def listmajor_hlo(one_chip, request):
+    """The IVF-PQ list-major search program of one of the batch cell's
+    slices (rot 96, int8 store, bf16 trim), compiled for one v5e, with
+    the slice's (nq, chunk, nsuper, sb)."""
     from raft_tpu.distance.distance_types import DistanceType
     from raft_tpu.neighbors.ivf_pq import _search_impl_recon8_listmajor
+    from raft_tpu.neighbors.probe_invert import (
+        chunk_count,
+        list_chunk,
+        superblocks,
+    )
 
-    shapes = [((LM_NQ, D), f32), ((D, D), f32), ((N_LISTS, D), f32),
+    nq = request.param
+    chunk, nsuper, sb = LM_SLICES[nq]
+    assert list_chunk(nq, LM_PROBES, N_LISTS) == chunk
+    assert superblocks(chunk_count(nq, LM_PROBES, N_LISTS, chunk), chunk,
+                       LM_SLOTS) == (nsuper, sb)
+    shapes = [((nq, D), f32), ((D, D), f32), ((N_LISTS, D), f32),
               ((N_LISTS, LM_SLOTS, D), jnp.int8), ((D,), f32),
               ((N_LISTS, LM_SLOTS), f32), ((N_LISTS, LM_SLOTS), i32)]
     args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
             for s, dt in shapes]
-    return _search_impl_recon8_listmajor.lower(
+    txt = _search_impl_recon8_listmajor.lower(
         *args, k=LM_K, n_probes=LM_PROBES, metric=DistanceType.L2Expanded,
         trim_bf16=True).compile().as_text()
+    return txt, (nq, chunk, nsuper, sb)
 
 
 def test_listmajor_trim_lowers_to_topk(listmajor_hlo):
     """At the batch cell's widths the list-major trim compiles to the
-    TPU's partial-select TopK over the 2,944-slot rows, each in the
-    rows-on-lanes layout ({0,1}) it gets outside a loop body, and no
-    sort runs over that axis."""
+    TPU's partial-select TopK over the 2,944-slot rows, one a superblock
+    of sb x chunk rows, each in the rows-on-lanes layout ({0,1}) it gets
+    outside a loop body at chunks of 32 and more, and no sort runs over
+    that axis."""
     import re
 
-    txt = listmajor_hlo
-    defs = {name: (dims.split(",")[-1], layout) for name, dims, layout in
+    txt, (_, chunk, nsuper, sb) = listmajor_hlo
+    defs = {name: (dims, layout) for name, dims, layout in
             re.findall(r"(%[\w.-]+) = \w+\[([\d,]*)\]\{([\d,]*)", txt)}
     operands = re.findall(
         r"custom-call\((%[\w.-]+)\), custom_call_target=\"TopK\"", txt)
-    trims = [defs[o][1] for o in operands if defs[o][0] == str(LM_SLOTS)]
-    assert trims and all(layout == "0,1" for layout in trims), trims
+    trims = [defs[o] for o in operands
+             if defs[o][0].split(",")[-1] == str(LM_SLOTS)]
+    assert trims and all(layout == "0,1" for _, layout in trims), trims
+    assert [dims for dims, _ in trims] == [f"{sb * chunk},{LM_SLOTS}"] * nsuper
     assert not re.search(rf"\[[\d,]*\b{LM_SLOTS}\b[\d,]*\][^\n]* sort\(",
                          txt)
 
 
 def test_listmajor_ids_looked_up_after_merge(listmajor_hlo):
     """The row ids are looked up for the merged top-k only: no gather
-    makes an output of one entry per trimmed slot of every chunk row
-    (sb x chunk x k), and the widest int32 gather is the regroup of the
-    nq x n_probes x k candidate slots."""
+    makes an output of one entry per trimmed slot of a superblock's chunk
+    rows (sb x chunk x k) but the regroup's two, and the widest int32
+    gather is the regroup of the nq x n_probes x k candidate slots."""
     import math
     import re
 
+    txt, (nq, chunk, _, sb) = listmajor_hlo
     sizes = [(dt, math.prod(int(d) for d in dims.split(",") if d))
              for dt, dims in re.findall(
-                 r"= (\w+)\[([\d,]*)\]\{[^}]*\} gather\(", listmajor_hlo)]
+                 r"= (\w+)\[([\d,]*)\]\{[^}]*\} gather\(", txt)]
     assert sizes
-    assert LM_SB * CHUNK * LM_K not in {n for _, n in sizes}
-    assert max(n for dt, n in sizes if dt == "s32") <= \
-        LM_NQ * LM_PROBES * LM_K
+    # at the 4,096-query slice a superblock's sb x chunk rows number its
+    # nq x n_probes pairs, so the regroup's gathers of candidate values
+    # and slots have that size too; a lookup a superblock would add more
+    assert sum(n == sb * chunk * LM_K for _, n in sizes) <= 2
+    assert max(n for dt, n in sizes if dt == "s32") <= nq * LM_PROBES * LM_K
 
 
 #: wiki1m-rabitq's widths: 768-d (24 code words), 4,096 lists, 4-bit
